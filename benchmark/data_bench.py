@@ -159,4 +159,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from incubator_mxnet_tpu import runtime
+
+    runtime.enable_compile_cache()
     sys.exit(main())

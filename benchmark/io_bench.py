@@ -87,4 +87,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from incubator_mxnet_tpu import runtime
+
+    runtime.enable_compile_cache()
     main()

@@ -127,4 +127,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from incubator_mxnet_tpu import runtime
+
+    runtime.enable_compile_cache()
     main()

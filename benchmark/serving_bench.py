@@ -191,13 +191,17 @@ def run_cold_start(net, feature_shape, buckets, artifact_dir):
     and report the artifact speedup vs compile-from-scratch. The
     artifact-warmed cache must perform ZERO XLA compiles."""
     import shutil
-    import tempfile
 
     from incubator_mxnet_tpu.serving import BucketedExecutorCache
 
-    own_tmp = artifact_dir is None
-    if own_tmp:
-        artifact_dir = tempfile.mkdtemp(prefix="mxtpu-artifacts-")
+    own_dir = artifact_dir is None
+    if own_dir:
+        # a fixed path under the checkout (never a temp name, pid or
+        # time), emptied first so the compile-and-persist leg is cold
+        artifact_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".serving_artifacts", "serving_bench")
+        shutil.rmtree(artifact_dir, ignore_errors=True)
     try:
         def fresh(store):
             return BucketedExecutorCache.from_block(
@@ -242,7 +246,7 @@ def run_cold_start(net, feature_shape, buckets, artifact_dir):
                       "value": round(float(value), 4), "unit": unit})
         return row
     finally:
-        if own_tmp:
+        if own_dir:
             shutil.rmtree(artifact_dir, ignore_errors=True)
 
 
@@ -400,7 +404,7 @@ def main():
                          "publish_weights flip vs steady state")
     ap.add_argument("--artifact-dir", type=str, default=None,
                     help="persist --cold-start artifacts here instead "
-                         "of a throwaway temp dir")
+                         "of <checkout>/.serving_artifacts/serving_bench")
     ap.add_argument("--rate", type=float, default=100.0,
                     help="offered rate (req/s) for --hot-swap")
     args = ap.parse_args()
@@ -503,4 +507,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from incubator_mxnet_tpu import runtime
+
+    runtime.enable_compile_cache()
     main()

@@ -344,4 +344,7 @@ if __name__ == "__main__":
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=8").strip()
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from incubator_mxnet_tpu import runtime
+
+    runtime.enable_compile_cache()
     sys.exit(main_overlap() if "--overlap" in sys.argv else main())

@@ -244,8 +244,10 @@ config.register(
     "computes XLA cost-analysis FLOPs only while a JSONL sink or "
     "/metrics server is live, because deriving FLOPs costs one extra "
     "AOT compile per executable signature; '1'/'0' force it on/off. "
-    "The gauge uses bench.py's canonical formula against the measured "
-    "ceiling (MXTPU_BENCH_CEILING_TFS).")
+    "The gauge uses bench.py's canonical formula against the device's "
+    "published peak (telemetry.PEAK_BF16_TFS by device_kind; "
+    "MXTPU_BENCH_CEILING_TFS overrides); a device with no published "
+    "peak emits no MFU.")
 config.register(
     "MXTPU_TRACE_SAMPLE", 0.0, float,
     "Head-based sampling rate for span tracing (telemetry.trace, "

@@ -61,7 +61,9 @@ class Context:
         if not devs:
             if self.kind == "tpu":
                 raise RuntimeError(
-                    "no accelerator devices visible to jax; use mx.cpu()")
+                    "no accelerator devices visible to jax "
+                    f"(jax.devices() is {jax.devices()}): "
+                    f"{self} cannot be resolved")
             raise RuntimeError("no cpu devices visible to jax")
         if self.device_id >= len(devs):
             raise ValueError(

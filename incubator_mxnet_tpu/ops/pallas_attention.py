@@ -44,11 +44,10 @@ from .registry import register
 
 
 def pallas_available() -> bool:
-    """True if a real TPU backend is present (compiled Pallas path)."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """True if the default backend is a TPU (compiled Pallas path). A
+    backend that fails to initialise raises — it is never read as "no
+    TPU", which would send a kernel to the interpreter in silence."""
+    return jax.default_backend() == "tpu"
 
 
 def _flash_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref=None,

@@ -307,6 +307,15 @@ def _fused_conv_kernel(*refs, stride, pad, relu, kh, kw, ho, wo, has_pro,
 # block-size heuristics (shared by fwd and bwd)
 # ---------------------------------------------------------------------------
 
+#: Mosaic's scoped-VMEM limit for these kernels. The compiler's default
+#: (16 MiB on v5e, of 128 MiB physical) is below what the block-size
+#: heuristics' working sets come to once Mosaic pads 7- and 14-wide
+#: tiles to the (8, 128) tiling — the v5e compiler refused the layer-3
+#: backward and every layer-4 shape at the default ("exceeded scoped
+#: vmem limit").
+_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
 def _vmem_budget():
     return int(config.get("MXTPU_CONV_VMEM_MB")) * 1024 * 1024
 
@@ -354,8 +363,9 @@ def _compiler_params(interpret, semantics):
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
-    return {"compiler_params": pltpu.TPUCompilerParams(
-        dimension_semantics=semantics)}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=_VMEM_LIMIT_BYTES)}
 
 
 def _use_im2col(ci, kh, kw):

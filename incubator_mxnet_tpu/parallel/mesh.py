@@ -22,27 +22,13 @@ EXPERT_AXIS = "expert"
 PIPE_AXIS = "pipe"
 
 
-# jax >= 0.5 exposes shard_map at the top level (check_vma kwarg); 0.4.x
-# keeps it in experimental with the older check_rep spelling — one compat
-# wrapper for every parallel module (pipeline, ring attention)
-if hasattr(jax, "shard_map"):
-    shard_map_compat = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_vma)
+# the names every parallel module (pipeline, ring attention, zero) calls
+shard_map_compat = jax.shard_map
 
 
 def axis_size_compat(axis_name: str) -> int:
-    """``lax.axis_size`` (jax >= 0.5) / static ``psum(1, axis)`` (0.4.x) —
-    the size of a mesh axis from inside shard_map."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """The size of a mesh axis from inside shard_map."""
+    return jax.lax.axis_size(axis_name)
 
 
 class _MeshState(threading.local):

@@ -203,16 +203,10 @@ def _artifact_store():
     """The persistent serving artifact store when configured — a
     migrate executable is one more AOT artifact, so a fresh process
     repeats a known flip by DESERIALIZING (ISSUE 14 machinery)."""
-    try:
-        from ..serving.artifacts import (ArtifactStore,
-                                         serialization_supported)
+    from ..serving.artifacts import ArtifactStore
 
-        root = str(_cfg("MXTPU_SERVING_ARTIFACT_DIR") or "")
-        if root and serialization_supported():
-            return ArtifactStore(root)
-    except Exception:
-        pass
-    return None
+    root = str(_cfg("MXTPU_SERVING_ARTIFACT_DIR") or "")
+    return ArtifactStore(root) if root else None
 
 
 def _compile_group(key: Tuple, leaf_specs: List[Tuple], dst_shs: List,

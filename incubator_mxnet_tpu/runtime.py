@@ -8,7 +8,33 @@ flags become runtime-discovered properties of the jax install.
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import List
+
+#: the checkout's own compile cache, used where the environment names none
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point
+    (``chip_smoke.py``, ``bench.py --config``, the ``benchmark/`` mains,
+    ``tools/chaos_soak.py``) and return the directory in use.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here. Otherwise the cache lives at the fixed
+    :data:`COMPILE_CACHE_DIR` — the directory is part of the cache key,
+    so a path made from a temp name, pid or time would never hit twice.
+    Called from entry points, never at package import: tests count
+    ``backend_compile`` events and must compile for real."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 @dataclasses.dataclass(frozen=True)

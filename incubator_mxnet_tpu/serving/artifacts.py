@@ -45,25 +45,12 @@ from typing import Any, Dict, Iterator, Optional, Tuple
 logger = logging.getLogger("mxtpu.serving")
 
 __all__ = ["ArtifactStore", "environment_fingerprint",
-           "params_fingerprint", "serialization_supported"]
+           "params_fingerprint"]
 
 #: bump when the on-disk pickle layout changes — old files are refused
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
 _SUFFIX = ".mxart"
-
-
-def serialization_supported() -> bool:
-    """Does this jax build expose compiled-executable serialization?
-    (``jax.experimental.serialize_executable``; present since 0.4.x.)
-    When absent the store disables itself and every warmup compiles —
-    the pre-artifact behaviour, never an error."""
-    try:
-        from jax.experimental import serialize_executable  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
 
 
 def environment_fingerprint() -> Dict[str, Any]:
@@ -97,6 +84,15 @@ def params_fingerprint(params) -> str:
         h.update(repr(tuple(int(d) for d in p.shape)).encode())
         h.update(str(getattr(p.dtype, "name", p.dtype)).encode())
     return h.hexdigest()[:16]
+
+
+def _execution_device_ids(compiled) -> list:
+    """Ids of the devices ``compiled`` runs on, in assignment order —
+    stored beside the artifact so a load targets the same devices."""
+    import jax
+
+    sharding = jax.tree_util.tree_leaves(compiled.output_shardings)[0]
+    return [int(d.id) for d in sharding._device_assignment]
 
 
 def _key_hash(logical: Dict[str, Any]) -> str:
@@ -137,6 +133,7 @@ class ArtifactStore:
         blob = pickle.dumps({"schema": SCHEMA_VERSION,
                              "logical": dict(logical),
                              "guard": dict(guard),
+                             "devices": _execution_device_ids(compiled),
                              "artifact": payload},
                             protocol=pickle.HIGHEST_PROTOCOL)
         path = self.path_for(model, logical)
@@ -231,9 +228,16 @@ class ArtifactStore:
             if stored.get(field) != want.get(field):
                 return None, f"refused:{field}"
         try:
+            import jax
             from jax.experimental.serialize_executable import (
                 deserialize_and_load)
 
-            return deserialize_and_load(*record["artifact"]), "ok"
+            # load onto exactly the devices the executable was compiled
+            # for: the default is every device of the backend, which a
+            # one-device executable on a multi-device host cannot run on
+            by_id = {d.id: d for d in jax.devices()}
+            devices = [by_id[i] for i in record["devices"]]
+            return deserialize_and_load(
+                *record["artifact"], execution_devices=devices), "ok"
         except Exception as e:   # noqa: BLE001 — fall back to compile
             return None, f"corrupt:{type(e).__name__}"

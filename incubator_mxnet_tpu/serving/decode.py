@@ -52,7 +52,7 @@ import numpy as np
 from .. import profiler
 from .. import telemetry
 from .artifacts import (ArtifactStore, environment_fingerprint,
-                        params_fingerprint, serialization_supported)
+                        params_fingerprint)
 from .batcher import (DeadlineExceededError, QueueFullError,
                       ServerClosedError)
 from .executor_cache import (BucketedExecutorCache,
@@ -338,8 +338,7 @@ class DecodeSession:
         if artifact_dir is None:
             artifact_dir = str(
                 config.get("MXTPU_SERVING_ARTIFACT_DIR") or "")
-        self._store = ArtifactStore(artifact_dir) \
-            if artifact_dir and serialization_supported() else None
+        self._store = ArtifactStore(artifact_dir) if artifact_dir else None
         self._guard = dict(
             environment_fingerprint(), model=self.name,
             fingerprint=params_fingerprint(self._params),

@@ -32,7 +32,7 @@ import numpy as np
 from .. import profiler
 from .. import telemetry
 from .artifacts import (ArtifactStore, environment_fingerprint,
-                        params_fingerprint, serialization_supported)
+                        params_fingerprint)
 from .metrics import ServingMetrics
 
 logger = logging.getLogger("mxtpu.serving")
@@ -276,16 +276,15 @@ class BucketedExecutorCache:
         self.param_names: Optional[List[str]] = None
         self._digests: Optional[List[str]] = None
         # the persistent artifact store (ISSUE 14): None when disabled
-        # (no dir configured, explicit "", or jax without executable
-        # serialization); the guard fingerprint is what a stored
-        # artifact must match field-for-field before deserialization
+        # (no dir configured or explicit ""); the guard fingerprint is
+        # what a stored artifact must match field-for-field before
+        # deserialization
         if artifact_dir is None:
             from ..config import config
 
             artifact_dir = str(
                 config.get("MXTPU_SERVING_ARTIFACT_DIR") or "")
-        self._store = ArtifactStore(artifact_dir) \
-            if artifact_dir and serialization_supported() else None
+        self._store = ArtifactStore(artifact_dir) if artifact_dir else None
         self._guard = dict(
             environment_fingerprint(), model=str(name),
             fingerprint=params_fingerprint(self._params),
@@ -486,11 +485,6 @@ class BucketedExecutorCache:
 
     def _resolve_store(self, directory: Optional[str]) -> ArtifactStore:
         if directory is not None:
-            if not serialization_supported():
-                raise RuntimeError(
-                    "this jax build has no compiled-executable "
-                    "serialization (jax.experimental."
-                    "serialize_executable)")
             return ArtifactStore(directory)
         if self._store is None:
             raise RuntimeError(

@@ -33,15 +33,32 @@ import time
 from typing import Callable, Dict, Optional
 
 
+#: published dense bf16 peak of one chip in TF/s, keyed by the
+#: ``device_kind`` jax reports ("TPU v5 lite" is the v5e). Source: Google
+#: Cloud documentation, "TPU v5e" (197 TFLOP/s bf16 per chip).
+PEAK_BF16_TFS = {"TPU v5 lite": 197.0}
+
+
 def ceiling_tfs() -> float:
-    """The MFU denominator: measured MXU ceiling in TF/s. SOURCE OF
-    TRUTH for the number — bench.py resolves it from here (lazily, so
-    its driver loop stays package-import-free), so the online
-    ``mxtpu_mfu_percent`` gauge and the offline bench MFU always share
-    one default and one env override (``MXTPU_BENCH_CEILING_TFS``).
-    187.9 = fence-free two-point-fit of an 8192^3 bf16 matmul chain
-    (PROFILE.md round 5)."""
-    return float(os.environ.get("MXTPU_BENCH_CEILING_TFS", "187.9"))
+    """The MFU denominator in TF/s: the published peak of the device
+    this process runs on, looked up by ``device_kind``. SOURCE OF TRUTH
+    for the number — bench.py resolves it from here (lazily, so its
+    driver loop stays package-import-free), so the online
+    ``mxtpu_mfu_percent`` gauge and the offline bench MFU share one
+    table and one override (``MXTPU_BENCH_CEILING_TFS``). A device that
+    is not in the table — the CPU included — raises ``LookupError``:
+    an MFU against an assumed peak is never emitted."""
+    env = os.environ.get("MXTPU_BENCH_CEILING_TFS")
+    if env:
+        return float(env)
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    if kind not in PEAK_BF16_TFS:
+        raise LookupError(
+            f"no published peak for device kind {kind!r}; MFU is not "
+            "emitted (set MXTPU_BENCH_CEILING_TFS to supply one)")
+    return PEAK_BF16_TFS[kind]
 
 
 def mfu_percent(flops_per_second: float) -> float:
@@ -308,7 +325,9 @@ class StepMeter:
                     insts["flops"].set(flops)
                     try:
                         mfu_pct = mfu_percent(flops / self._ema_s)
-                    except Exception:      # bad MXTPU_BENCH_CEILING_TFS
+                    except (LookupError, ValueError):
+                        # a device with no published peak, or a bad
+                        # MXTPU_BENCH_CEILING_TFS: no MFU, never a guess
                         mfu_pct = None
                     else:
                         insts["mfu"].set(mfu_pct)

@@ -37,7 +37,11 @@ from typing import Dict, List, Optional, Tuple
 
 logger = logging.getLogger("mxtpu.telemetry")
 
-#: event names that mean "XLA compiled an executable"
+#: event names that mean "XLA compiled an executable". jax times
+#: ``compile_or_get_cached`` under this name, so with the persistent
+#: compilation cache on the event fires for a cache hit as well: a
+#: post-warmup executable request is seen whether the cache is warm or
+#: cold (checked on jax 0.9.0, ISSUE 21)
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",)
 
 _tls = threading.local()

@@ -1,0 +1,110 @@
+"""AOT compiles of the main-path Pallas kernels for a described TPU v5e
+(ISSUE 21): the chip's own compiler, no chip attached.
+
+Interpret-mode tests cannot see what Mosaic refuses — a renamed
+compiler-params class, a working set over the scoped-VMEM limit, a slice
+not aligned to the tiling. These compile the kernels at the real widths
+``chip_smoke.py`` runs them at, about two seconds each.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, and every xdist
+worker imports every test file. All such tests live in this one file so
+that one worker owns the library; they compile in the test's own
+process, with the persistent compilation cache off (an entry written
+for a described chip cannot be read back without one).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from incubator_mxnet_tpu.ops.pallas_attention import _flash_core
+from incubator_mxnet_tpu.ops.pallas_conv import fused_conv_bn
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 — any failure to describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *specs):
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _spec(one_chip, shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def test_flash_forward_and_backward_t2048(one_chip):
+    q = _spec(one_chip, (4, 12, 2048, 64))
+    flash = functools.partial(_flash_core, lens=None, scale=0.125,
+                              causal=True, interpret=False,
+                              cache_offset=False)
+    _compile(lambda q, k, v: flash(q, k, v), q, q, q)
+    _compile(jax.grad(lambda q, k, v: flash(q, k, v)
+                      .astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+             q, q, q)
+
+
+@pytest.mark.parametrize("slots,tq", [(8, 1), (1, 512)])
+def test_flash_cache_offset_decode_shape(one_chip, slots, tq):
+    """The KV-cache alignment: Tq=1 over every slot, and a prefill
+    bucket, against a max_len=1024 buffer with per-slot lengths."""
+    q = _spec(one_chip, (slots, 12, tq, 64))
+    kv = _spec(one_chip, (slots, 12, 1024, 64))
+    lens = _spec(one_chip, (slots,), jnp.int32)
+    _compile(lambda q, k, v, l: _flash_core(q, k, v, l, 0.125, True,
+                                            False, True), q, kv, kv, lens)
+
+
+@pytest.mark.parametrize("hw,c", [(56, 64), (7, 512)])
+def test_fused_conv_bn_forward_and_backward_resnet50_shape(one_chip, hw, c):
+    """3x3 stride-1 at the first and last ResNet-50 stage, batch 32. The
+    7x7x512 shape needs the raised scoped-VMEM limit."""
+    x = _spec(one_chip, (32, hw, hw, c))
+    w = _spec(one_chip, (3, 3, c, c))
+    ab = _spec(one_chip, (c,), jnp.float32)
+
+    def conv(x, w, a, b):
+        return fused_conv_bn(x, w, a, b, stride=1, pad=1, relu=True,
+                             interpret=False)
+
+    def loss(x, w, a, b):
+        y, s, ss = conv(x, w, a, b)
+        return y.astype(jnp.float32).sum() + s.sum() + ss.sum()
+
+    _compile(conv, x, w, ab, ab)
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), x, w, ab, ab)

@@ -17,7 +17,7 @@ from incubator_mxnet_tpu.test_utils import check_numeric_gradient
 
 
 def _tols():
-    """TPU tolerance ladder (TPU_TESTS.md discipline). The noisy side on
+    """TPU tolerance ladder (README.md "Running it"). The noisy side on
     TPU is the FINITE DIFFERENCE, not the op: transcendental-approximation
     error on each scalar eval (~2e-4 over a summed (3,4) input) divides by
     2*eps, bounding FD noise at ~2e-2 absolute for eps=1e-2 — verified for

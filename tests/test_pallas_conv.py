@@ -1,5 +1,5 @@
 """Fused Pallas conv+BN kernel tests (interpret mode on the CPU mesh; the
-same code path compiles for the TPU tier — see TPU_TESTS.md).
+same code path compiles for the chip — tests/test_chip_compile.py).
 
 v2 coverage: every kernel variant is oracle-proven against the XLA
 formulation — blocked forward (output-channel blocking forced via the

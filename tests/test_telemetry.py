@@ -583,3 +583,20 @@ def test_jsonl_interleaves_trace_records_under_concurrent_writers(
                    if r["name"] == f"unit.t{i}") == per
     # spans carry distinct head-sampled trace ids (roots, no ambient)
     assert len({r["trace"] for r in spans}) == n_threads * per
+
+
+def test_mfu_ceiling_is_the_published_peak_or_nothing(monkeypatch):
+    """ISSUE 21: the MFU denominator is looked up by device_kind; a
+    device with no published peak (the CPU mesh here) raises instead of
+    assuming one, and the env override still supplies a denominator."""
+    from incubator_mxnet_tpu.telemetry import meters
+
+    monkeypatch.delenv("MXTPU_BENCH_CEILING_TFS", raising=False)
+    with pytest.raises(LookupError):
+        meters.ceiling_tfs()
+    with pytest.raises(LookupError):
+        meters.mfu_percent(1e12)
+    assert meters.PEAK_BF16_TFS["TPU v5 lite"] == 197.0
+    monkeypatch.setenv("MXTPU_BENCH_CEILING_TFS", "100")
+    assert meters.ceiling_tfs() == 100.0
+    assert meters.mfu_percent(50e12) == 50.0

@@ -472,6 +472,11 @@ def main(argv=None) -> int:
             os.environ["MXTPU_SOAK_REEXEC"] = "1"
             os.execv(sys.executable, [sys.executable] + sys.argv)
 
+    # after the re-exec decision above, which must see jax unimported
+    from incubator_mxnet_tpu import runtime
+
+    runtime.enable_compile_cache()
+
     if args.jsonl:
         os.environ["MXTPU_TELEMETRY_JSONL"] = args.jsonl
     if args.plan:

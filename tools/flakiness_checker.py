@@ -4,6 +4,10 @@ named test N times, each with a different random seed, and report the
 pass/fail tally. Seeds are injected through ``MXNET_TEST_SEED`` — the same
 env knob the test fixtures honor (SURVEY.md §4 "seed discipline").
 
+This parent never imports jax: each trial is a fresh pytest process, run
+one after another, so even under ``MXTPU_TEST_PLATFORM=tpu`` only one
+process at a time asks for the chip.
+
 Usage:
     python tools/flakiness_checker.py tests/test_operator.py::test_dropout
     python tools/flakiness_checker.py -n 50 --seed-start 1000 \

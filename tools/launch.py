@@ -14,6 +14,14 @@ service bound by worker 0. The reference's DMLC_* names are still exported
 (mapped onto the jax settings) so reference-style launch scripts keep
 working; ``-s/--num-servers`` is accepted and ignored with a note.
 
+One process per chip: a chip belongs to one process at a time, and N
+local workers that each asked for "the TPU" would fight over it. This
+launcher never imports jax (it holds no chip itself), and its local
+workers are CPU-only by explicit environment (``JAX_PLATFORMS=cpu``)
+unless the caller has named a platform. All the chips of one host are
+driven by ONE process (``parallel.SPMDTrainer`` over ``jax.devices()``),
+not by one launcher worker each.
+
 Usage (matches the reference's local launcher):
     python tools/launch.py -n 4 [--launcher local] python train.py ...
 """
@@ -44,6 +52,9 @@ def launch_local(num_workers: int, command, extra_env=None,
     for rank in range(num_workers):
         env = dict(os.environ)
         env.update(extra_env or {})
+        # one process per chip: local workers share this host, so they
+        # stay off the chip unless the caller says otherwise
+        env.setdefault("JAX_PLATFORMS", "cpu")
         # reference DMLC tracker names, mapped onto jax.distributed
         env["DMLC_ROLE"] = "worker"
         env["DMLC_PS_ROOT_URI"] = host
