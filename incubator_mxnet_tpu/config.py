@@ -265,9 +265,13 @@ config.register(
     "sequence-numbered name). Empty (default) disables dumping; the "
     "in-memory rings still record.")
 config.register(
-    "MXTPU_TRACE_RING", 512, int,
+    "MXTPU_TRACE_RING", 12288, int,
     "Capacity of each flight-recorder ring (last N finished spans, "
-    "last N step-ledger records). Fixed at first use per process.")
+    "last N turn-ledger records: StepMeter commits, decode prefills). "
+    "The default keeps two minutes at 100 turns/s, about 14 MB of "
+    "host memory for a full ledger ring at ~1.2 KB a record (the span "
+    "ring fills only while MXTPU_TRACE_SAMPLE > 0). Fixed at first "
+    "use per process.")
 config.register(
     "MXTPU_TRACE_TRIGGER", "0", str,
     "Trigger-driven profiler capture: '1'/'auto' arms one bounded "

@@ -16,6 +16,7 @@ Optimizers here are optax transformations (idiomatic jax); the imperative
 from __future__ import annotations
 
 import re
+import time
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -32,6 +33,10 @@ from ..gluon.parameter import Parameter, _trace
 from ..gluon.block import _Trace
 from ..ndarray import NDArray
 from .mesh import DATA_AXIS, make_mesh
+
+#: phases of ``SPMDTrainer.step``'s turn-ledger record
+#: (docs/OBSERVABILITY.md "Phases and the turn ledger")
+_STEP_PHASES = ("h2d", "rng", "dispatch", "meter")
 
 
 def _to_optax(optimizer, optimizer_params: Optional[dict]):
@@ -403,14 +408,19 @@ class SPMDTrainer:
         # a supervised retry of a failed step is bit-identical
         from ..resilience import chaos
 
+        turn = telemetry.trace.Turn("spmd.step", _STEP_PHASES)
+        t0 = time.perf_counter()
         chaos.maybe_inject("step", detail="spmd")
         chaos.maybe_inject("step.slow", detail="spmd")
         data = data if isinstance(data, (list, tuple)) else [data]
         labels = labels if isinstance(labels, (list, tuple)) else [labels]
-        data_arrays = [jax.device_put(self._as_jax(d), self._batch_sharding)
-                       for d in data]
-        label_arrays = [jax.device_put(self._as_jax(l), self._batch_sharding)
-                        for l in labels]
+        with turn.phase("h2d"):
+            data_arrays = [jax.device_put(self._as_jax(d),
+                                          self._batch_sharding)
+                           for d in data]
+            label_arrays = [jax.device_put(self._as_jax(l),
+                                           self._batch_sharding)
+                            for l in labels]
         key = (tuple((a.shape, str(a.dtype)) for a in data_arrays),
                tuple((a.shape, str(a.dtype)) for a in label_arrays))
         fn = self._step_cache.get(key)
@@ -420,7 +430,8 @@ class SPMDTrainer:
                                 (data_arrays, label_arrays))
             self._step_cache[key] = fn
         self._num_steps += 1
-        rng = _random.next_key()
+        with turn.phase("rng"):
+            rng = _random.next_key()
         # trace/execute under the ambient-mesh scope so mesh-aware ops
         # (e.g. moe_ffn's expert-axis sharding constraint) see self.mesh
         from .mesh import mesh_scope
@@ -429,7 +440,8 @@ class SPMDTrainer:
         with telemetry.trace.span("spmd.step", step=self._num_steps), \
                 self._telemetry.step(
                 h2d_bytes=h2d,
-                flops_fn=lambda: self._flops_for(key, data, labels)):
+                flops_fn=lambda: self._flops_for(key, data, labels),
+                turn=turn):
             if miss:
                 # jax.monitoring-less fallback: the ragged-batch
                 # recompile this cache miss implies must still be seen.
@@ -437,11 +449,12 @@ class SPMDTrainer:
                 # marks this step compile-dominated (EMA/MFU exclusion)
                 # just like a real compile event would.
                 telemetry.note_cache_miss("spmd.step", detail=str(key[0]))
-            with mesh_scope(self.mesh):
+            with mesh_scope(self.mesh), turn.phase("dispatch"):
                 self.params, self.frozen, self.opt_state, loss = fn(
                     self.params, self.frozen, self.opt_state, rng,
                     data_arrays, label_arrays)
         self._note_wire(1)
+        turn.close(t0, time.perf_counter() - t0)
         return loss
 
     def _note_wire(self, k: int) -> None:

@@ -63,6 +63,13 @@ __all__ = ["DecodeHandle", "DecodeSession", "KVCache"]
 
 logger = logging.getLogger("mxtpu.serving")
 
+#: phases of the scheduler's turn-ledger records (docs/OBSERVABILITY.md
+#: "Phases and the turn ledger"); ``sched`` and ``idle`` are the time
+#: between two records, the rest lie inside the step or the prefill
+_STEP_PHASES = ("sched", "idle", "h2d", "dispatch", "fence", "meter",
+                "deliver", "finish")
+_PREFILL_PHASES = ("dispatch", "join", "fence")
+
 
 def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:
     """Prompt-length buckets from ``MXTPU_DECODE_BUCKETS`` clipped to the
@@ -326,7 +333,8 @@ class DecodeSession:
                            self.max_len, block.head_dim, dtype=dtype)
         self.metrics = DecodeMetrics(self.name)
         self.metrics.set_capacity(max_slots, self._kv.nbytes)
-        self._meter = telemetry.StepMeter(f"decode.{self.name}")
+        self._site = f"decode.{self.name}"
+        self._meter = telemetry.StepMeter(self._site)
         self._flops: Optional[float] = None
 
         self._joins: dict = {}
@@ -362,6 +370,12 @@ class DecodeSession:
         self._pending: deque = deque()
         self._cv = threading.Condition()
         self._state = "running"
+        # the scheduler's turn ledger: the next step's record is open
+        # from the moment the last record closed (``_t_mark``); what the
+        # scheduler does in between is that step's ``sched``, what it
+        # waits with nothing to run its ``idle``
+        self._turn = telemetry.trace.Turn(self._site, _STEP_PHASES)
+        self._t_mark = time.perf_counter()
         self._worker = threading.Thread(
             target=self._loop, name=f"mxtpu-decode-{self.name}",
             daemon=True)
@@ -805,7 +819,11 @@ class DecodeSession:
                     break
                 if self._state == "draining" and not self._pending:
                     return None, []
-                self._cv.wait(timeout=0.25)
+                t_wait = time.perf_counter()
+                self._turn.add("sched", t_wait - self._t_mark)
+                with self._turn.phase("idle"):
+                    self._cv.wait(timeout=0.25)
+                self._t_mark = time.perf_counter()
             shed: List[_Request] = []
             admits: List[Tuple[int, _Request]] = []
             now = time.monotonic()
@@ -832,19 +850,24 @@ class DecodeSession:
         through the length-bucketed cache, join the K/V planes into the
         slot's cache range, emit the first greedy token."""
         n = int(req.prompt.shape[0])
+        bucket = self._prefill.bucket_for(n)
         root = req.trace
+        turn = telemetry.trace.Turn(self._site, _PREFILL_PHASES)
         t0 = time.perf_counter()
-        with profiler.scope(f"decode::{self.name}::prefill"), \
-                telemetry.attribute(f"decode.{self.name}",
-                                    detail=f"prefill len={n}"):
-            first, k_pad, v_pad = self._prefill(req.prompt)
+        self._turn.add("sched", t0 - self._t_mark)
+        with telemetry.attribute(self._site, detail=f"prefill len={n}"):
+            with turn.phase("dispatch"):
+                first, k_pad, v_pad = self._prefill(req.prompt)
             t_pf1 = time.perf_counter()
-            join = self._join_exec(self._prefill.bucket_for(n))
-            self._kv.k, self._kv.v = join(self._kv.k, self._kv.v, k_pad,
-                                          v_pad, jnp.asarray(slot,
-                                                             jnp.int32))
-            first_tok = int(first)                    # the D2H fence
+            with turn.phase("join"):
+                join = self._join_exec(bucket)
+                self._kv.k, self._kv.v = join(
+                    self._kv.k, self._kv.v, k_pad, v_pad,
+                    jnp.asarray(slot, jnp.int32))
+            with turn.phase("fence"):
+                first_tok = int(first)                # the D2H fence
         t_fence = time.perf_counter()
+        self._t_mark = t_fence
         dt = t_fence - t0
         now = time.monotonic()
         if root is not None:
@@ -855,7 +878,7 @@ class DecodeSession:
             telemetry.trace.record(root, "queue", req.t_submit_p, t0,
                                    slot=slot)
             telemetry.trace.record(root, "prefill", t0, t_pf1,
-                                   bucket=self._prefill.bucket_for(n))
+                                   bucket=bucket)
             telemetry.trace.record(root, "join", t_pf1, t_fence)
         with self._cv:
             st = self._slots[slot]
@@ -864,7 +887,10 @@ class DecodeSession:
             self._cache_len[slot] = n
             self._tokens[slot] = first_tok
         st.generated = 1
-        self.metrics.observe_admit(st.t_admitted - req.t_submit, dt)
+        queue_wait = st.t_admitted - req.t_submit
+        self.metrics.observe_admit(queue_wait, dt)
+        turn.close(t0, dt, kind="prefill", bucket=bucket, prompt_len=n,
+                   queue_wait_s=queue_wait)
         self.metrics.observe_first_token(now - req.t_submit)
         if root is not None:
             # the measured TTFT on the SAME perf clock the segments use
@@ -892,22 +918,32 @@ class DecodeSession:
             cache_len = self._cache_len.copy()
             tokens = self._tokens.copy()
         k = len(active)
+        # this step takes the open turn; the next one opens here, so a
+        # step that fails leaves nothing of itself in a later record
+        turn, self._turn = self._turn, telemetry.trace.Turn(
+            self._site, _STEP_PHASES)
         t0 = time.perf_counter()
+        turn.add("sched", t0 - self._t_mark)
         with self._meter.step(
                 h2d_bytes=int(cache_len.nbytes + tokens.nbytes),
-                detail=f"active={k}", flops_fn=self._decode_flops):
-            with profiler.scope(f"decode::{self.name}::step"):
-                ex = self._decode_exec()
+                detail=f"active={k}", flops_fn=self._decode_flops,
+                turn=turn):
+            ex = self._decode_exec()
+            with turn.phase("h2d"):
+                cache_len_d = jnp.asarray(cache_len)
+                tokens_d = jnp.asarray(tokens)
+            with turn.phase("dispatch"):
                 nxt, self._kv.k, self._kv.v = ex(
-                    self._params, self._kv.k, self._kv.v,
-                    jnp.asarray(cache_len), jnp.asarray(tokens))
+                    self._params, self._kv.k, self._kv.v, cache_len_d,
+                    tokens_d)
+            with turn.phase("fence"):
                 nxt_np = np.asarray(nxt)              # the D2H fence
         t1 = time.perf_counter()
         dt = t1 - t0
         self.metrics.observe_step(k, dt, k)
         finished: List[int] = []
         first_steps: List[_Request] = []
-        with self._cv:
+        with turn.phase("deliver"), self._cv:
             for i in active:
                 st = self._slots[i]
                 if st is None:        # closed underneath us
@@ -927,8 +963,11 @@ class DecodeSession:
         for req in first_steps:
             telemetry.trace.record(req.trace, "first_step", t0, t1,
                                    active=k)
-        for i in finished:
-            self._finish_slot(i)
+        with turn.phase("finish"):
+            for i in finished:
+                self._finish_slot(i)
+        self._t_mark = time.perf_counter()
+        turn.close(t0, dt, active=k)
         self.metrics.observe_slots(self.active_slots)
 
     def _finish_slot(self, slot: int) -> None:

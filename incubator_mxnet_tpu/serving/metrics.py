@@ -26,7 +26,7 @@ from __future__ import annotations
 import math
 import threading
 from collections import deque
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 from .. import profiler
 from .. import telemetry
@@ -377,6 +377,12 @@ class DecodeMetrics:
         self._t_prefill_s.inc(prefill_s)
         self._t_queue_wait.observe(queue_wait_s)
         self._t_prefill_hist.observe(prefill_s)
+
+    def queue_waits(self) -> List[float]:
+        """Submit -> admission waits (seconds) of the last ``window``
+        admitted requests, oldest first (a copy)."""
+        with self._lock:
+            return list(self._queue_waits)
 
     def observe_first_token(self, ttft_s: float) -> None:
         with self._lock:

@@ -166,16 +166,17 @@ class _StepScope:
     compiles, commits instruments on exit."""
 
     __slots__ = ("meter", "h2d_bytes", "dispatches", "count", "flops_fn",
-                 "detail", "_t0", "_attr", "_compiles0", "record")
+                 "detail", "turn", "_t0", "_attr", "_compiles0", "record")
 
     def __init__(self, meter, h2d_bytes, dispatches, count, flops_fn,
-                 detail):
+                 detail, turn):
         self.meter = meter
         self.h2d_bytes = h2d_bytes
         self.dispatches = dispatches
         self.count = count
         self.flops_fn = flops_fn
         self.detail = detail
+        self.turn = turn
         self.record: Dict = {}
 
     def __enter__(self):
@@ -205,7 +206,15 @@ class _StepScope:
         dt = time.perf_counter() - self._t0
         self._attr.__exit__(exc_type, exc, tb)
         if exc_type is None:
-            self.meter._commit(self, dt, self._compiles0)
+            turn = self.turn
+            if turn is None:
+                self.meter._commit(self, dt, self._compiles0)
+            else:
+                # the commit is a phase of the caller's turn, and its
+                # record the turn's: close() adds t0/dur_s/phases to it
+                with turn.phase("meter"):
+                    self.meter._commit(self, dt, self._compiles0)
+                turn.rec = self.record
         return False
 
 
@@ -276,17 +285,20 @@ class StepMeter:
     # -- the hot-path API ---------------------------------------------------
     def step(self, h2d_bytes: int = 0, dispatches: int = 1,
              count: int = 1, flops_fn: Optional[Callable] = None,
-             detail: str = ""):
+             detail: str = "", turn=None):
         """Context manager around one step (or ``count`` fused steps —
         ``run_steps`` drives N device-side steps in one dispatch).
         ``flops_fn`` is a zero-arg callable returning per-step FLOPs (or
-        None); it is only called when MFU accounting is observed."""
+        None); it is only called when MFU accounting is observed.
+        ``turn`` is the caller's open ``trace.Turn`` (one with a phase
+        ``meter``): the commit is then timed as that phase and its
+        ledger record becomes the turn's."""
         from . import enabled
 
         if not enabled():
             return _NULL_CTX
         return _StepScope(self, int(h2d_bytes), int(dispatches),
-                          max(1, int(count)), flops_fn, detail)
+                          max(1, int(count)), flops_fn, detail, turn)
 
     # -- commit -------------------------------------------------------------
     def _commit(self, scope: _StepScope, dt: float,
@@ -337,7 +349,7 @@ class StepMeter:
             if "peak_bytes_in_use" in mem:
                 insts["mem_peak"].set(mem["peak_bytes_in_use"])
         rec = {"kind": "step", "site": self.site, "step": self._last_step,
-               "wall_ms": round(per * 1e3, 4),
+               "t0": scope._t0, "wall_ms": round(per * 1e3, 4),
                "dispatches": scope.dispatches,
                "h2d_bytes": scope.h2d_bytes}
         if scope.count > 1:
@@ -360,7 +372,7 @@ class StepMeter:
         jsonl_emit(rec)
         # flight recorder: every step commit lands in the always-on
         # ring (one deque append), so an incident dump carries the
-        # recent step ledger even with span sampling off
+        # recent turn ledger even with span sampling off
         from .trace import flight_step
 
         flight_step(rec)

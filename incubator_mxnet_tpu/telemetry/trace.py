@@ -18,11 +18,22 @@ its time". Three services on one spine:
   shared no-op ``NULL_SPAN`` — the same zero-cost-when-off contract as
   the NULL instruments. Finished spans flow to two sinks: the JSONL
   sink (``kind:"trace"`` records, next to steps/recompiles/bench rows)
-  and — while a profiling run is active — the chrome-trace stream, so
-  spans line up with host scopes and the XPlane trace on one timeline.
+  and — while a profiling run is active — the chrome-trace stream
+  (host scopes of ``profiler.py``; not the XPlane's clock).
+
+* **Phases and the turn ledger** — a :class:`Turn` is the open ledger
+  record of one unit of a thread's work (a decode step, a prefill, a
+  trainer step); ``turn.phase(name)`` times one part of it. A phase is a
+  ``jax.profiler.TraceAnnotation("mxtpu/<site>/<phase>")``, so while a
+  ``jax.profiler`` capture is live (whoever started it) the program's
+  phases sit on the thread's line of the XPlane's ``/host:CPU`` plane,
+  on the clock of the device ops; and its ``perf_counter`` duration is
+  added to the turn's record. No sampling decision, no lock, no
+  ``Span``. ``turn.close`` writes ``t0``, ``dur_s`` and ``phases``
+  into the always-on step ring below.
 
 * **Flight recorder** — a fixed-size ring of the last N finished spans
-  plus the last N step-ledger records (every ``StepMeter`` commit calls
+  plus the last N turn-ledger records (every ``StepMeter`` commit calls
   :func:`flight_step`; one deque append, always on). :func:`dump`
   writes the rings atomically (tmp + fsync + rename — the checkpoint
   commit idiom, so a torn dump never corrupts an earlier one) to
@@ -54,8 +65,8 @@ from typing import Any, Dict, List, Optional
 
 __all__ = [
     "NULL_SPAN", "Span", "SpanContext", "active_spans", "ctx", "dump",
-    "flight_step", "incident_dump", "note_latency", "record", "reset",
-    "ring", "span", "start", "trigger", "use",
+    "Turn", "flight_step", "incident_dump", "note_latency", "record",
+    "reset", "ring", "ring_capacity", "span", "start", "trigger", "use",
 ]
 
 _lock = threading.Lock()
@@ -350,7 +361,7 @@ def _ring_len() -> int:
     try:
         return max(16, int(_cfg("MXTPU_TRACE_RING")))
     except (TypeError, ValueError):
-        return 512
+        return 12288
 
 
 def _spans_ring() -> deque:
@@ -372,16 +383,22 @@ def _steps_ring() -> deque:
 
 
 def flight_step(rec: Dict) -> None:
-    """Append one step-ledger record (a ``StepMeter`` commit dict) to
-    the always-on ring. One deque append — cheap enough for every step
-    even with sampling off, which is what makes the black box useful in
-    the default configuration."""
+    """Append one turn-ledger record (a ``StepMeter`` commit dict, or a
+    :class:`Turn` with no meter of its own) to the always-on ring. One
+    deque append — cheap enough for every step even with sampling off,
+    which is what makes the black box useful in the default
+    configuration."""
     _steps_ring().append(rec)
 
 
 def ring() -> Dict[str, List[Dict]]:
     """The flight recorder's current contents (copies)."""
     return {"spans": list(_spans_ring()), "steps": list(_steps_ring())}
+
+
+def ring_capacity() -> int:
+    """Records each ring holds before it overwrites its oldest."""
+    return _steps_ring().maxlen
 
 
 def active_spans() -> List[Dict]:
@@ -464,6 +481,83 @@ def incident_dump(reason: str) -> Optional[str]:
         return dump(reason)
     except Exception:
         return None
+
+
+# -- phases and the turn ledger ---------------------------------------------
+_annotation = None
+
+
+def _trace_annotation(name: str):
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(name)
+
+
+class _Phase:
+    __slots__ = ("_phases", "_name", "_ann", "_t0")
+
+    def __init__(self, phases: Dict[str, float], name: str, label: str):
+        self._phases = phases
+        self._name = name
+        self._ann = _trace_annotation(label)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        self._phases[self._name] += dt
+        return False
+
+
+class Turn:
+    """The open ledger record of one unit of a thread's work at
+    ``site``: a decode step, a prefill, a trainer step. ``phases``
+    names every phase the record will carry (each starts at 0, so a
+    turn in which a phase never ran still reports it). Owned by the one
+    thread that runs the turn: no lock, no sampling, no ``Span``.
+
+    A turn whose work runs under ``StepMeter.step(turn=...)`` shares
+    the meter's record: the commit is timed as phase ``meter`` and
+    leaves its record in ``rec``, and :meth:`close` adds the turn's
+    fields to it in place. A turn with no meter appends a record of
+    its own."""
+
+    __slots__ = ("site", "phases", "rec", "_prefix")
+
+    def __init__(self, site: str, phases):
+        self.site = site
+        self.phases = dict.fromkeys(phases, 0.0)
+        self.rec: Optional[Dict] = None
+        self._prefix = f"mxtpu/{site}/"
+
+    def phase(self, name: str) -> _Phase:
+        """Context manager around one phase of the turn: a
+        ``TraceAnnotation`` (recorded only while a ``jax.profiler``
+        capture is live, whoever started it) whose ``perf_counter``
+        duration is added to ``phases[name]``."""
+        return _Phase(self.phases, name, self._prefix + name)
+
+    def add(self, name: str, seconds: float) -> None:
+        """Time the caller measured itself (between turns, by
+        difference of stamps it already takes)."""
+        self.phases[name] += seconds
+
+    def close(self, t0: float, dur_s: float, **fields) -> None:
+        """Write the turn into the ledger: ``t0`` and ``dur_s`` on the
+        ``perf_counter`` clock, ``phases``, and ``fields``. With
+        telemetry off nothing is written."""
+        if self.rec is not None:
+            self.rec.update(fields, t0=t0, dur_s=dur_s, phases=self.phases)
+        elif _telemetry_enabled():
+            flight_step(dict(fields, site=self.site, t0=t0, dur_s=dur_s,
+                             phases=self.phases))
 
 
 # -- trigger engine ---------------------------------------------------------

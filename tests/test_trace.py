@@ -605,3 +605,154 @@ def test_two_meters_on_one_site_keep_separate_gauges():
     # the shared-site histogram still aggregates both meters' steps
     h = reg.find("mxtpu_step_seconds", site="unit.shared")
     assert h is not None and h.count == 2
+
+
+# ---------------------------------------------------------------------------
+# phases and the turn ledger (ISSUE 25)
+# ---------------------------------------------------------------------------
+STEP_PHASES = {"sched", "idle", "h2d", "dispatch", "fence", "meter",
+               "deliver", "finish"}
+
+
+def _ledger(site):
+    return [r for r in trace.ring()["steps"] if r.get("site") == site]
+
+
+def test_phases_land_on_one_host_line_of_the_xplane(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    with serving.DecodeSession(_tiny_gpt(), max_slots=2, max_len=48,
+                               prefill_buckets=(8,), name="xp") as sess:
+        sess.submit(_prompts([5])[0], max_new_tokens=2).result(120)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            s0 = sess.metrics.steps
+            sess.submit(_prompts([6])[0], max_new_tokens=5).result(120)
+            steps = sess.metrics.steps - s0
+        finally:
+            jax.profiler.stop_trace()
+    found = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    assert len(found) == 1
+    lines = []
+    for plane in ProfileData.from_file(found[0]).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            names = [ev.name for ev in line.events
+                     if ev.name.startswith("mxtpu/decode.xp/")]
+            if names:
+                lines.append(names)
+    assert len(lines) == 1, "every phase on the scheduler thread's line"
+    assert steps >= 4
+    for phase in ("h2d", "dispatch", "fence", "deliver", "finish"):
+        assert lines[0].count(f"mxtpu/decode.xp/{phase}") >= steps
+    assert "mxtpu/decode.xp/join" in lines[0]
+
+
+def test_ledger_holds_every_turn_with_sampling_off():
+    assert float(config.get("MXTPU_TRACE_SAMPLE")) == 0.0
+    t_open = time.perf_counter()
+    with serving.DecodeSession(_tiny_gpt(), max_slots=2, max_len=48,
+                               prefill_buckets=(8, 16), name="led") as sess:
+        sess.warmup()
+        for p, n in zip(_prompts([5, 12, 7], seed=3), (4, 3, 5)):
+            sess.submit(p, max_new_tokens=n).result(120)
+    elapsed = time.perf_counter() - t_open
+    recs = _ledger("decode.led")
+    steps = [r for r in recs if r["kind"] == "step"]
+    prefills = [r for r in recs if r["kind"] == "prefill"]
+    assert trace.ring()["spans"] == []
+    assert len(steps) == sess.metrics.steps > 0
+    assert len(prefills) == sess.metrics.prefills == 3
+    for r in steps:
+        assert set(r["phases"]) == STEP_PHASES and r["active"] >= 1
+        inside = sum(r["phases"][k] for k in ("h2d", "dispatch", "fence",
+                                              "meter"))
+        assert 0 < inside <= r["dur_s"]
+    for r in prefills:
+        assert set(r["phases"]) == {"dispatch", "join", "fence"}
+        assert 0 < sum(r["phases"].values()) <= r["dur_s"]
+        assert r["bucket"] in (8, 16) and r["prompt_len"] <= r["bucket"]
+        assert r["queue_wait_s"] >= 0
+    # the records are stamped on one clock, in order, and the phases of
+    # one thread never overlap: together they fit into its lifetime
+    t0s = [r["t0"] for r in recs]
+    assert t0s == sorted(t0s)
+    assert sum(sum(r["phases"].values()) for r in recs) <= elapsed
+    # dur_s is the very interval the session's own counters sum
+    assert sum(r["dur_s"] for r in steps) == pytest.approx(
+        sess.metrics.decode_seconds, abs=1e-6)
+    assert sum(r["dur_s"] for r in prefills) == pytest.approx(
+        sess.metrics.prefill_seconds, abs=1e-6)
+    assert sess.metrics.queue_waits() == pytest.approx(
+        [r["queue_wait_s"] for r in prefills])
+
+
+def test_phases_construct_no_span(monkeypatch):
+    made = []
+    init = trace.Span.__init__
+
+    def counting(self, *a, **kw):
+        made.append(a)
+        init(self, *a, **kw)
+
+    monkeypatch.setattr(trace.Span, "__init__", counting)
+    with serving.DecodeSession(_tiny_gpt(), max_slots=2, max_len=48,
+                               prefill_buckets=(8,), name="nospan") as sess:
+        sess.submit(_prompts([5])[0], max_new_tokens=3).result(120)
+    tr = _trainer()
+    tr.step(np.random.rand(8, 8).astype(np.float32),
+            np.random.randint(0, 4, (8,)).astype(np.float32))
+    assert _ledger("decode.nospan") and _ledger("spmd.step")
+    assert made == []
+
+
+def test_an_empty_session_is_idle_not_scheduling():
+    with serving.DecodeSession(_tiny_gpt(), max_slots=2, max_len=48,
+                               prefill_buckets=(8,), name="idle") as sess:
+        sess.submit(_prompts([5])[0], max_new_tokens=2).result(120)
+        time.sleep(0.3)
+        sess.submit(_prompts([6])[0], max_new_tokens=2).result(120)
+    steps = [r for r in _ledger("decode.idle") if r["kind"] == "step"]
+    assert len(steps) == 2
+    waited = steps[1]["phases"]
+    assert waited["idle"] >= 0.25
+    assert waited["sched"] < 0.05
+
+
+def test_telemetry_off_leaves_the_ledger_empty():
+    config.set("MXTPU_TELEMETRY", False)
+    telemetry.reset()
+    with serving.DecodeSession(_tiny_gpt(), max_slots=2, max_len=48,
+                               prefill_buckets=(8,), name="off") as sess:
+        toks = sess.submit(_prompts([5])[0], max_new_tokens=3).result(120)
+    assert len(toks) == 3
+    assert trace.ring()["steps"] == []
+
+
+def test_spmd_step_writes_its_four_phases():
+    tr = _trainer()
+    rs = np.random.RandomState(0)
+    for _ in range(3):
+        tr.step(rs.rand(8, 8).astype(np.float32),
+                rs.randint(0, 4, (8,)).astype(np.float32))
+    recs = _ledger("spmd.step")
+    assert [r["step"] for r in recs] == [1, 2, 3]
+    for r in recs:
+        assert r["kind"] == "step" and r["t0"] > 0
+        assert set(r["phases"]) == {"h2d", "rng", "dispatch", "meter"}
+        assert all(v > 0 for v in r["phases"].values())
+        assert sum(r["phases"].values()) <= r["dur_s"]
+    # a meter with no turn still stamps its record
+    meter = telemetry.StepMeter("unit.noturn")
+    with meter.step():
+        pass
+    (rec,) = _ledger("unit.noturn")
+    assert rec["t0"] > 0 and "phases" not in rec
+
+
+def test_ring_default_keeps_two_minutes_of_turns():
+    assert trace.ring_capacity() == int(config.get("MXTPU_TRACE_RING"))
+    assert trace.ring_capacity() >= 12000
