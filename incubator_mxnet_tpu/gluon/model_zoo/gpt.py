@@ -14,10 +14,17 @@ but wired for BOTH halves of the decoder-LLM story:
 * **Serving**: ``prefill`` additionally returns the per-layer K/V planes
   so a serving tier can seed a device-resident KV cache, and
   ``decode_step`` advances EVERY slot of a ``[L, S, H, T, D]`` cache by
-  one token — the new token's K/V is written at its slot's fill level
-  via a vmapped ``dynamic_update_slice`` and attention reads exactly
+  one token and updates the cache WHERE IT LIES: layer ``i``'s attention
+  reads plane ``cache[i]`` (a static leading-axis slice) with the new
+  token's K/V row selected in at ``cache_len[slot]`` — the values a
+  write-then-read would see, bit for bit — over exactly
   ``[0, cache_len]`` through the ``cache_offset`` flash-attention path
-  (ops/pallas_attention.py). Because every shape is static in
+  (ops/pallas_attention.py), and once the last layer's row exists all
+  ``L`` rows of a slot are written straight into the stacked cache, one
+  ``dynamic_update_slice`` per slot and tensor. No plane is sliced out
+  and stacked back, so in the donated decode executable the output
+  caches alias the inputs and the only cache bytes a step writes are
+  the ``S`` new rows per layer. Because every shape is static in
   ``max_len``/slot count, ONE compiled decode executable serves any mix
   of sequence ages with zero recompiles (serving/decode.py builds it).
 
@@ -58,30 +65,63 @@ def _stack0(arrays):
                   name="stack_layers", differentiable=False)
 
 
-def _kv_cache_write(cache, new, total_lens):
-    """Write each slot's new K/V row at its fill position.
+def _kv_plane_with_row(cache, new, layer, total_lens):
+    """Layer ``layer``'s (S, H, T, D) plane of the stacked cache with
+    each slot's new row in place, WITHOUT writing it: ``cache``
+    (L, S, H, T, D), ``new`` (S, H, 1, D), ``total_lens`` (S,) valid
+    length per slot INCLUDING the new token. A select on the position
+    over a static leading-axis slice — both fuse into the attention
+    that reads the plane, which so sees exactly what it would read
+    after the row was written at ``total_lens - 1``."""
+    import jax.numpy as jnp
 
-    ``cache`` (S, H, T, D), ``new`` (S, H, 1, D), ``total_lens`` (S,)
-    valid length per slot INCLUDING the new token — the write lands at
-    ``total_lens - 1``. A vmapped ``dynamic_update_slice`` so the whole
-    batch updates in one fused op with per-slot indices; in the donated
-    decode executable XLA aliases input/output so this is an in-place
-    cache write, not a copy."""
-    import jax
+    from ...ndarray.ndarray import invoke
+
+    def plane(c, u, lens):
+        at = jnp.arange(c.shape[3], dtype=jnp.int32)[None, :] \
+            == lens.astype(jnp.int32)[:, None] - 1
+        return jnp.where(at[:, None, :, None], u, c[layer])
+
+    return invoke(plane, [cache, new, total_lens], name="kv_plane_with_row",
+                  differentiable=False)
+
+
+def _kv_cache_write(cache, rows, total_lens):
+    """Write every layer's new K/V rows into the stacked cache where it
+    lies.
+
+    ``cache`` (L, S, H, T, D); ``rows`` the ``L`` per-layer
+    (S, H, 1, D) rows; ``total_lens`` (S,) valid length per slot
+    INCLUDING the new token — slot ``s``'s rows land at
+    ``(:, s, :, total_lens[s] - 1, :)``. One ``dynamic_update_slice``
+    per slot, chained on the whole cache: they are the cache's only
+    writers in a step, so XLA updates the (donated) buffer in place and
+    nothing of the cache's or a plane's shape is copied out or stacked
+    back. (Measured on the v5e, PERF.md PR 26: the cache lies
+    ``T``-minor on the device, so a row touches 100 tiles and the
+    update's time goes by tiles touched, not by calls — one call per
+    slot for all layers costs the device what one per slot and layer
+    does, in a program that compiles and loads several times faster. A
+    scatter — which a vmapped
+    ``dynamic_update_slice`` also lowers to — makes the TPU compiler
+    relayout its whole operand around it.) The slot is static and the
+    position is CLAMPED into ``[0, T)`` by ``dynamic_update_slice``, so
+    a freed slot's stale ``cache_len`` of ``max_len`` neither faults
+    nor lands outside that slot's own (freed) rows."""
     import jax.numpy as jnp
     from jax import lax
 
     from ...ndarray.ndarray import invoke
 
-    def write(c, u, lens):
-        idx = lens.astype(jnp.int32) - 1
+    def write(c, lens, *us):
+        u = jnp.stack(us, axis=0)                     # (L, S, H, 1, D)
+        pos = lens.astype(jnp.int32) - 1
+        for s in range(c.shape[1]):
+            c = lax.dynamic_update_slice(c, u[:, s:s + 1],
+                                         (0, s, 0, pos[s], 0))
+        return c
 
-        def one(cs, us, i):
-            return lax.dynamic_update_slice(cs, us, (0, i, 0))
-
-        return jax.vmap(one)(c, u, idx)
-
-    return invoke(write, [cache, new, total_lens], name="kv_cache_write",
+    return invoke(write, [cache, total_lens, *rows], name="kv_cache_write",
                   differentiable=False)
 
 
@@ -126,25 +166,29 @@ class CausalSelfAttention(HybridBlock):
         out = out.transpose((0, 2, 1, 3)).reshape(b, t, self._units)
         return self.drop(self.proj(out)), k, v
 
-    def decode_step(self, x, k_cache, v_cache, total_lens):
-        """One-token decode over this layer's cache plane.
+    def decode_step(self, x, k_cache, v_cache, total_lens, layer):
+        """One-token decode of layer ``layer`` over the stacked cache.
 
         ``x`` (S, 1, C) — the new token's activations per slot;
-        ``k_cache``/``v_cache`` (S, H, T, D); ``total_lens`` (S,) valid
-        length per slot including the new token. Returns the attended
-        activations and the UPDATED cache planes (new K/V written at
-        ``total_lens - 1``; attention reads ``[0, total_lens)`` exactly
-        via the ``cache_offset`` path)."""
+        ``k_cache``/``v_cache`` (L, S, H, T, D), ALL layers, read and
+        not written here; ``total_lens`` (S,) valid length per slot
+        including the new token; ``layer`` this layer's (static) index.
+        Returns the attended activations and the new token's K/V rows
+        (S, H, 1, D) for the caller to write: attention reads the
+        layer's plane with those rows selected in at ``total_lens - 1``
+        over ``[0, total_lens)`` exactly via the ``cache_offset``
+        path."""
         from ...ndarray.ndarray import invoke_op
 
         q, k_new, v_new = self._project(x)
-        k_cache = _kv_cache_write(k_cache, k_new, total_lens)
-        v_cache = _kv_cache_write(v_cache, v_new, total_lens)
-        out = invoke_op("flash_attention", q, k_cache, v_cache, total_lens,
-                        cache_offset=True)
+        out = invoke_op(
+            "flash_attention", q,
+            _kv_plane_with_row(k_cache, k_new, layer, total_lens),
+            _kv_plane_with_row(v_cache, v_new, layer, total_lens),
+            total_lens, cache_offset=True)
         s, h, t, d = out.shape
         out = out.transpose((0, 2, 1, 3)).reshape(s, t, self._units)
-        return self.drop(self.proj(out)), k_cache, v_cache
+        return self.drop(self.proj(out)), k_new, v_new
 
 
 class GPTBlockCell(HybridBlock):
@@ -177,11 +221,11 @@ class GPTBlockCell(HybridBlock):
         x = x + a
         return x + self._ffn(self.ln2(x)), k, v
 
-    def decode_step(self, x, k_cache, v_cache, total_lens):
-        a, k_cache, v_cache = self.attn.decode_step(
-            self.ln1(x), k_cache, v_cache, total_lens)
+    def decode_step(self, x, k_cache, v_cache, total_lens, layer):
+        a, k_new, v_new = self.attn.decode_step(
+            self.ln1(x), k_cache, v_cache, total_lens, layer)
         x = x + a
-        return x + self._ffn(self.ln2(x)), k_cache, v_cache
+        return x + self._ffn(self.ln2(x)), k_new, v_new
 
 
 class GPTDecoder(HybridBlock):
@@ -263,9 +307,18 @@ class GPTDecoder(HybridBlock):
         input token per slot; ``k_cache``/``v_cache`` (L, S, H, T, D);
         ``cache_len`` (S,) tokens already cached per slot (the new token
         lands at that position). Returns ``logits`` (S, V) and the
-        updated caches. Slots whose entries are stale (free slots) still
-        compute — the scheduler ignores their rows; their writes land in
-        freed cache space."""
+        updated caches.
+
+        The stacked caches are read by every layer (plane ``i`` with
+        the new row selected in) and written once, after the last
+        layer: ``S`` rows per layer and tensor, nothing of the cache's
+        or a plane's shape is built beside them, so a donated
+        executable updates the cache where it lies
+        (``tests/test_decode.py`` pins the lowered program). Slots whose
+        entries are stale (free slots) still compute — the scheduler
+        ignores their rows; their writes land in their own freed rows,
+        also when a stale ``cache_len`` is ``max_len``
+        (``_kv_cache_write``: the position is clamped)."""
         s = tokens.shape[0]
         tok = tokens.reshape(s, 1)
         pos = cache_len.reshape(s, 1)
@@ -273,14 +326,13 @@ class GPTDecoder(HybridBlock):
         total = cache_len + 1
         new_k, new_v = [], []
         for i in range(self._layers):
-            k_l = k_cache.slice_axis(0, i, i + 1).squeeze(0)
-            v_l = v_cache.slice_axis(0, i, i + 1).squeeze(0)
             x, k_l, v_l = getattr(self, f"layer{i}").decode_step(
-                x, k_l, v_l, total)
+                x, k_cache, v_cache, total, i)
             new_k.append(k_l)
             new_v.append(v_l)
         logits = self.head(self.ln_f(x)).squeeze(1)
-        return logits, _stack0(new_k), _stack0(new_v)
+        return (logits, _kv_cache_write(k_cache, new_k, total),
+                _kv_cache_write(v_cache, new_v, total))
 
 
 #: GPT-2-family configs (117M/345M) plus a tiny config for tests/benches

@@ -70,6 +70,15 @@ _STEP_PHASES = ("sched", "idle", "h2d", "dispatch", "fence", "meter",
                 "deliver", "finish")
 _PREFILL_PHASES = ("dispatch", "join", "fence")
 
+#: revision of the PROGRAM the join and decode executables are lowered
+#: from, part of their artifact guard: the guard's other fields name the
+#: compiler, the device and the shapes, and an artifact persisted by an
+#: older program agrees with all of them. Bump it with any change to
+#: what ``_decode_apply``/``join`` compute or take, so that such an
+#: artifact is ``refused:program`` and recompiled, never deserialized
+#: (1, unwritten: the step that re-stacked the cache; 2: in place).
+_PROGRAM_REVISION = 2
+
 
 def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:
     """Prompt-length buckets from ``MXTPU_DECODE_BUCKETS`` clipped to the
@@ -89,8 +98,13 @@ class KVCache:
     """Device-resident per-slot KV planes ``[L, S, H, T, D]`` (k and v).
 
     Owned by a :class:`DecodeSession`; rebound on every donated
-    join/decode dispatch (XLA aliases the buffers in place on backends
-    with donation). Freed slots are not zeroed — their ranges are
+    join/decode dispatch. Both executables only ever
+    ``dynamic_update_slice`` into the stacked array (the join one
+    slot's prompt range, the decode step one row per slot and layer
+    after every layer has read its plane), so with donation on the TPU
+    their outputs alias their inputs and the cache is updated where it
+    lies; without donation (the CPU default) each dispatch copies it
+    once. Freed slots are not zeroed — their ranges are
     overwritten by the next prefill and never read in between
     (``cache_len`` guards every attention read)."""
 
@@ -351,7 +365,7 @@ class DecodeSession:
             environment_fingerprint(), model=self.name,
             fingerprint=params_fingerprint(self._params),
             version=str(model_version), donate=self._donate,
-            kv_shape=tuple(self._kv.shape),
+            program=_PROGRAM_REVISION, kv_shape=tuple(self._kv.shape),
             kv_dtype=self._kv.dtype.name)
         self.engine_metrics = ServingMetrics(f"{self.name}.engine")
         # live weight hot-swap: publishers stage off the hot path; the
@@ -484,6 +498,18 @@ class DecodeSession:
             self._joins[bucket] = ex
             return ex
 
+    def _lower_decode(self):
+        """The decode step lowered for this session's shapes: the
+        stacked caches donated (where the session donates), so the
+        compiled program's output caches alias its inputs."""
+        cache = jax.ShapeDtypeStruct(self._kv.shape, self._kv.dtype)
+        vec = jax.ShapeDtypeStruct((self.max_slots,), jnp.int32)
+        jitted = jax.jit(self._decode_apply,
+                         donate_argnums=(1, 2) if self._donate else ())
+        p_specs = [jax.ShapeDtypeStruct(p.shape, p.dtype)
+                   for p in self._params]
+        return jitted.lower(p_specs, cache, cache, vec, vec)
+
     def _decode_exec(self):
         """THE decode executable — built once (deserialized where a
         warm artifact exists); serves every mix of sequence ages and
@@ -493,21 +519,9 @@ class DecodeSession:
         with self._compile_lock:
             if self._dec_ex is not None:
                 return self._dec_ex
-
-            def compile_decode():
-                cache = jax.ShapeDtypeStruct(self._kv.shape,
-                                             self._kv.dtype)
-                vec = jax.ShapeDtypeStruct((self.max_slots,), jnp.int32)
-                jitted = jax.jit(self._decode_apply,
-                                 donate_argnums=(1, 2)
-                                 if self._donate else ())
-                p_specs = [jax.ShapeDtypeStruct(p.shape, p.dtype)
-                           for p in self._params]
-                return jitted.lower(p_specs, cache, cache, vec,
-                                    vec).compile()
-
             self._dec_ex = self._load_or_compile(
-                {"component": "decode"}, compile_decode)
+                {"component": "decode"},
+                lambda: self._lower_decode().compile())
             return self._dec_ex
 
     def _decode_flops(self) -> Optional[float]:

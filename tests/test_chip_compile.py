@@ -108,3 +108,50 @@ def test_fused_conv_bn_forward_and_backward_resnet50_shape(one_chip, hw, c):
 
     _compile(conv, x, w, ab, ab)
     _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), x, w, ab, ab)
+
+
+def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
+    """The served decode step at GPT-2 XL's widths (two layers of the
+    48), 16 slots of 1024 positions, donated as ``DecodeSession`` lowers
+    it: both caches alias their outputs, and outside fusions the only
+    ops whose result has the cache's or a layer plane's shape are the
+    in-place ``dynamic-update-slice`` of the new rows — no copy, no
+    relayout, no concatenate (a scatter in their place makes this
+    compiler relayout the whole cache around it)."""
+    import re
+
+    from incubator_mxnet_tpu import serving
+    from incubator_mxnet_tpu.gluon.model_zoo import get_gpt
+
+    layers, slots, heads, t, d = 2, 16, 25, 1024, 64
+    net = get_gpt("gpt_decoder_345m", num_layers=layers, units=heads * d,
+                  num_heads=heads, vocab_size=50257, max_length=t,
+                  dropout=0.0)
+    net.cast("bfloat16")
+    net.initialize()
+    with serving.DecodeSession(net, max_slots=slots, max_len=t,
+                               prefill_buckets=(256,), name="xl2",
+                               donate=True, artifact_dir="") as sess:
+        cache = _spec(one_chip, sess._kv.shape, sess._kv.dtype)
+        vec = _spec(one_chip, (slots,), jnp.int32)
+        params = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
+        compiled = jax.jit(sess._decode_apply, donate_argnums=(1, 2)).lower(
+            params, cache, cache, vec, vec).compile()
+    n = len(params)
+    text = compiled.as_text()
+    alias = re.search(r"input_output_alias=\{[^\n]*?\}, entry", text)
+    assert alias and f"{{1}}: ({n}, {{}}" in alias.group(0) \
+        and f"{{2}}: ({n + 1}, {{}}" in alias.group(0), "caches not aliased"
+    assert compiled.memory_analysis().alias_size_in_bytes >= sess._kv.nbytes
+    big = re.compile(r"bf16\[(%d,)?(1,)?%d,%d,%d,%d\]"
+                     % (layers, slots, heads, t, d))
+    beside, fused = {}, False
+    for line in text.splitlines():
+        if line and not line.startswith((" ", "}")):
+            fused = not line.startswith("ENTRY")   # only the entry's ops
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if not fused and m and big.match(m.group(1)) and m.group(2) not in (
+                "parameter", "bitcast", "get-tuple-element"):
+            beside[m.group(2)] = beside.get(m.group(2), 0) + 1
+    assert beside == {"dynamic-update-slice": 2 * slots}, beside

@@ -12,6 +12,7 @@ train (SuperStep + ZeRO-2) -> sharded checkpoint -> ``from_checkpoint``
 import os
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -129,6 +130,79 @@ def test_cache_capacity_finishes_and_frees_slot():
         # the slot came back: a second request is served, not starved
         got2 = sess.generate(_prompts([4])[0], max_new_tokens=3)
         assert len(got2) == 3
+
+
+# ---------------------------------------------------------------------------
+# the cache is updated where it lies: the lowered program, and stale slots
+# ---------------------------------------------------------------------------
+def test_decode_step_builds_nothing_of_the_caches_shape():
+    """The decode step as ``DecodeSession`` lowers it never
+    concatenates planes back into a cache-shaped value, and its temp
+    memory does not grow with the cache: under two layers' K/V planes
+    and under one tensor's whole cache (the step that sliced 6 planes
+    out and stacked them back needed 5.6 layers' planes here, 0.93 of
+    the K and V cache). Lowered as the CPU serves it, without donation:
+    with it, XLA's CPU compiler copies the cache once ahead of the
+    writes (the reads of the last layer's attention are not ordered
+    before them by data), where the TPU's updates it in place
+    (``tests/test_chip_compile.py``)."""
+    net = _tiny_net(dropout=0.0, max_length=64, layers=6)
+    with serving.DecodeSession(net, max_slots=8, max_len=64,
+                               prefill_buckets=(8,), name="inplace",
+                               donate=False) as sess:
+        lowered = sess._lower_decode()
+        kv = sess._kv
+        mlir_shape = "tensor<" + "x".join(str(d) for d in kv.shape) + "x"
+        joins = [ln for ln in lowered.as_text().splitlines()
+                 if "concatenate" in ln and mlir_shape in ln.split("->")[-1]]
+        assert joins == [], "a cache-shaped concatenate is back"
+        try:
+            temp = lowered.compile().memory_analysis().temp_size_in_bytes
+        except Exception:           # noqa: BLE001 — backend has none
+            pytest.skip("no memory_analysis() on this backend")
+        layer_pair = kv.nbytes // kv.shape[0]    # one layer's K and V
+        assert temp < 2 * layer_pair
+        assert temp < kv.nbytes // 2
+
+
+def test_stale_full_slot_leaves_other_slots_rows_alone():
+    """A freed slot still computes. With a stale ``cache_len`` of
+    ``max_len`` its rows are clamped into its OWN last position: the step
+    does not fault, and every other slot's planes and tokens are
+    bit-identical to a step in which that slot's entry was 0."""
+    net = _tiny_net(dropout=0.0)
+    T, stale = 48, 1
+    with serving.DecodeSession(net, max_slots=4, max_len=T,
+                               prefill_buckets=(8,), name="stale") as sess:
+        rs = np.random.RandomState(11)
+        k0 = rs.standard_normal(sess._kv.shape).astype(np.float32)
+        v0 = rs.standard_normal(sess._kv.shape).astype(np.float32)
+        tokens = rs.randint(1, VOCAB, (4,)).astype(np.int32)
+        step = jax.jit(sess._decode_apply)
+
+        def run(stale_len):
+            lens = np.array([3, stale_len, 7, 0], np.int32)
+            nxt, k, v = step(sess._params, k0, v0, lens, tokens)
+            return np.asarray(nxt), np.asarray(k), np.asarray(v), lens
+
+        nxt_a, k_a, v_a, lens = run(T)
+        nxt_b, k_b, v_b, _ = run(0)
+        others = [s for s in range(4) if s != stale]
+        # (the stale slot's own new row holds whatever position
+        # ``max_len``, outside the table, embeds to)
+        assert np.isfinite(k_a[:, others]).all()
+        assert np.isfinite(v_a[:, others]).all()
+        for got, ref, src in ((k_a, k_b, k0), (v_a, v_b, v0)):
+            np.testing.assert_array_equal(got[:, others], ref[:, others])
+            for s in others:        # only the new token's row moved
+                keep = np.arange(T) != lens[s]
+                np.testing.assert_array_equal(got[:, s][:, :, keep],
+                                              src[:, s][:, :, keep])
+                assert (got[:, s, :, lens[s]] != src[:, s, :, lens[s]]).any()
+            # the stale slot wrote inside its own rows: the last one
+            np.testing.assert_array_equal(got[:, stale, :, :T - 1],
+                                          src[:, stale, :, :T - 1])
+        np.testing.assert_array_equal(nxt_a[others], nxt_b[others])
 
 
 # ---------------------------------------------------------------------------

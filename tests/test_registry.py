@@ -266,6 +266,63 @@ def test_decode_artifact_guard_covers_cache_shape(tmp_path):
         s2.close()
 
 
+@pytest.mark.parametrize("stored", ["absent", 1])
+def test_decode_artifact_of_older_program_refused(tmp_path, monkeypatch,
+                                                  stored):
+    """A decode artifact persisted by an older PROGRAM (the parent
+    commit wrote no ``program`` field; a later one writes a lower
+    revision) agrees with the guard on compiler, device and shapes. It
+    is refused by the field's name and recompiled, never deserialized
+    into the session."""
+    from jax.experimental import serialize_executable
+
+    net = _tiny_gpt()
+    d = str(tmp_path / "art")
+    prompt = np.random.RandomState(5).randint(
+        1, VOCAB, (6,)).astype(np.int32)
+    want = _gpt_oracle(net, prompt, 5)
+    kw = dict(max_slots=2, max_len=32, prefill_buckets=(8,),
+              artifact_dir=d, name="gpt")
+    with serving.DecodeSession(net, **kw) as s1:
+        s1.warmup()
+        guard = dict(s1._guard)
+    assert guard["program"] == serving.decode._PROGRAM_REVISION
+
+    store = ArtifactStore(d)
+    path = store.path_for("gpt", {"component": "decode"})
+    with open(path, "rb") as f:
+        rec = pickle.load(f)
+    if stored == "absent":
+        del rec["guard"]["program"]
+    else:
+        rec["guard"]["program"] = stored
+    with open(path, "wb") as f:
+        pickle.dump(rec, f)
+    assert store.load("gpt", {"component": "decode"}, guard) == \
+        (None, "refused:program")
+
+    loaded = []
+    real = serialize_executable.deserialize_and_load
+
+    def spy(*a, **k):
+        ex = real(*a, **k)
+        loaded.append(ex)
+        return ex
+
+    monkeypatch.setattr(serialize_executable, "deserialize_and_load", spy)
+    with serving.DecodeSession(net, **kw) as s2:
+        s2.warmup()
+        assert s2.engine_metrics.artifact_refused == 1
+        assert s2.engine_metrics.compiles == 1          # the decode
+        assert s2.engine_metrics.artifact_hits == 1     # the join
+        assert all(ex is not s2._dec_ex for ex in loaded)
+        assert s2.generate(prompt, max_new_tokens=5) == want
+    monkeypatch.undo()
+    # compile-and-repersist: the next replica warms from the new one
+    ex, reason = store.load("gpt", {"component": "decode"}, guard)
+    assert reason == "ok" and ex is not None
+
+
 # ---------------------------------------------------------------------------
 # live weight hot-swap
 # ---------------------------------------------------------------------------
