@@ -277,6 +277,34 @@ class GPTDecoder(HybridBlock):
     def vocab_size(self):
         return self._vocab
 
+    #: integers a decode step returns beside the logits: none here
+    step_counters = ()
+
+    def cache_groups(self, max_len):
+        """The K/V cache this block is served with (docs/SERVING.md "What
+        a block declares"): one group, every layer ``max_len`` rows."""
+        return [dict(layers=self._layers, heads=self._heads,
+                     rows=int(max_len), head_dim=self.head_dim,
+                     kind="full")]
+
+    def serve_prefill(self, tokens, n):
+        """``prefill`` of one padded prompt ``tokens`` (T,) as the serving
+        tier takes it: the logits at the last TRUE position ``n - 1``
+        (``n`` a traced scalar) and the K/V planes ``[L, H, T, D]``."""
+        import jax
+
+        from ...ndarray.ndarray import invoke
+
+        logits, k, v = self.prefill(tokens.reshape(1, -1))
+        return invoke(
+            lambda lg, k_, v_, n_: (jax.lax.dynamic_index_in_dim(
+                lg[0], n_ - 1, axis=0, keepdims=False), k_[:, 0], v_[:, 0]),
+            [logits, k, v, n], name="serve_prefill", differentiable=False)
+
+    def serve_step(self, tokens, cache_len, k_cache, v_cache):
+        """``decode_step`` in the serving tier's argument order."""
+        return self.decode_step(tokens, k_cache, v_cache, cache_len)
+
     def _embed(self, tokens, positions):
         return self.embed_dropout(self.word_embed(tokens)
                                   + self.position_embed(positions))

@@ -14,10 +14,20 @@ Capacity semantics: each expert processes at most
 dropped (contribute zero for that expert choice), matching Switch/GShard.
 Priority is choice-major (all tokens' first choices queue before any
 second choice).
+
+Beside it, the dropless form a served sparse decoder uses
+(``moe_route`` + ``moe_held_ffn``): the router scores every expert of the
+layer, the layer is told which contiguous share of them it holds, and it
+computes that share's part of the result for every token that chose one
+of them: the token-choices are sorted by held expert and each projection
+is ONE grouped product (``jax.lax.ragged_dot``) over the sorted rows.
+No capacity, nothing dropped; what the experts held elsewhere would add
+is not computed and not stood in for.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -123,3 +133,68 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, k=2, capacity_factor=1.25,
     out = _expert_constraint(out)
     y = jnp.einsum("nec,ecd->nd", combine, out)
     return y.reshape(orig_shape), aux_loss.astype(jnp.float32)
+
+
+def moe_route(logits, k, bias=None, scale=1.0):
+    """The ``k`` experts each token chooses and the weights of the choice.
+
+    ``logits`` (N, E) float32 over ALL the layer's experts. Scores are
+    ``sigmoid(logits)``; the choice is the ``k`` largest of ``scores +
+    bias`` (``bias`` (E,): used for the choice only); the weights are the
+    chosen scores over their sum, times ``scale``. Returns ``(idx (N, k)
+    int32, weights (N, k) f32)``.
+    """
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    pick = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(pick, int(k))
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * float(scale)
+
+
+def moe_held_ffn(x, idx, weights, w_gate, w_up, w_down, first_expert=0,
+                 live=None):
+    """The held experts' part of a sparse SiLU-gated FFN, dropless.
+
+    ``x`` (N, C); ``idx``/``weights`` (N, k) from :func:`moe_route` (ids
+    over all the layer's experts); ``w_gate``/``w_up`` (E, C, F) and
+    ``w_down`` (E, F, C) the ``E`` experts held here, which are experts
+    ``first_expert .. first_expert + E - 1`` of the layer. The N x k
+    token-choices are sorted by held expert (those that fell on experts
+    held elsewhere go last and belong to no group) and each projection is
+    one grouped product over the sorted rows; every token is served by
+    every held expert it chose. Returns ``(y (N, C) float32, counts)``;
+    ``counts`` are int32 scalars of the routing: ``routed_here``
+    (token-choices on held experts), ``experts_hit`` (held experts with
+    at least one token), ``load_max`` (most tokens on one held expert),
+    over the tokens where ``live`` (N,) is true (all, by default).
+    """
+    n, k = idx.shape
+    e = w_gate.shape[0]
+    local = idx - int(first_expert)
+    held = (local >= 0) & (local < e)
+    key = jnp.where(held, local, e).reshape(-1)            # (N*k,)
+    order = jnp.argsort(key, stable=True)
+    hits = jax.nn.one_hot(key, e + 1, dtype=jnp.int32)      # (N*k, E+1)
+    sizes = hits.sum(0)[:e]
+    rows = jnp.take(x, order // k, axis=0)                  # (N*k, C)
+    # 16-bit operands hold nothing a higher contract precision could
+    # recover, and the TPU's ragged-dot kernel refuses the pair ("Bad lhs
+    # type" under an ambient ``highest``): ask for what they can give
+    low = jnp.dtype(x.dtype).itemsize < 4
+    grouped = functools.partial(
+        jax.lax.ragged_dot, group_sizes=sizes,
+        precision=jax.lax.Precision.DEFAULT if low else None)
+    h = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+    out = grouped(h, w_down, preferred_element_type=jnp.float32)
+    # back to (token, choice) order. Rows of no group are NOT computed:
+    # the TPU's kernel leaves whatever lay there (NaN included), so they
+    # are selected out, never multiplied by a zero weight
+    back = jnp.take(out, jnp.argsort(order), axis=0).reshape(n, k, -1)
+    back = jnp.where(held[..., None], back, 0.0)
+    y = jnp.einsum("nkc,nk->nc", back, weights)
+    load = sizes if live is None else \
+        (hits * live.astype(jnp.int32).repeat(k)[:, None]).sum(0)[:e]
+    counts = {"routed_here": load.sum(), "experts_hit": (load > 0).sum(),
+              "load_max": load.max()}
+    return y, {name: v.astype(jnp.int32) for name, v in counts.items()}

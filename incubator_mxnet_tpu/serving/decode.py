@@ -2,7 +2,9 @@
 
 The serving half of the decoder-LLM workload (ISSUE 12): a
 **prefill/decode split** over a slot-based, device-resident KV cache
-``[layers, slots, heads, max_len, head_dim]``, in the full-AOT stance of
+(one ``[layers, slots, heads, rows, head_dim]`` array pair per group of
+layers the block declares: ``max_len`` rows, or a ring of a window's
+rows), in the full-AOT stance of
 arXiv:1810.09868 / arXiv:1605.08695 — a small FIXED set of pre-compiled
 executables with ALL dynamism carried as device-resident state or tiny
 per-step host vectors, never as recompilation:
@@ -15,7 +17,9 @@ per-step host vectors, never as recompilation:
 * **Join** (one tiny executable per bucket) writes a prefilled plane
   into a slot's cache range with ``lax.dynamic_update_slice`` at a
   TRACED slot index — any free slot, no recompile — donating the cache
-  so the write aliases in place.
+  so the write aliases in place. Into a ring it writes the last ``rows``
+  positions below the prompt's TRUE length, each at ``position mod
+  rows``.
 * **Decode** is ONE donated executable over the whole cache: every
   step advances EVERY slot one token; per-slot ``cache_len`` (a host
   int32 vector, H2D per step) makes the single program serve any mix
@@ -76,8 +80,9 @@ _PREFILL_PHASES = ("dispatch", "join", "fence")
 #: older program agrees with all of them. Bump it with any change to
 #: what ``_decode_apply``/``join`` compute or take, so that such an
 #: artifact is ``refused:program`` and recompiled, never deserialized
-#: (1, unwritten: the step that re-stacked the cache; 2: in place).
-_PROGRAM_REVISION = 2
+#: (1, unwritten: the step that re-stacked the cache; 2: in place; 3:
+#: cache groups, the join takes ``[slot, true length]``).
+_PROGRAM_REVISION = 3
 
 
 def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:
@@ -95,11 +100,19 @@ def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:
 
 
 class KVCache:
-    """Device-resident per-slot KV planes ``[L, S, H, T, D]`` (k and v).
+    """Device-resident per-slot K/V planes, one stacked array pair
+    ``[Lg, S, H, rows, D]`` (k and v) per GROUP of layers.
+
+    ``groups`` is what the served block declares (``cache_groups``): per
+    group ``layers``, ``heads`` (K/V heads), ``rows``, ``head_dim`` and
+    ``kind``: ``"full"`` keeps a position at its own row, ``"ring"``
+    keeps the last ``rows`` positions, position ``p`` at row ``p mod
+    rows``. A group of no layer holds nothing. ``arrays`` is the flat
+    list the executables take and return: k then v, group by group.
 
     Owned by a :class:`DecodeSession`; rebound on every donated
     join/decode dispatch. Both executables only ever
-    ``dynamic_update_slice`` into the stacked array (the join one
+    ``dynamic_update_slice`` into the stacked arrays (the join one
     slot's prompt range, the decode step one row per slot and layer
     after every layer has read its plane), so with donation on the TPU
     their outputs alias their inputs and the cache is updated where it
@@ -108,25 +121,60 @@ class KVCache:
     overwritten by the next prefill and never read in between
     (``cache_len`` guards every attention read)."""
 
-    def __init__(self, num_layers: int, slots: int, num_heads: int,
-                 max_len: int, head_dim: int, dtype="float32"):
-        self.shape = (int(num_layers), int(slots), int(num_heads),
-                      int(max_len), int(head_dim))
+    def __init__(self, groups, slots: int, dtype="float32"):
+        self.groups = [dict(g) for g in groups if int(g["layers"])]
         self.dtype = jnp.dtype(dtype)
-        self.k = jax.device_put(jnp.zeros(self.shape, self.dtype))
-        self.v = jax.device_put(jnp.zeros(self.shape, self.dtype))
+        self.shapes = [(int(g["layers"]), int(slots), int(g["heads"]),
+                        int(g["rows"]), int(g["head_dim"]))
+                       for g in self.groups]
+        self.arrays = [jax.device_put(jnp.zeros(shape, self.dtype))
+                       for shape in self.shapes for _ in "kv"]
+
+    # a cache of one group (every model before the window layers) reads
+    # as the one array pair it is
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        (shape,) = self.shapes
+        return shape
+
+    @property
+    def k(self):
+        return self.arrays[0]
+
+    @property
+    def v(self):
+        return self.arrays[1]
+
+    def specs(self) -> list:
+        """``jax.ShapeDtypeStruct`` of every array, in ``arrays`` order."""
+        return [jax.ShapeDtypeStruct(shape, self.dtype)
+                for shape in self.shapes for _ in "kv"]
 
     @property
     def slots(self) -> int:
-        return self.shape[1]
+        return self.shapes[0][1]
 
     @property
     def max_len(self) -> int:
-        return self.shape[3]
+        return max(shape[3] for shape in self.shapes)
 
     @property
     def nbytes(self) -> int:
-        return 2 * int(np.prod(self.shape)) * self.dtype.itemsize
+        return 2 * self.dtype.itemsize * sum(
+            int(np.prod(shape)) for shape in self.shapes)
+
+    @property
+    def rows(self) -> int:
+        """Rows the cache holds in all: layers x slots x rows, summed
+        over the groups."""
+        return sum(l * s * r for l, s, _, r, _ in self.shapes)
+
+    def live_rows(self, lens) -> int:
+        """Of those, the rows that hold a position of a sequence whose
+        cached length is in ``lens`` (one entry per active slot)."""
+        lens = np.asarray(lens, np.int64)
+        return int(sum(l * np.minimum(lens, r).sum()
+                       for l, _, _, r, _ in self.shapes))
 
 
 _DONE = object()
@@ -271,9 +319,12 @@ class _Active:
 class DecodeSession:
     """Continuous-batching autoregressive serving over one decoder block.
 
-    ``block`` is a :class:`~..gluon.model_zoo.gpt.GPTDecoder`-shaped
-    gluon block (``prefill``/``decode_step``/``num_layers``/
-    ``num_heads``/``head_dim`` surface), parameters initialized. Greedy
+    ``block`` is a gluon block that declares what it is served with
+    (``cache_groups``/``serve_prefill``/``serve_step``/``step_counters``:
+    docs/SERVING.md "What a block declares";
+    :class:`~..gluon.model_zoo.gpt.GPTDecoder` and
+    :class:`~..gluon.model_zoo.decoder.HybridDecoder` do), parameters
+    initialized. Greedy
     decoding (argmax) — the contract that makes the output stream
     bit-exact against the full-sequence forward oracle.
 
@@ -343,8 +394,9 @@ class DecodeSession:
         self._prefill.param_names = self._param_names
 
         dtype = self._params[0].dtype
-        self._kv = KVCache(block.num_layers, max_slots, block.num_heads,
-                           self.max_len, block.head_dim, dtype=dtype)
+        self._kv = KVCache(block.cache_groups(self.max_len), max_slots,
+                           dtype=dtype)
+        self._counters = tuple(block.step_counters)
         self.metrics = DecodeMetrics(self.name)
         self.metrics.set_capacity(max_slots, self._kv.nbytes)
         self._site = f"decode.{self.name}"
@@ -365,7 +417,7 @@ class DecodeSession:
             environment_fingerprint(), model=self.name,
             fingerprint=params_fingerprint(self._params),
             version=str(model_version), donate=self._donate,
-            program=_PROGRAM_REVISION, kv_shape=tuple(self._kv.shape),
+            program=_PROGRAM_REVISION, kv_shape=tuple(self._kv.shapes),
             kv_dtype=self._kv.dtype.name)
         self.engine_metrics = ServingMetrics(f"{self.name}.engine")
         # live weight hot-swap: publishers stage off the hot path; the
@@ -416,20 +468,26 @@ class DecodeSession:
 
     # -- the compiled executable set -----------------------------------------
     def _prefill_apply(self, pvals, tokens, n):
-        """(first greedy token, k/v planes [L, H, Lb, D]) of one padded
-        prompt; ``n`` is the TRUE prompt length (traced), so the greedy
-        read indexes the last valid position without a per-length
-        executable."""
-        logits, k, v = self._run(self._block.prefill, pvals, tokens[None])
-        last = jax.lax.dynamic_index_in_dim(logits[0], n - 1, axis=0,
-                                            keepdims=False)
-        first = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        return first, k[:, 0], v[:, 0]
+        """(first greedy token, each cache group's k/v planes
+        ``[Lg, H, Lb, D]``) of one padded prompt; ``n`` is the TRUE
+        prompt length (traced), so the greedy read indexes the last
+        valid position without a per-length executable."""
+        last, *planes = self._run(self._block.serve_prefill, pvals, tokens,
+                                  n)
+        return (jnp.argmax(last, axis=-1).astype(jnp.int32), *planes)
 
-    def _decode_apply(self, pvals, k, v, cache_len, tokens):
-        logits, k2, v2 = self._run(self._block.decode_step, pvals, tokens,
-                                   k, v, cache_len)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), k2, v2
+    def _decode_apply(self, pvals, *args):
+        """``(pvals, *caches, cache_len, tokens)`` -> ``(out, *caches)``:
+        ``out`` (S,) int32 holds the greedy next token of every slot and,
+        behind them, the block's ``step_counters`` (one fetch brings
+        both)."""
+        *caches, cache_len, tokens = args
+        logits, *rest = self._run(self._block.serve_step, pvals, tokens,
+                                  cache_len, *caches)
+        out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if self._counters:
+            out = jnp.concatenate([out, rest.pop(0).astype(jnp.int32)])
+        return (out, *rest)
 
     def _load_or_compile(self, logical: dict, compile_fn):
         """Artifact-or-compile for one engine executable (caller holds
@@ -461,10 +519,15 @@ class DecodeSession:
         return ex
 
     def _join_exec(self, bucket: int):
-        """The per-bucket cache-join executable: writes a prefilled
-        ``[L, H, Lb, D]`` plane into slot ``slot``'s cache range at
-        position 0 (``dynamic_update_slice`` with a TRACED slot index —
-        one executable serves every slot). Cache operands are donated."""
+        """The per-bucket cache-join executable: writes each group's
+        prefilled ``[Lg, H, Lb, D]`` plane into slot ``slot``'s cache
+        range (``dynamic_update_slice`` with a TRACED slot index — one
+        executable serves every slot). A full group takes the plane at
+        position 0; a ring takes the last ``rows`` positions below the
+        prompt's TRUE length ``n``, position ``p`` at row ``p mod rows``
+        (rows no position below ``n`` maps to hold garbage that
+        ``cache_len`` masks). ``at`` is ``[slot, n]``. Cache operands are
+        donated."""
         ex = self._joins.get(bucket)
         if ex is not None:
             return ex
@@ -472,25 +535,35 @@ class DecodeSession:
             ex = self._joins.get(bucket)
             if ex is not None:
                 return ex
+            kinds = [g["kind"] for g in self._kv.groups for _ in "kv"]
+            n_arrays = len(kinds)
 
             def compile_join():
-                def join(kc, vc, kp, vp, slot):
-                    at = (0, slot, 0, 0, 0)
-                    return (jax.lax.dynamic_update_slice(kc, kp[:, None],
-                                                         at),
-                            jax.lax.dynamic_update_slice(vc, vp[:, None],
-                                                         at))
+                def join(*args):
+                    caches, planes = args[:n_arrays], args[n_arrays:-1]
+                    slot, n = args[-1][0], args[-1][1]
+                    out = []
+                    for cache, plane, kind in zip(caches, planes, kinds):
+                        if kind == "ring":
+                            rows = cache.shape[3]
+                            r = jnp.arange(rows, dtype=jnp.int32)
+                            p = n - 1 - (n - 1 - r) % rows
+                            plane = jnp.take(
+                                plane, jnp.clip(p, 0, plane.shape[2] - 1),
+                                axis=2)
+                        out.append(jax.lax.dynamic_update_slice(
+                            cache, plane[:, None], (0, slot, 0, 0, 0)))
+                    return tuple(out)
 
-                l, s, h, t, d = self._kv.shape
-                cache = jax.ShapeDtypeStruct(self._kv.shape,
-                                             self._kv.dtype)
-                plane = jax.ShapeDtypeStruct((l, h, bucket, d),
-                                             self._kv.dtype)
-                slot = jax.ShapeDtypeStruct((), jnp.int32)
-                jitted = jax.jit(join, donate_argnums=(0, 1)
+                planes = [jax.ShapeDtypeStruct((l, h, bucket, d),
+                                               self._kv.dtype)
+                          for l, _, h, _, d in self._kv.shapes
+                          for _ in "kv"]
+                at = jax.ShapeDtypeStruct((2,), jnp.int32)
+                jitted = jax.jit(join, donate_argnums=tuple(range(n_arrays))
                                  if self._donate else ())
-                return jitted.lower(cache, cache, plane, plane,
-                                    slot).compile()
+                return jitted.lower(*self._kv.specs(), *planes,
+                                    at).compile()
 
             ex = self._load_or_compile(
                 {"component": "join", "bucket": int(bucket)},
@@ -499,16 +572,17 @@ class DecodeSession:
             return ex
 
     def _lower_decode(self):
-        """The decode step lowered for this session's shapes: the
-        stacked caches donated (where the session donates), so the
-        compiled program's output caches alias its inputs."""
-        cache = jax.ShapeDtypeStruct(self._kv.shape, self._kv.dtype)
+        """The decode step lowered for this session's shapes: every
+        cache array donated (where the session donates), so the compiled
+        program's output caches alias its inputs."""
+        caches = self._kv.specs()
         vec = jax.ShapeDtypeStruct((self.max_slots,), jnp.int32)
         jitted = jax.jit(self._decode_apply,
-                         donate_argnums=(1, 2) if self._donate else ())
+                         donate_argnums=tuple(range(1, 1 + len(caches)))
+                         if self._donate else ())
         p_specs = [jax.ShapeDtypeStruct(p.shape, p.dtype)
                    for p in self._params]
-        return jitted.lower(p_specs, cache, cache, vec, vec)
+        return jitted.lower(p_specs, *caches, vec, vec)
 
     def _decode_exec(self):
         """THE decode executable — built once (deserialized where a
@@ -871,13 +945,15 @@ class DecodeSession:
         self._turn.add("sched", t0 - self._t_mark)
         with telemetry.attribute(self._site, detail=f"prefill len={n}"):
             with turn.phase("dispatch"):
-                first, k_pad, v_pad = self._prefill(req.prompt)
+                first, *planes = self._prefill(req.prompt)
             t_pf1 = time.perf_counter()
             with turn.phase("join"):
                 join = self._join_exec(bucket)
-                self._kv.k, self._kv.v = join(
-                    self._kv.k, self._kv.v, k_pad, v_pad,
-                    jnp.asarray(slot, jnp.int32))
+                # a numpy vector: the executable takes it as it is, and
+                # building a jax array from a list would compile
+                self._kv.arrays = list(join(
+                    *self._kv.arrays, *planes,
+                    np.asarray([slot, n], np.int32)))
             with turn.phase("fence"):
                 first_tok = int(first)                # the D2H fence
         t_fence = time.perf_counter()
@@ -947,9 +1023,8 @@ class DecodeSession:
                 cache_len_d = jnp.asarray(cache_len)
                 tokens_d = jnp.asarray(tokens)
             with turn.phase("dispatch"):
-                nxt, self._kv.k, self._kv.v = ex(
-                    self._params, self._kv.k, self._kv.v, cache_len_d,
-                    tokens_d)
+                nxt, *self._kv.arrays = ex(
+                    self._params, *self._kv.arrays, cache_len_d, tokens_d)
             with turn.phase("fence"):
                 nxt_np = np.asarray(nxt)              # the D2H fence
         t1 = time.perf_counter()
@@ -981,7 +1056,14 @@ class DecodeSession:
             for i in finished:
                 self._finish_slot(i)
         self._t_mark = time.perf_counter()
-        turn.close(t0, dt, active=k)
+        # the block's own per-step integers ride behind the tokens; the
+        # cache's live rows are the scheduler's to know (the rows this
+        # step read: each active slot's length with its new token)
+        turn.close(t0, dt, active=k,
+                   kv_live_rows=self._kv.live_rows(cache_len[active] + 1),
+                   kv_rows=self._kv.rows,
+                   **dict(zip(self._counters,
+                              nxt_np[self.max_slots:].tolist())))
         self.metrics.observe_slots(self.active_slots)
 
     def _finish_slot(self, slot: int) -> None:
@@ -1069,6 +1151,17 @@ class DecodeSession:
             st.req._end_trace(error="ServerClosedError")
             st.req.handle._fail(ServerClosedError("decode session closed"))
         self._worker.join(timeout=join_timeout)
+        if not self._worker.is_alive():
+            # a closed session pins no device memory: the cache, the
+            # parameter references and the loaded executables go now, not
+            # when the cycle collector finds the session (the prefill
+            # cache holds a bound method of it); whoever still holds the
+            # block's parameters holds the weights
+            with self._compile_lock:
+                self._joins, self._dec_ex = {}, None
+            self._kv.arrays = []
+            self._params = []
+            self._prefill.release()
 
     def __enter__(self) -> "DecodeSession":
         return self

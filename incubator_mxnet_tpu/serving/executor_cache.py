@@ -531,6 +531,15 @@ class BucketedExecutorCache:
         budget accounting)."""
         return sum(int(p.nbytes) for p in self._params)
 
+    def release(self) -> None:
+        """Drop the resident parameters and every loaded executable: the
+        owner is closed and nothing will be dispatched again. Without it
+        they live until the cycle collector finds the owner (the cache
+        holds its owner's bound ``apply_fn``)."""
+        with self._lock:
+            self._params = []
+            self._execs = {}
+
     # -- execution ------------------------------------------------------------
     def __call__(self, x) -> Any:
         """Pad ``x`` up to its bucket, execute, slice outputs back down."""

@@ -155,3 +155,50 @@ def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
                 "parameter", "bitcast", "get-tuple-element"):
             beside[m.group(2)] = beside.get(m.group(2), 0) + 1
     assert beside == {"dynamic-update-slice": 2 * slots}, beside
+
+
+def test_sparse_decoder_step_aliases_both_cache_groups_published_widths(
+        one_chip):
+    """The data-built decoder's served decode step at K-EXAONE's widths
+    (hidden 6144, 64 / 8 heads of 128, window 128; one ``LLLG`` period,
+    16 experts held of 128, the FFN widths cut so that the weights are a
+    test's), 32 slots of 4096 positions, donated as ``DecodeSession``
+    lowers it and under the ambient ``highest`` precision the serving
+    tier traces it with: all four cache arrays (K and V of the full
+    group, K and V of the ring) alias their outputs, the grouped expert
+    products are the compiler's own ragged-dot kernels, and the step's
+    temp memory stays under a tenth of the cache."""
+    import re
+
+    from incubator_mxnet_tpu import serving
+    from incubator_mxnet_tpu.gluon.model_zoo import get_decoder
+
+    slots, t = 32, 4096
+    net = get_decoder("exaone_moe", num_layers=4, hidden_size=512,
+                      expert_hidden=256, experts_held=16, expert_share=0,
+                      vocab_size=1024, max_length=t)
+    net.cast("bfloat16")
+    net.initialize(init="zeros")
+    with serving.DecodeSession(net, max_slots=slots, max_len=t,
+                               prefill_buckets=(256,), name="ep8",
+                               donate=True, artifact_dir="") as sess:
+        assert sess._kv.shapes == [(1, slots, 8, t, 128),
+                                   (3, slots, 8, 128, 128)]
+        caches = [_spec(one_chip, shape) for shape in sess._kv.shapes
+                  for _ in "kv"]
+        vec = _spec(one_chip, (slots,), jnp.int32)
+        params = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
+        compiled = jax.jit(
+            sess._decode_apply, donate_argnums=(1, 2, 3, 4)).lower(
+            params, *caches, vec, vec).compile()
+        kv_bytes = sess._kv.nbytes
+    n = len(params)
+    text = compiled.as_text()
+    alias = re.search(r"input_output_alias=\{[^\n]*?\}, entry", text)
+    assert alias and all(f"{{{j + 1}}}: ({n + j}, {{}}" in alias.group(0)
+                         for j in range(4)), "a cache array is not aliased"
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= kv_bytes
+    assert mem.temp_size_in_bytes < kv_bytes // 10
+    assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot-none",
+                          text)) == 3 * 3      # gate, up, down x 3 layers
