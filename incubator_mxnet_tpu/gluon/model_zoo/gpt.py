@@ -13,20 +13,30 @@ but wired for BOTH halves of the decoder-LLM story:
   workload.
 * **Serving**: ``prefill`` additionally returns the per-layer K/V planes
   so a serving tier can seed a device-resident KV cache, and
-  ``decode_step`` advances EVERY slot of a ``[L, S, H, T, D]`` cache by
-  one token and updates the cache WHERE IT LIES: layer ``i``'s attention
-  reads plane ``cache[i]`` (a static leading-axis slice) with the new
-  token's K/V row selected in at ``cache_len[slot]`` — the values a
-  write-then-read would see, bit for bit — over exactly
-  ``[0, cache_len]`` through the ``cache_offset`` flash-attention path
-  (ops/pallas_attention.py), and once the last layer's row exists all
-  ``L`` rows of a slot are written straight into the stacked cache, one
-  ``dynamic_update_slice`` per slot and tensor. No plane is sliced out
-  and stacked back, so in the donated decode executable the output
-  caches alias the inputs and the only cache bytes a step writes are
-  the ``S`` new rows per layer. Because every shape is static in
-  ``max_len``/slot count, ONE compiled decode executable serves any mix
-  of sequence ages with zero recompiles (serving/decode.py builds it).
+  ``decode_step`` advances EVERY slot of the cache by one token and
+  updates the cache WHERE IT LIES. The cache is kept in the STORED form
+  ``[L, S, P, T, W]``: ``g = 128 // D`` heads lie side by side in one
+  row of ``W = g * D`` lanes (``_kv_pack``; two heads of 64 for every
+  GPT-2 width), ``P = ceil(H / g)`` such rows a position, the last one
+  zero-padded where ``g`` does not divide ``H``. A row of whole
+  128-lane tiles is what the TPU keeps minor, so a new row is ``P``
+  tiles; a minor dimension of 64 it laid out ``T``-minor, ``4 * H``
+  tiles a new row (PERF.md PR 29). Layer
+  ``i``'s attention reads plane ``cache[i]`` (a static leading-axis
+  slice) with the new token's K/V row selected in at
+  ``cache_len[slot]`` — the values a write-then-read would see, bit
+  for bit — over exactly ``[0, cache_len]`` (``_stored_attention``:
+  the ``g`` heads of a stored row as ``g`` queries over one K/V head
+  ``W`` wide, each zero outside its own lanes), and once the last
+  layer's row exists all ``L`` rows of a slot are written straight
+  into the stacked cache, one ``dynamic_update_slice`` per slot and
+  tensor. No plane is sliced out and stacked back and the minor
+  dimension is never reshaped, so in the donated decode executable the
+  output caches alias the inputs and the only cache bytes a step
+  writes are the ``S`` new rows per layer. Because every shape is
+  static in ``max_len``/slot count, ONE compiled decode executable
+  serves any mix of sequence ages with zero recompiles
+  (serving/decode.py builds it).
 
 All three entry points share the same sub-blocks (one parameter set),
 so greedy decode through the cache is bit-exact against the
@@ -65,14 +75,91 @@ def _stack0(arrays):
                   name="stack_layers", differentiable=False)
 
 
+#: lanes of a TPU tile: the stored K/V row is a whole number of them
+_LANES = 128
+
+
+def _kv_pack(head_dim):
+    """Heads that lie side by side in one stored K/V row: as many as
+    fill ``_LANES`` lanes (2 for GPT-2's 64, 8 for the tiny spec's 16),
+    and 1 — a row is a head, the plain ``[.., H, T, D]`` — where a head
+    is that wide already or does not divide it."""
+    return _LANES // head_dim \
+        if head_dim < _LANES and _LANES % head_dim == 0 else 1
+
+
+def _store_rows(x, heads, pack):
+    """K or V as the ``qkv`` product gives it, ``x`` (B, T, H*D), in the
+    stored form (B, P, T, W): ``pack`` heads side by side in a row of
+    ``W = pack * D``, ``P = ceil(H / pack)`` rows a position, what is
+    left of the last row zero. One pad, one reshape of the minor
+    ``H*D`` (the product's, not a cache's) and one transpose."""
+    import jax.numpy as jnp
+
+    from ...ndarray.ndarray import invoke
+
+    def store(a):
+        b, t, c = a.shape
+        w = c // heads * pack
+        rows = -(-heads // pack)
+        a = jnp.pad(a, ((0, 0), (0, 0), (0, rows * w - c)))
+        return a.reshape(b, t, rows, w).transpose(0, 2, 1, 3)
+
+    return invoke(store, [x], name="kv_store_rows", differentiable=False)
+
+
+def _stored_attention(q, k, v, total_lens, head_dim):
+    """One-token attention over planes in the stored form: ``q``
+    (S, P, 1, W) the query heads packed like a K/V row, ``k``/``v``
+    (S, P, T, W), ``total_lens`` (S,) the valid length per slot. Returns
+    the attended rows (S, P, 1, W), head ``p * g + j`` in lanes
+    ``[j*D, (j+1)*D)`` of row ``p``.
+
+    The ``g = W // D`` heads of a stored row are ``g`` queries over ONE
+    K/V head ``W`` wide (the grouped-query form of
+    ``decoder.py::serve_step``), query ``j`` zero outside its own ``D``
+    lanes: the other lanes of a row hold another head's finite values
+    or the pad's zeros, so they add exact zeros to a score, and of an
+    output row each head keeps its own lanes. The products do ``g``
+    times the useful work inside a fusion that waits on the plane's
+    bytes; what they buy is that ``W`` is never split — reshaping
+    ``W`` into ``(g, D)`` on a tiled plane is a relayout of the plane.
+    Scale, mask and float32 softmax are
+    ``ops/pallas_attention.py::_xla_reference``'s for one query at
+    position ``total_lens - 1``; the scores are accumulated AND kept in
+    float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...ndarray.ndarray import invoke
+
+    def attend(q_, k_, v_, lens):
+        w, t = k_.shape[-1], k_.shape[2]
+        own = jnp.arange(w, dtype=jnp.int32)[None, :] // head_dim \
+            == jnp.arange(w // head_dim, dtype=jnp.int32)[:, None]  # (g, W)
+        sc = jnp.einsum("spgc,sptc->spgt", jnp.where(own, q_, 0), k_,
+                        preferred_element_type=jnp.float32)
+        valid = jnp.arange(t, dtype=jnp.int32)[None, :] \
+            < lens.astype(jnp.int32)[:, None]
+        sc = jnp.where(valid[:, None, None, :], sc * (1.0 / head_dim ** 0.5),
+                       -jnp.inf)
+        p = jax.nn.softmax(sc, axis=-1).astype(v_.dtype)
+        out = jnp.einsum("spgt,sptc->spgc", p, v_)
+        return jnp.where(own, out, 0).sum(axis=2, keepdims=True)
+
+    return invoke(attend, [q, k, v, total_lens], name="stored_attention",
+                  differentiable=False)
+
+
 def _kv_plane_with_row(cache, new, layer, total_lens):
-    """Layer ``layer``'s (S, H, T, D) plane of the stacked cache with
+    """Layer ``layer``'s (S, P, T, W) plane of the stacked cache with
     each slot's new row in place, WITHOUT writing it: ``cache``
-    (L, S, H, T, D), ``new`` (S, H, 1, D), ``total_lens`` (S,) valid
-    length per slot INCLUDING the new token. A select on the position
-    over a static leading-axis slice — both fuse into the attention
-    that reads the plane, which so sees exactly what it would read
-    after the row was written at ``total_lens - 1``."""
+    (L, S, P, T, W) in the stored form (``_kv_pack``), ``new``
+    (S, P, 1, W), ``total_lens`` (S,) valid length per slot INCLUDING
+    the new token. A select on the position over a static leading-axis
+    slice — both fuse into the attention that reads the plane, which so
+    sees exactly what it would read after the row was written at
+    ``total_lens - 1``."""
     import jax.numpy as jnp
 
     from ...ndarray.ndarray import invoke
@@ -90,31 +177,32 @@ def _kv_cache_write(cache, rows, total_lens):
     """Write every layer's new K/V rows into the stacked cache where it
     lies.
 
-    ``cache`` (L, S, H, T, D); ``rows`` the ``L`` per-layer
-    (S, H, 1, D) rows; ``total_lens`` (S,) valid length per slot
-    INCLUDING the new token — slot ``s``'s rows land at
+    ``cache`` (L, S, P, T, W) in the stored form; ``rows`` the ``L``
+    per-layer (S, P, 1, W) rows; ``total_lens`` (S,) valid length per
+    slot INCLUDING the new token — slot ``s``'s rows land at
     ``(:, s, :, total_lens[s] - 1, :)``. One ``dynamic_update_slice``
     per slot, chained on the whole cache: they are the cache's only
     writers in a step, so XLA updates the (donated) buffer in place and
     nothing of the cache's or a plane's shape is copied out or stacked
-    back. (Measured on the v5e, PERF.md PR 26: the cache lies
-    ``T``-minor on the device, so a row touches 100 tiles and the
-    update's time goes by tiles touched, not by calls — one call per
-    slot for all layers costs the device what one per slot and layer
-    does, in a program that compiles and loads several times faster. A
-    scatter — which a vmapped
-    ``dynamic_update_slice`` also lowers to — makes the TPU compiler
-    relayout its whole operand around it.) The slot is static and the
-    position is CLAMPED into ``[0, T)`` by ``dynamic_update_slice``, so
-    a freed slot's stale ``cache_len`` of ``max_len`` neither faults
-    nor lands outside that slot's own (freed) rows."""
+    back. (Measured on the v5e, PERF.md PR 26 and PR 29: an update's
+    time goes by tiles touched, not by calls — one call per slot for
+    all layers costs the device what one per slot and layer does, in a
+    program that compiles and loads several times faster. A stored row
+    of whole 128-lane tiles lies ``W``-minor and is ``P`` tiles; a
+    ``[.., H, T, 64]`` cache lay ``T``-minor and a row was ``4 * H``.
+    A scatter — which a vmapped ``dynamic_update_slice`` also lowers to
+    — makes the TPU compiler relayout its whole operand around it.)
+    The slot is static and the position is CLAMPED into ``[0, T)`` by
+    ``dynamic_update_slice``, so a freed slot's stale ``cache_len`` of
+    ``max_len`` neither faults nor lands outside that slot's own
+    (freed) rows."""
     import jax.numpy as jnp
     from jax import lax
 
     from ...ndarray.ndarray import invoke
 
     def write(c, lens, *us):
-        u = jnp.stack(us, axis=0)                     # (L, S, H, 1, D)
+        u = jnp.stack(us, axis=0)                     # (L, S, P, 1, W)
         pos = lens.astype(jnp.int32) - 1
         for s in range(c.shape[1]):
             c = lax.dynamic_update_slice(c, u[:, s:s + 1],
@@ -134,6 +222,7 @@ class CausalSelfAttention(HybridBlock):
         assert units % num_heads == 0
         self._units = units
         self._heads = num_heads
+        self._pack = _kv_pack(units // num_heads)
         with self.name_scope():
             self.qkv = Dense(3 * units, flatten=False, in_units=units)
             self.proj = Dense(units, flatten=False, in_units=units)
@@ -144,50 +233,57 @@ class CausalSelfAttention(HybridBlock):
         return x.reshape(b, t, self._heads,
                          self._units // self._heads).transpose((0, 2, 1, 3))
 
-    def _project(self, x):
+    def _qkv(self, x):
+        """The fused product's three (B, T, C) slices."""
         c = self._units
         qkv = self.qkv(x)
-        return (self._split(qkv.slice_axis(2, 0, c)),
-                self._split(qkv.slice_axis(2, c, 2 * c)),
-                self._split(qkv.slice_axis(2, 2 * c, 3 * c)))
+        return (qkv.slice_axis(2, 0, c), qkv.slice_axis(2, c, 2 * c),
+                qkv.slice_axis(2, 2 * c, 3 * c))
+
+    def _stored(self, x):
+        return _store_rows(x, self._heads, self._pack)
 
     def forward(self, x, *args):
         out, _, _ = self.forward_with_kv(x)
         return out
 
-    def forward_with_kv(self, x):
+    def forward_with_kv(self, x, stored=False):
         """Full-sequence causal attention; also returns this layer's K/V
-        planes (B, H, T, D) for cache seeding (prefill)."""
+        planes for cache seeding (prefill): (B, H, T, D), or with
+        ``stored`` the cache's own (B, P, T, W) (``_store_rows``, from
+        the product's slices and not from the split heads)."""
         from ...ndarray.ndarray import invoke_op
 
-        q, k, v = self._project(x)
-        out = invoke_op("flash_attention", q, k, v, causal=True)
+        q, k, v = self._qkv(x)
+        kh, vh = self._split(k), self._split(v)
+        out = invoke_op("flash_attention", self._split(q), kh, vh,
+                        causal=True)
         b, h, t, d = out.shape
         out = out.transpose((0, 2, 1, 3)).reshape(b, t, self._units)
-        return self.drop(self.proj(out)), k, v
+        if stored:
+            kh, vh = self._stored(k), self._stored(v)
+        return self.drop(self.proj(out)), kh, vh
 
     def decode_step(self, x, k_cache, v_cache, total_lens, layer):
         """One-token decode of layer ``layer`` over the stacked cache.
 
         ``x`` (S, 1, C) — the new token's activations per slot;
-        ``k_cache``/``v_cache`` (L, S, H, T, D), ALL layers, read and
-        not written here; ``total_lens`` (S,) valid length per slot
-        including the new token; ``layer`` this layer's (static) index.
-        Returns the attended activations and the new token's K/V rows
-        (S, H, 1, D) for the caller to write: attention reads the
-        layer's plane with those rows selected in at ``total_lens - 1``
-        over ``[0, total_lens)`` exactly via the ``cache_offset``
-        path."""
-        from ...ndarray.ndarray import invoke_op
-
-        q, k_new, v_new = self._project(x)
-        out = invoke_op(
-            "flash_attention", q,
-            _kv_plane_with_row(k_cache, k_new, layer, total_lens),
+        ``k_cache``/``v_cache`` (L, S, P, T, W), ALL layers in the
+        stored form, read and not written here; ``total_lens`` (S,)
+        valid length per slot including the new token; ``layer`` this
+        layer's (static) index. Returns the attended activations and
+        the new token's K/V rows (S, P, 1, W) for the caller to write:
+        attention reads the layer's plane with those rows selected in
+        at ``total_lens - 1`` over ``[0, total_lens)`` exactly
+        (``_stored_attention``)."""
+        q, k_new, v_new = (self._stored(a) for a in self._qkv(x))
+        out = _stored_attention(
+            q, _kv_plane_with_row(k_cache, k_new, layer, total_lens),
             _kv_plane_with_row(v_cache, v_new, layer, total_lens),
-            total_lens, cache_offset=True)
-        s, h, t, d = out.shape
-        out = out.transpose((0, 2, 1, 3)).reshape(s, t, self._units)
+            total_lens, self._units // self._heads)
+        s = out.shape[0]
+        out = out.transpose((0, 2, 1, 3)).reshape(s, 1, -1) \
+            .slice_axis(2, 0, self._units)
         return self.drop(self.proj(out)), k_new, v_new
 
 
@@ -216,8 +312,8 @@ class GPTBlockCell(HybridBlock):
         x = x + self.attn(self.ln1(x))
         return x + self._ffn(self.ln2(x))
 
-    def forward_with_kv(self, x):
-        a, k, v = self.attn.forward_with_kv(self.ln1(x))
+    def forward_with_kv(self, x, stored=False):
+        a, k, v = self.attn.forward_with_kv(self.ln1(x), stored=stored)
         x = x + a
         return x + self._ffn(self.ln2(x)), k, v
 
@@ -282,20 +378,26 @@ class GPTDecoder(HybridBlock):
 
     def cache_groups(self, max_len):
         """The K/V cache this block is served with (docs/SERVING.md "What
-        a block declares"): one group, every layer ``max_len`` rows."""
-        return [dict(layers=self._layers, heads=self._heads,
-                     rows=int(max_len), head_dim=self.head_dim,
+        a block declares"): one group, every layer ``max_len`` rows, in
+        the STORED form — ``heads`` is the stored rows a position,
+        ``ceil(H / g)``, and ``head_dim`` their width ``g * D``
+        (``_kv_pack``: ``[48, S, 13, T, 128]`` for GPT-2 XL's 25 heads
+        of 64)."""
+        g = _kv_pack(self.head_dim)
+        return [dict(layers=self._layers, heads=-(-self._heads // g),
+                     rows=int(max_len), head_dim=g * self.head_dim,
                      kind="full")]
 
     def serve_prefill(self, tokens, n):
         """``prefill`` of one padded prompt ``tokens`` (T,) as the serving
         tier takes it: the logits at the last TRUE position ``n - 1``
-        (``n`` a traced scalar) and the K/V planes ``[L, H, T, D]``."""
+        (``n`` a traced scalar) and the K/V planes in the stored form
+        ``[L, P, T, W]`` that ``cache_groups`` declares."""
         import jax
 
         from ...ndarray.ndarray import invoke
 
-        logits, k, v = self.prefill(tokens.reshape(1, -1))
+        logits, k, v = self.prefill(tokens.reshape(1, -1), stored=True)
         return invoke(
             lambda lg, k_, v_, n_: (jax.lax.dynamic_index_in_dim(
                 lg[0], n_ - 1, axis=0, keepdims=False), k_[:, 0], v_[:, 0]),
@@ -315,27 +417,29 @@ class GPTDecoder(HybridBlock):
             x = getattr(self, f"layer{i}")(x)
         return self.head(self.ln_f(x))
 
-    def prefill(self, tokens):
+    def prefill(self, tokens, stored=False):
         """Full causal forward that ALSO returns the per-layer K/V planes
         for cache seeding: ``logits`` (B, T, V), ``k``/``v``
-        (L, B, H, T, D). Positions beyond a prompt's true length carry
-        garbage K/V — causality guarantees no valid position ever
-        attended them, and the serving tier's per-slot ``cache_len``
-        keeps decode from reading them."""
+        (L, B, H, T, D), or with ``stored`` the cache's (L, B, P, T, W).
+        Positions beyond a prompt's true length carry garbage K/V —
+        causality guarantees no valid position ever attended them, and
+        the serving tier's per-slot ``cache_len`` keeps decode from
+        reading them."""
         x = self._embed(tokens, _positions_like(tokens))
         ks, vs = [], []
         for i in range(self._layers):
-            x, k, v = getattr(self, f"layer{i}").forward_with_kv(x)
+            x, k, v = getattr(self, f"layer{i}").forward_with_kv(
+                x, stored=stored)
             ks.append(k)
             vs.append(v)
         return self.head(self.ln_f(x)), _stack0(ks), _stack0(vs)
 
     def decode_step(self, tokens, k_cache, v_cache, cache_len):
         """Advance every slot one token: ``tokens`` (S,) int32 — the next
-        input token per slot; ``k_cache``/``v_cache`` (L, S, H, T, D);
-        ``cache_len`` (S,) tokens already cached per slot (the new token
-        lands at that position). Returns ``logits`` (S, V) and the
-        updated caches.
+        input token per slot; ``k_cache``/``v_cache`` (L, S, P, T, W),
+        the stored form ``cache_groups`` declares; ``cache_len`` (S,)
+        tokens already cached per slot (the new token lands at that
+        position). Returns ``logits`` (S, V) and the updated caches.
 
         The stacked caches are read by every layer (plane ``i`` with
         the new row selected in) and written once, after the last

@@ -81,8 +81,9 @@ _PREFILL_PHASES = ("dispatch", "join", "fence")
 #: what ``_decode_apply``/``join`` compute or take, so that such an
 #: artifact is ``refused:program`` and recompiled, never deserialized
 #: (1, unwritten: the step that re-stacked the cache; 2: in place; 3:
-#: cache groups, the join takes ``[slot, true length]``).
-_PROGRAM_REVISION = 3
+#: cache groups, the join takes ``[slot, true length]``; 4: GPT's cache
+#: in the stored form, several heads side by side in a 128-lane row).
+_PROGRAM_REVISION = 4
 
 
 def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:
@@ -104,11 +105,18 @@ class KVCache:
     ``[Lg, S, H, rows, D]`` (k and v) per GROUP of layers.
 
     ``groups`` is what the served block declares (``cache_groups``): per
-    group ``layers``, ``heads`` (K/V heads), ``rows``, ``head_dim`` and
-    ``kind``: ``"full"`` keeps a position at its own row, ``"ring"``
-    keeps the last ``rows`` positions, position ``p`` at row ``p mod
-    rows``. A group of no layer holds nothing. ``arrays`` is the flat
-    list the executables take and return: k then v, group by group.
+    group ``layers``, ``heads``, ``rows``, ``head_dim`` and ``kind``:
+    ``"full"`` keeps a position at its own row, ``"ring"`` keeps the
+    last ``rows`` positions, position ``p`` at row ``p mod rows``.
+    ``heads`` and ``head_dim`` are the STORED form, which is the
+    block's to choose and nothing here looks inside: a K/V head a row
+    (the data-built decoder's 8 heads of 128), or several heads side by
+    side in one row of 128 lanes (``gpt.py``: GPT-2 XL's 25 heads of 64
+    are ``heads`` 13, ``head_dim`` 128). The block's ``serve_prefill``
+    returns its planes, and its ``serve_step`` reads and writes the
+    cache, in that same form. A group of no layer holds nothing.
+    ``arrays`` is the flat list the executables take and return: k then
+    v, group by group.
 
     Owned by a :class:`DecodeSession`; rebound on every donated
     join/decode dispatch. Both executables only ever
@@ -1206,6 +1214,7 @@ class DecodeSession:
         snap["warmup_seconds"] = self.engine_metrics.warmup_seconds
         snap["weights_version"] = self._weights_version
         snap["max_len"] = self.max_len
+        snap["kv_shapes"] = list(self._kv.shapes)
         if self._meter.ema_seconds is not None:
             snap["step_ema_ms"] = self._meter.ema_seconds * 1e3
         return snap

@@ -113,11 +113,15 @@ def test_fused_conv_bn_forward_and_backward_resnet50_shape(one_chip, hw, c):
 def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
     """The served decode step at GPT-2 XL's widths (two layers of the
     48), 16 slots of 1024 positions, donated as ``DecodeSession`` lowers
-    it: both caches alias their outputs, and outside fusions the only
-    ops whose result has the cache's or a layer plane's shape are the
-    in-place ``dynamic-update-slice`` of the new rows — no copy, no
-    relayout, no concatenate (a scatter in their place makes this
-    compiler relayout the whole cache around it)."""
+    it. The cache is in the stored form, two heads of 64 side by side in
+    a row of 128 lanes (13 rows for the 25 heads), and the compiler
+    keeps that row minor (``{4,3,2,1,0}``; a ``[.., 25, 1024, 64]``
+    cache it laid out ``T``-minor, a hundred tiles a new row). Both
+    caches alias their outputs, and outside fusions the only ops whose
+    result has the cache's or a layer plane's shape are the in-place
+    ``dynamic-update-slice`` of the new rows — no copy, no relayout, no
+    concatenate (a scatter in their place makes this compiler relayout
+    the whole cache around it)."""
     import re
 
     from incubator_mxnet_tpu import serving
@@ -132,6 +136,7 @@ def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
     with serving.DecodeSession(net, max_slots=slots, max_len=t,
                                prefill_buckets=(256,), name="xl2",
                                donate=True, artifact_dir="") as sess:
+        assert sess._kv.shape == (layers, slots, 13, t, 128)
         cache = _spec(one_chip, sess._kv.shape, sess._kv.dtype)
         vec = _spec(one_chip, (slots,), jnp.int32)
         params = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
@@ -143,17 +148,21 @@ def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
     assert alias and f"{{1}}: ({n}, {{}}" in alias.group(0) \
         and f"{{2}}: ({n + 1}, {{}}" in alias.group(0), "caches not aliased"
     assert compiled.memory_analysis().alias_size_in_bytes >= sess._kv.nbytes
-    big = re.compile(r"bf16\[(%d,)?(1,)?%d,%d,%d,%d\]"
-                     % (layers, slots, heads, t, d))
-    beside, fused = {}, False
+    big = re.compile(r"bf16\[(%d,)?(1,)?%d,13,%d,128\]" % (layers, slots, t))
+    whole = "bf16[%d,%d,13,%d,128]{" % (layers, slots, t)
+    beside, layouts, fused = {}, [], False
     for line in text.splitlines():
         if line and not line.startswith((" ", "}")):
             fused = not line.startswith("ENTRY")   # only the entry's ops
             continue
         m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
-        if not fused and m and big.match(m.group(1)) and m.group(2) not in (
-                "parameter", "bitcast", "get-tuple-element"):
+        if fused or not m or not big.match(m.group(1)):
+            continue
+        if m.group(2) == "parameter" and m.group(1).startswith(whole):
+            layouts.append(m.group(1)[len(whole):].split(":")[0].rstrip("}"))
+        if m.group(2) not in ("parameter", "bitcast", "get-tuple-element"):
             beside[m.group(2)] = beside.get(m.group(2), 0) + 1
+    assert layouts == ["4,3,2,1,0"] * 2, layouts
     assert beside == {"dynamic-update-slice": 2 * slots}, beside
 
 

@@ -205,6 +205,98 @@ def test_stale_full_slot_leaves_other_slots_rows_alone():
         np.testing.assert_array_equal(nxt_a[others], nxt_b[others])
 
 
+@pytest.mark.parametrize("heads,head_dim,pack", [
+    (25, 64, 2),      # GPT-2 XL's heads: 13 stored rows, half of the last a pad
+    (4, 16, 8),       # the tiny spec: one stored row, half of it a pad
+    (3, 64, 2),       # an odd count of heads
+    (2, 128, 1),      # a head fills the lanes: a row is a head
+])
+def test_stored_rows_serve_the_plain_forward(heads, head_dim, pack):
+    """The cache keeps ``pack`` heads side by side in a row of 128 lanes
+    (``gpt.py::_kv_pack``). Through it a real session's greedy streams
+    are the oracle's and what pads the last stored row stays zero under
+    joins and steps; the logits of ``serve_prefill`` and of every
+    ``serve_step`` of a greedy run are the plain forward's; and a stale
+    slot whose ``cache_len`` is ``max_len`` writes into its own last row
+    and moves nothing else (PR 26's clamp, in the stored form)."""
+    import jax.numpy as jnp
+
+    T, bucket, slots, stale = 24, 8, 3, 1
+    rows, width = -(-heads // pack), pack * head_dim
+    used = heads * head_dim - (rows - 1) * width   # of the last row
+    np.random.seed(3)
+    mx.random.seed(3)
+    net = get_gpt("gpt_decoder_tiny", vocab_size=VOCAB, num_layers=2,
+                  units=heads * head_dim, num_heads=heads, hidden_size=64,
+                  max_length=T, dropout=0.0)
+    net.initialize(init="xavier")
+
+    def forward(seq):
+        return net(mx.nd.array(np.array(seq)[None],
+                               dtype="int32")).asnumpy()[0, -1]
+
+    with serving.DecodeSession(net, max_slots=slots, max_len=T,
+                               prefill_buckets=(bucket,),
+                               name=f"stored{heads}x{head_dim}") as sess:
+        shape = (2, slots, rows, T, width)
+        assert sess.stats()["kv_shapes"] == [shape]
+        prompts = _prompts([5, 3, 7, 4], seed=heads)
+        handles = [sess.submit(p, max_new_tokens=4) for p in prompts]
+        for h, p in zip(handles, prompts):
+            assert h.result(120.0) == _oracle(net, p, 4)
+        for cache in (np.asarray(sess._kv.k), np.asarray(sess._kv.v)):
+            assert cache.shape == shape and cache.any()
+            assert not cache[:, :, -1, :, used:].any(), "the pad moved"
+
+        run, params = sess._run, sess._params
+        prefill = jax.jit(lambda pv, tok, n: run(net.serve_prefill, pv,
+                                                 tok, n))
+        step = jax.jit(lambda pv, *a: run(net.serve_step, pv, *a))
+        k = v = jnp.zeros(shape, jnp.float32)
+        seqs = {0: list(prompts[0]), 2: list(prompts[1])}
+        nxt = np.zeros(slots, np.int32)
+        for slot, seq in seqs.items():
+            padded = np.zeros(bucket, np.int32)
+            padded[:len(seq)] = seq
+            last, kp, vp = prefill(params, padded, np.int32(len(seq)))
+            assert kp.shape == (2, rows, bucket, width)
+            np.testing.assert_allclose(last, forward(seq), rtol=1e-4,
+                                       atol=1e-4)
+            k, v = k.at[:, slot, :, :bucket].set(kp), \
+                v.at[:, slot, :, :bucket].set(vp)
+            nxt[slot] = int(np.argmax(last))
+        for _ in range(5):
+            n0, n2 = len(seqs[0]), len(seqs[2])
+            logits, k2, v2 = step(params, nxt.copy(),
+                                  np.array([n0, T, n2], np.int32), k, v)
+            logits_b, k2b, v2b = step(params, nxt.copy(),
+                                      np.array([n0, 0, n2], np.int32), k, v)
+            for slot, seq in seqs.items():
+                seq.append(int(nxt[slot]))
+                np.testing.assert_allclose(logits[slot], forward(seq),
+                                           rtol=1e-4, atol=1e-4)
+                np.testing.assert_array_equal(logits[slot], logits_b[slot])
+                nxt[slot] = int(np.argmax(logits[slot]))
+            for new, new_b, old in ((k2, k2b, k), (v2, v2b, v)):
+                new, new_b, old = map(np.asarray, (new, new_b, old))
+                others = [s for s in range(slots) if s != stale]
+                # (the stale slot's own new row holds whatever position
+                # ``max_len``, outside the table, embeds to)
+                assert np.isfinite(new[:, others]).all()
+                assert not new[:, others, -1, :, used:].any(), \
+                    "the pad moved"
+                np.testing.assert_array_equal(new[:, others],
+                                              new_b[:, others])
+                for slot in others:     # one row a slot, at its length
+                    moved = (new[:, slot] != old[:, slot]).any(axis=(0, 1, 3))
+                    assert list(np.flatnonzero(moved)) == [len(seqs[slot]) - 1]
+                # the stale slot wrote inside its own rows: the last one
+                np.testing.assert_array_equal(new[:, stale, :, :T - 1],
+                                              old[:, stale, :, :T - 1])
+                assert (new[:, stale, :, T - 1] != old[:, stale, :, T - 1]).any()
+            k, v = k2, v2
+
+
 # ---------------------------------------------------------------------------
 # front-door semantics: backpressure, shedding, drain/healthz
 # ---------------------------------------------------------------------------

@@ -369,8 +369,9 @@ def test_donated_decode_step_aliases_every_cache_array(cfg):
 
 
 def test_gpt2_through_the_cache_groups_gives_the_tokens_it_gave():
-    """GPT-2 declares one full group: the cache is the one array pair it
-    was, and the greedy stream is the full forward's."""
+    """GPT-2 declares one full group: the cache is one array pair (in
+    the stored form: the tiny spec's 4 heads of 16 side by side in one
+    row of 128 lanes), and the greedy stream is the full forward's."""
     np.random.seed(0)
     mx.random.seed(0)
     net = get_gpt("gpt_decoder_tiny", vocab_size=VOCAB, max_length=48,
@@ -379,8 +380,8 @@ def test_gpt2_through_the_cache_groups_gives_the_tokens_it_gave():
     prompt = np.random.RandomState(3).randint(1, VOCAB, 7).astype(np.int32)
     with serving.DecodeSession(net, max_slots=2, max_len=48,
                                prefill_buckets=(8,), name="one") as sess:
-        assert sess._kv.shapes == [(2, 2, 4, 48, 16)]
-        assert sess._kv.shape == (2, 2, 4, 48, 16)
+        assert sess._kv.shapes == [(2, 2, 1, 48, 128)]
+        assert sess._kv.shape == (2, 2, 1, 48, 128)
         assert sess._kv.k.shape == sess._kv.v.shape == sess._kv.shape
         got = sess.generate(prompt, max_new_tokens=6)
     seq, want = list(prompt), []

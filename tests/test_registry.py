@@ -266,12 +266,12 @@ def test_decode_artifact_guard_covers_cache_shape(tmp_path):
         s2.close()
 
 
-@pytest.mark.parametrize("stored", ["absent", 1])
+@pytest.mark.parametrize("stored", ["absent", 1, 3])
 def test_decode_artifact_of_older_program_refused(tmp_path, monkeypatch,
                                                   stored):
     """A decode artifact persisted by an older PROGRAM (the parent
-    commit wrote no ``program`` field; a later one writes a lower
-    revision) agrees with the guard on compiler, device and shapes. It
+    of PR 26 wrote no ``program`` field; later ones write a lower
+    revision, 3 the one whose GPT cache kept a head a row) agrees with the guard on compiler, device and shapes. It
     is refused by the field's name and recompiled, never deserialized
     into the session."""
     from jax.experimental import serialize_executable
