@@ -20,13 +20,12 @@ What the block declares to be served by ``serving.DecodeSession``
 
 * ``cache_groups(max_len)``: the K/V cache as groups of layers with
   their own row count: the full layers keep ``max_len`` rows, the window
-  layers a ring of ``window`` rows (row ``position mod window``);
+  layers a ring of ``window`` rows;
 * ``serve_prefill(tokens, n)``: one padded prompt -> the logits at its
   last TRUE position and each group's K/V planes ``[Lg, Hkv, T, D]``;
 * ``serve_step(tokens, cache_len, *caches)``: every slot one token on,
-  the caches updated where they lie (the form ``gpt.py`` measured:
-  each layer's attention reads its plane with the new row selected in,
-  all rows of a slot are written after the last layer);
+  the caches updated where they lie. Where a row lies, what a slot may
+  read, the one-token attention and the writes are ``ops/kv_cache.py``'s;
 * ``step_counters``: the integers a step returns beside the logits.
 
 The arithmetic is plain ``jax.numpy`` over the parameter arrays (one
@@ -43,6 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from ...ndarray.ndarray import invoke
+from ...ops import kv_cache
 from ...ops.moe import moe_held_ffn, moe_route
 from ..block import HybridBlock
 
@@ -51,6 +51,7 @@ __all__ = ["HybridDecoder", "get_decoder"]
 #: the cache groups, in ``cache_groups`` order: full-attention layers keep
 #: every position, window layers a ring of the window's rows
 _FULL, _RING = 0, 1
+_KINDS = ("full", "ring")
 
 #: queries per block of the prefill attention: scores are built a block
 #: at a time against the keys that block may see, so a 2048-token prompt
@@ -168,14 +169,13 @@ class HybridDecoder(HybridBlock):
     def cache_groups(self, max_len):
         """The K/V cache this block is served with, a dict per group of
         layers: ``layers``, ``heads`` (K/V heads), ``rows``, ``head_dim``
-        and ``kind``: ``"full"`` (row = position) or ``"ring"`` (row =
-        position mod rows). Groups a model has no layer of are left out
-        of the cache but keep their place in the order."""
+        and ``kind`` (``ops/kv_cache.py``). Groups a model has no layer
+        of are left out of the cache but keep their place in the
+        order."""
         rows = (int(max_len), min(self._window, int(max_len)))
         return [dict(layers=n, heads=self._kv_heads, rows=r,
                      head_dim=self._head_dim, kind=kind)
-                for n, r, kind in zip(self._group_counts, rows,
-                                      ("full", "ring"))]
+                for n, r, kind in zip(self._group_counts, rows, _KINDS)]
 
     # -- the arithmetic, over plain arrays -------------------------------------
     def _arrays(self):
@@ -316,49 +316,33 @@ class HybridDecoder(HybridBlock):
         of no layer left out). Returns the logits (S, V), the
         ``step_counters`` as one int32 vector (over the slots whose
         ``cache_len`` is not 0: a free slot's is), and the caches with
-        each slot's new row written at ``cache_len`` (``mod rows`` on a
-        ring)."""
+        each slot's new row written (``kv_cache.address``)."""
         group_of = self._group_of
         present = [g for g, c in enumerate(self._group_counts) if c]
 
         def fn(p, tok, lens, *cs):
-            s = tok.shape[0]
             lens = lens.astype(jnp.int32)
             kv = {g: (cs[2 * j], cs[2 * j + 1])
                   for j, g in enumerate(present)}
-            # where each slot's new row lies and which rows it may read
-            pos, see = {}, {}
-            for g in present:
-                rows = kv[g][0].shape[3]
-                at = lens % rows if g == _RING \
-                    else jnp.clip(lens, 0, rows - 1)
-                r = jnp.arange(rows, dtype=jnp.int32)[None, :]
-                pos[g] = at
-                see[g] = (r == at[:, None],
-                          r < jnp.minimum(lens + 1, rows)[:, None])
+            at = {g: kv_cache.address(lens, kv[g][0].shape[3], _KINDS[g])
+                  for g in present}
             x = jnp.take(p["embed"], tok, axis=0)[:, None]     # (S, 1, C)
             live = lens > 0
             new = {g: ([], []) for g in present}
             totals = dict.fromkeys(("routed_here", "experts_hit"), 0)
             load_max, sparse = 0, 0
-            scale = 1.0 / math.sqrt(self._head_dim)
             for i, (attn, _) in enumerate(self._kinds):
                 lp = self._layer(p, i)
                 g, j = group_of[i]
                 with jax.named_scope("attention"):
                     q, k_new, v_new = self._qkv(
                         lp, x, lens[:, None], self._window * (g == _RING))
-                    here, valid = see[g]
-                    sel = here[:, None, :, None]
-                    k_all = jnp.where(sel, k_new, kv[g][0][j])
-                    v_all = jnp.where(sel, v_new, kv[g][1][j])
-                    sc = jnp.einsum("shgqd,shtd->shgqt", q, k_all,
-                                    preferred_element_type=jnp.float32)
-                    sc = jnp.where(valid[:, None, None, None, :],
-                                   sc * scale, -jnp.inf)
-                    w = jax.nn.softmax(sc, axis=-1).astype(v_all.dtype)
-                    a = self._merge(lp, jnp.einsum("shgqt,shtd->shgqd", w,
-                                                   v_all))
+                    _, here, see = at[g]
+                    a = kv_cache.attend(
+                        q[:, :, :, 0], kv_cache.read(kv[g][0], j, k_new, here),
+                        kv_cache.read(kv[g][1], j, v_new, here), see,
+                        self._head_dim)
+                    a = self._merge(lp, a[:, :, :, None])
                 new[g][0].append(k_new)
                 new[g][1].append(v_new)
                 x = x + rms_norm(a, lp["attn_norm"], self._eps)
@@ -375,14 +359,9 @@ class HybridDecoder(HybridBlock):
                 live.sum() * self._top_k * sparse,
                 totals["experts_hit"], load_max)])
             out = [logits, counters]
-            for g in present:
-                for cache, rows_new in zip(kv[g], new[g]):
-                    u = jnp.stack(rows_new, axis=0)      # (Lg, S, Hkv, 1, D)
-                    for slot in range(s):
-                        cache = jax.lax.dynamic_update_slice(
-                            cache, u[:, slot:slot + 1],
-                            (0, slot, 0, pos[g][slot], 0))
-                    out.append(cache)
+            out += [kv_cache.write(cache, rows_new, at[g][0])
+                    for g in present
+                    for cache, rows_new in zip(kv[g], new[g])]
             return tuple(out)
 
         return self._run(fn, [tokens, cache_len, *caches],

@@ -11,42 +11,28 @@ but wired for BOTH halves of the decoder-LLM story:
   streaming Pallas kernels above it), so the same config trains under
   ``SPMDTrainer`` + SuperStep + the ZeRO ladder like every other
   workload.
-* **Serving**: ``prefill`` additionally returns the per-layer K/V planes
-  so a serving tier can seed a device-resident KV cache, and
-  ``decode_step`` advances EVERY slot of the cache by one token and
-  updates the cache WHERE IT LIES. The cache is kept in the STORED form
-  ``[L, S, P, T, W]``: ``g = 128 // D`` heads lie side by side in one
-  row of ``W = g * D`` lanes (``_kv_pack``; two heads of 64 for every
-  GPT-2 width), ``P = ceil(H / g)`` such rows a position, the last one
-  zero-padded where ``g`` does not divide ``H``. A row of whole
-  128-lane tiles is what the TPU keeps minor, so a new row is ``P``
-  tiles; a minor dimension of 64 it laid out ``T``-minor, ``4 * H``
-  tiles a new row (PERF.md PR 29). Layer
-  ``i``'s attention reads plane ``cache[i]`` (a static leading-axis
-  slice) with the new token's K/V row selected in at
-  ``cache_len[slot]`` — the values a write-then-read would see, bit
-  for bit — over exactly ``[0, cache_len]`` (``_stored_attention``:
-  the ``g`` heads of a stored row as ``g`` queries over one K/V head
-  ``W`` wide, each zero outside its own lanes), and once the last
-  layer's row exists all ``L`` rows of a slot are written straight
-  into the stacked cache, one ``dynamic_update_slice`` per slot and
-  tensor. No plane is sliced out and stacked back and the minor
-  dimension is never reshaped, so in the donated decode executable the
-  output caches alias the inputs and the only cache bytes a step
-  writes are the ``S`` new rows per layer. Because every shape is
-  static in ``max_len``/slot count, ONE compiled decode executable
-  serves any mix of sequence ages with zero recompiles
-  (serving/decode.py builds it).
+* **Serving** (docs/SERVING.md "What a block declares"): ``prefill``
+  additionally returns the per-layer K/V planes that seed a
+  device-resident cache, and ``serve_step`` advances EVERY slot of the
+  cache by one token. The cache's rows are ``ops/kv_cache.py``'s: this
+  block declares one ``full`` group in the stored form ``[L, S, P, T,
+  W]`` (``g = 128 // D`` heads side by side in a row of ``W = g * D``
+  lanes, two heads of 64 for every GPT-2 width) and hands each layer's
+  packed query and new K/V rows to that module's read, attend and
+  write. Every shape is static in ``max_len``/slot count, so ONE
+  compiled decode executable serves any mix of sequence ages with zero
+  recompiles (serving/decode.py builds it).
 
-All three entry points share the same sub-blocks (one parameter set),
-so greedy decode through the cache is bit-exact against the
-full-sequence forward oracle — the contract tests/test_decode.py pins.
+All entry points share the same sub-blocks (one parameter set), so
+greedy decode through the cache is bit-exact against the full-sequence
+forward oracle — the contract tests/test_decode.py pins.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
+from ...ops import kv_cache
 from ..block import HybridBlock
 from ..nn import Dense, Dropout, Embedding, LayerNorm
 
@@ -75,144 +61,6 @@ def _stack0(arrays):
                   name="stack_layers", differentiable=False)
 
 
-#: lanes of a TPU tile: the stored K/V row is a whole number of them
-_LANES = 128
-
-
-def _kv_pack(head_dim):
-    """Heads that lie side by side in one stored K/V row: as many as
-    fill ``_LANES`` lanes (2 for GPT-2's 64, 8 for the tiny spec's 16),
-    and 1 — a row is a head, the plain ``[.., H, T, D]`` — where a head
-    is that wide already or does not divide it."""
-    return _LANES // head_dim \
-        if head_dim < _LANES and _LANES % head_dim == 0 else 1
-
-
-def _store_rows(x, heads, pack):
-    """K or V as the ``qkv`` product gives it, ``x`` (B, T, H*D), in the
-    stored form (B, P, T, W): ``pack`` heads side by side in a row of
-    ``W = pack * D``, ``P = ceil(H / pack)`` rows a position, what is
-    left of the last row zero. One pad, one reshape of the minor
-    ``H*D`` (the product's, not a cache's) and one transpose."""
-    import jax.numpy as jnp
-
-    from ...ndarray.ndarray import invoke
-
-    def store(a):
-        b, t, c = a.shape
-        w = c // heads * pack
-        rows = -(-heads // pack)
-        a = jnp.pad(a, ((0, 0), (0, 0), (0, rows * w - c)))
-        return a.reshape(b, t, rows, w).transpose(0, 2, 1, 3)
-
-    return invoke(store, [x], name="kv_store_rows", differentiable=False)
-
-
-def _stored_attention(q, k, v, total_lens, head_dim):
-    """One-token attention over planes in the stored form: ``q``
-    (S, P, 1, W) the query heads packed like a K/V row, ``k``/``v``
-    (S, P, T, W), ``total_lens`` (S,) the valid length per slot. Returns
-    the attended rows (S, P, 1, W), head ``p * g + j`` in lanes
-    ``[j*D, (j+1)*D)`` of row ``p``.
-
-    The ``g = W // D`` heads of a stored row are ``g`` queries over ONE
-    K/V head ``W`` wide (the grouped-query form of
-    ``decoder.py::serve_step``), query ``j`` zero outside its own ``D``
-    lanes: the other lanes of a row hold another head's finite values
-    or the pad's zeros, so they add exact zeros to a score, and of an
-    output row each head keeps its own lanes. The products do ``g``
-    times the useful work inside a fusion that waits on the plane's
-    bytes; what they buy is that ``W`` is never split — reshaping
-    ``W`` into ``(g, D)`` on a tiled plane is a relayout of the plane.
-    Scale, mask and float32 softmax are
-    ``ops/pallas_attention.py::_xla_reference``'s for one query at
-    position ``total_lens - 1``; the scores are accumulated AND kept in
-    float32."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...ndarray.ndarray import invoke
-
-    def attend(q_, k_, v_, lens):
-        w, t = k_.shape[-1], k_.shape[2]
-        own = jnp.arange(w, dtype=jnp.int32)[None, :] // head_dim \
-            == jnp.arange(w // head_dim, dtype=jnp.int32)[:, None]  # (g, W)
-        sc = jnp.einsum("spgc,sptc->spgt", jnp.where(own, q_, 0), k_,
-                        preferred_element_type=jnp.float32)
-        valid = jnp.arange(t, dtype=jnp.int32)[None, :] \
-            < lens.astype(jnp.int32)[:, None]
-        sc = jnp.where(valid[:, None, None, :], sc * (1.0 / head_dim ** 0.5),
-                       -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1).astype(v_.dtype)
-        out = jnp.einsum("spgt,sptc->spgc", p, v_)
-        return jnp.where(own, out, 0).sum(axis=2, keepdims=True)
-
-    return invoke(attend, [q, k, v, total_lens], name="stored_attention",
-                  differentiable=False)
-
-
-def _kv_plane_with_row(cache, new, layer, total_lens):
-    """Layer ``layer``'s (S, P, T, W) plane of the stacked cache with
-    each slot's new row in place, WITHOUT writing it: ``cache``
-    (L, S, P, T, W) in the stored form (``_kv_pack``), ``new``
-    (S, P, 1, W), ``total_lens`` (S,) valid length per slot INCLUDING
-    the new token. A select on the position over a static leading-axis
-    slice — both fuse into the attention that reads the plane, which so
-    sees exactly what it would read after the row was written at
-    ``total_lens - 1``."""
-    import jax.numpy as jnp
-
-    from ...ndarray.ndarray import invoke
-
-    def plane(c, u, lens):
-        at = jnp.arange(c.shape[3], dtype=jnp.int32)[None, :] \
-            == lens.astype(jnp.int32)[:, None] - 1
-        return jnp.where(at[:, None, :, None], u, c[layer])
-
-    return invoke(plane, [cache, new, total_lens], name="kv_plane_with_row",
-                  differentiable=False)
-
-
-def _kv_cache_write(cache, rows, total_lens):
-    """Write every layer's new K/V rows into the stacked cache where it
-    lies.
-
-    ``cache`` (L, S, P, T, W) in the stored form; ``rows`` the ``L``
-    per-layer (S, P, 1, W) rows; ``total_lens`` (S,) valid length per
-    slot INCLUDING the new token — slot ``s``'s rows land at
-    ``(:, s, :, total_lens[s] - 1, :)``. One ``dynamic_update_slice``
-    per slot, chained on the whole cache: they are the cache's only
-    writers in a step, so XLA updates the (donated) buffer in place and
-    nothing of the cache's or a plane's shape is copied out or stacked
-    back. (Measured on the v5e, PERF.md PR 26 and PR 29: an update's
-    time goes by tiles touched, not by calls — one call per slot for
-    all layers costs the device what one per slot and layer does, in a
-    program that compiles and loads several times faster. A stored row
-    of whole 128-lane tiles lies ``W``-minor and is ``P`` tiles; a
-    ``[.., H, T, 64]`` cache lay ``T``-minor and a row was ``4 * H``.
-    A scatter — which a vmapped ``dynamic_update_slice`` also lowers to
-    — makes the TPU compiler relayout its whole operand around it.)
-    The slot is static and the position is CLAMPED into ``[0, T)`` by
-    ``dynamic_update_slice``, so a freed slot's stale ``cache_len`` of
-    ``max_len`` neither faults nor lands outside that slot's own
-    (freed) rows."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    from ...ndarray.ndarray import invoke
-
-    def write(c, lens, *us):
-        u = jnp.stack(us, axis=0)                     # (L, S, P, 1, W)
-        pos = lens.astype(jnp.int32) - 1
-        for s in range(c.shape[1]):
-            c = lax.dynamic_update_slice(c, u[:, s:s + 1],
-                                         (0, s, 0, pos[s], 0))
-        return c
-
-    return invoke(write, [cache, total_lens, *rows], name="kv_cache_write",
-                  differentiable=False)
-
-
 class CausalSelfAttention(HybridBlock):
     """Fused-QKV multi-head causal self-attention with a decode mode."""
 
@@ -222,7 +70,6 @@ class CausalSelfAttention(HybridBlock):
         assert units % num_heads == 0
         self._units = units
         self._heads = num_heads
-        self._pack = _kv_pack(units // num_heads)
         with self.name_scope():
             self.qkv = Dense(3 * units, flatten=False, in_units=units)
             self.proj = Dense(units, flatten=False, in_units=units)
@@ -241,46 +88,53 @@ class CausalSelfAttention(HybridBlock):
                 qkv.slice_axis(2, 2 * c, 3 * c))
 
     def _stored(self, x):
-        return _store_rows(x, self._heads, self._pack)
+        """A (B, T, C) slice of the product in the cache's stored form
+        (B, P, T, W)."""
+        from ...ndarray.ndarray import invoke
 
-    def forward(self, x, *args):
-        out, _, _ = self.forward_with_kv(x)
-        return out
+        d = self._units // self._heads
+        return invoke(
+            lambda a: kv_cache.store_rows(a, self._heads, kv_cache.pack(d)),
+            [x], name="kv_store_rows", differentiable=False)
 
-    def forward_with_kv(self, x, stored=False):
-        """Full-sequence causal attention; also returns this layer's K/V
-        planes for cache seeding (prefill): (B, H, T, D), or with
-        ``stored`` the cache's own (B, P, T, W) (``_store_rows``, from
-        the product's slices and not from the split heads)."""
+    def _attend(self, q, k, v):
+        """Full-sequence causal attention of the product's slices."""
         from ...ndarray.ndarray import invoke_op
 
-        q, k, v = self._qkv(x)
-        kh, vh = self._split(k), self._split(v)
-        out = invoke_op("flash_attention", self._split(q), kh, vh,
-                        causal=True)
+        out = invoke_op("flash_attention", self._split(q), self._split(k),
+                        self._split(v), causal=True)
         b, h, t, d = out.shape
         out = out.transpose((0, 2, 1, 3)).reshape(b, t, self._units)
-        if stored:
-            kh, vh = self._stored(k), self._stored(v)
-        return self.drop(self.proj(out)), kh, vh
+        return self.drop(self.proj(out))
 
-    def decode_step(self, x, k_cache, v_cache, total_lens, layer):
+    def forward(self, x, *args):
+        return self._attend(*self._qkv(x))
+
+    def forward_with_kv(self, x):
+        """``forward`` and this layer's K/V planes for cache seeding
+        (prefill), in the stored form (B, P, T, W)."""
+        q, k, v = self._qkv(x)
+        return self._attend(q, k, v), self._stored(k), self._stored(v)
+
+    def decode_step(self, x, k_cache, v_cache, here, see, layer):
         """One-token decode of layer ``layer`` over the stacked cache.
 
         ``x`` (S, 1, C) — the new token's activations per slot;
-        ``k_cache``/``v_cache`` (L, S, P, T, W), ALL layers in the
-        stored form, read and not written here; ``total_lens`` (S,)
-        valid length per slot including the new token; ``layer`` this
-        layer's (static) index. Returns the attended activations and
-        the new token's K/V rows (S, P, 1, W) for the caller to write:
-        attention reads the layer's plane with those rows selected in
-        at ``total_lens - 1`` over ``[0, total_lens)`` exactly
-        (``_stored_attention``)."""
+        ``k_cache``/``v_cache`` (L, S, P, T, W), ALL layers, read and not
+        written here; ``here``/``see`` the step's ``kv_cache.address``;
+        ``layer`` this layer's (static) index. Returns the attended
+        activations and the new token's K/V rows (S, P, 1, W) for the
+        caller to write."""
+        from ...ndarray.ndarray import invoke
+
         q, k_new, v_new = (self._stored(a) for a in self._qkv(x))
-        out = _stored_attention(
-            q, _kv_plane_with_row(k_cache, k_new, layer, total_lens),
-            _kv_plane_with_row(v_cache, v_new, layer, total_lens),
-            total_lens, self._units // self._heads)
+        d = self._units // self._heads
+        out = invoke(
+            lambda q_, kc, vc, kn, vn, at, ok: kv_cache.attend(
+                q_, kv_cache.read(kc, layer, kn, at),
+                kv_cache.read(vc, layer, vn, at), ok, d),
+            [q, k_cache, v_cache, k_new, v_new, here, see],
+            name="stored_attention", differentiable=False)
         s = out.shape[0]
         out = out.transpose((0, 2, 1, 3)).reshape(s, 1, -1) \
             .slice_axis(2, 0, self._units)
@@ -312,14 +166,14 @@ class GPTBlockCell(HybridBlock):
         x = x + self.attn(self.ln1(x))
         return x + self._ffn(self.ln2(x))
 
-    def forward_with_kv(self, x, stored=False):
-        a, k, v = self.attn.forward_with_kv(self.ln1(x), stored=stored)
+    def forward_with_kv(self, x):
+        a, k, v = self.attn.forward_with_kv(self.ln1(x))
         x = x + a
         return x + self._ffn(self.ln2(x)), k, v
 
-    def decode_step(self, x, k_cache, v_cache, total_lens, layer):
+    def decode_step(self, x, k_cache, v_cache, here, see, layer):
         a, k_new, v_new = self.attn.decode_step(
-            self.ln1(x), k_cache, v_cache, total_lens, layer)
+            self.ln1(x), k_cache, v_cache, here, see, layer)
         x = x + a
         return x + self._ffn(self.ln2(x)), k_new, v_new
 
@@ -381,9 +235,9 @@ class GPTDecoder(HybridBlock):
         a block declares"): one group, every layer ``max_len`` rows, in
         the STORED form — ``heads`` is the stored rows a position,
         ``ceil(H / g)``, and ``head_dim`` their width ``g * D``
-        (``_kv_pack``: ``[48, S, 13, T, 128]`` for GPT-2 XL's 25 heads
-        of 64)."""
-        g = _kv_pack(self.head_dim)
+        (``kv_cache.pack``: ``[48, S, 13, T, 128]`` for GPT-2 XL's 25
+        heads of 64)."""
+        g = kv_cache.pack(self.head_dim)
         return [dict(layers=self._layers, heads=-(-self._heads // g),
                      rows=int(max_len), head_dim=g * self.head_dim,
                      kind="full")]
@@ -397,15 +251,48 @@ class GPTDecoder(HybridBlock):
 
         from ...ndarray.ndarray import invoke
 
-        logits, k, v = self.prefill(tokens.reshape(1, -1), stored=True)
+        logits, k, v = self.prefill(tokens.reshape(1, -1))
         return invoke(
             lambda lg, k_, v_, n_: (jax.lax.dynamic_index_in_dim(
                 lg[0], n_ - 1, axis=0, keepdims=False), k_[:, 0], v_[:, 0]),
             [logits, k, v, n], name="serve_prefill", differentiable=False)
 
     def serve_step(self, tokens, cache_len, k_cache, v_cache):
-        """``decode_step`` in the serving tier's argument order."""
-        return self.decode_step(tokens, k_cache, v_cache, cache_len)
+        """Advance every slot one token: ``tokens`` (S,) int32 — the next
+        input token per slot; ``cache_len`` (S,) tokens already cached
+        per slot (the new token lands at that position);
+        ``k_cache``/``v_cache`` (L, S, P, T, W), the stored form
+        ``cache_groups`` declares. Returns ``logits`` (S, V) and the
+        updated caches.
+
+        Every layer reads the stacked caches (plane ``i`` with the new
+        row selected in) and they are written once, after the last
+        layer, so a donated executable updates them where they lie
+        (``tests/test_decode.py`` pins the lowered program). Slots whose
+        entries are stale (free slots) still compute — the scheduler
+        ignores their rows, and their writes land in their own freed
+        rows (``kv_cache.address``)."""
+        from ...ndarray.ndarray import invoke
+
+        s = tokens.shape[0]
+        x = self._embed(tokens.reshape(s, 1), cache_len.reshape(s, 1))
+        row, here, see = invoke(
+            lambda n: kv_cache.address(n, k_cache.shape[3], "full"),
+            [cache_len], name="kv_address", differentiable=False)
+        new_k, new_v = [], []
+        for i in range(self._layers):
+            x, k_l, v_l = getattr(self, f"layer{i}").decode_step(
+                x, k_cache, v_cache, here, see, i)
+            new_k.append(k_l)
+            new_v.append(v_l)
+        logits = self.head(self.ln_f(x)).squeeze(1)
+
+        def write(cache, new):
+            return invoke(lambda c, at, *us: kv_cache.write(c, us, at),
+                          [cache, row, *new], name="kv_cache_write",
+                          differentiable=False)
+
+        return logits, write(k_cache, new_k), write(v_cache, new_v)
 
     def _embed(self, tokens, positions):
         return self.embed_dropout(self.word_embed(tokens)
@@ -417,54 +304,20 @@ class GPTDecoder(HybridBlock):
             x = getattr(self, f"layer{i}")(x)
         return self.head(self.ln_f(x))
 
-    def prefill(self, tokens, stored=False):
+    def prefill(self, tokens):
         """Full causal forward that ALSO returns the per-layer K/V planes
         for cache seeding: ``logits`` (B, T, V), ``k``/``v``
-        (L, B, H, T, D), or with ``stored`` the cache's (L, B, P, T, W).
-        Positions beyond a prompt's true length carry garbage K/V —
-        causality guarantees no valid position ever attended them, and
-        the serving tier's per-slot ``cache_len`` keeps decode from
-        reading them."""
+        (L, B, P, T, W), the form ``cache_groups`` declares. Positions
+        beyond a prompt's true length carry garbage K/V — causality
+        guarantees no valid position ever attended them, and the serving
+        tier's per-slot ``cache_len`` keeps decode from reading them."""
         x = self._embed(tokens, _positions_like(tokens))
         ks, vs = [], []
         for i in range(self._layers):
-            x, k, v = getattr(self, f"layer{i}").forward_with_kv(
-                x, stored=stored)
+            x, k, v = getattr(self, f"layer{i}").forward_with_kv(x)
             ks.append(k)
             vs.append(v)
         return self.head(self.ln_f(x)), _stack0(ks), _stack0(vs)
-
-    def decode_step(self, tokens, k_cache, v_cache, cache_len):
-        """Advance every slot one token: ``tokens`` (S,) int32 — the next
-        input token per slot; ``k_cache``/``v_cache`` (L, S, P, T, W),
-        the stored form ``cache_groups`` declares; ``cache_len`` (S,)
-        tokens already cached per slot (the new token lands at that
-        position). Returns ``logits`` (S, V) and the updated caches.
-
-        The stacked caches are read by every layer (plane ``i`` with
-        the new row selected in) and written once, after the last
-        layer: ``S`` rows per layer and tensor, nothing of the cache's
-        or a plane's shape is built beside them, so a donated
-        executable updates the cache where it lies
-        (``tests/test_decode.py`` pins the lowered program). Slots whose
-        entries are stale (free slots) still compute — the scheduler
-        ignores their rows; their writes land in their own freed rows,
-        also when a stale ``cache_len`` is ``max_len``
-        (``_kv_cache_write``: the position is clamped)."""
-        s = tokens.shape[0]
-        tok = tokens.reshape(s, 1)
-        pos = cache_len.reshape(s, 1)
-        x = self._embed(tok, pos)
-        total = cache_len + 1
-        new_k, new_v = [], []
-        for i in range(self._layers):
-            x, k_l, v_l = getattr(self, f"layer{i}").decode_step(
-                x, k_cache, v_cache, total, i)
-            new_k.append(k_l)
-            new_v.append(v_l)
-        logits = self.head(self.ln_f(x)).squeeze(1)
-        return (logits, _kv_cache_write(k_cache, new_k, total),
-                _kv_cache_write(v_cache, new_v, total))
 
 
 #: GPT-2-family configs (117M/345M) plus a tiny config for tests/benches

@@ -1,0 +1,126 @@
+"""The slot K/V cache's rows: where one lies, how a decode step reads,
+attends and writes it, and how a prefilled plane joins a slot. Every rule
+of ``serving.KVCache``'s arrays ``[L, S, H, rows, W]`` is here once; the
+served blocks (``gluon/model_zoo/gpt.py``, ``decoder.py``) and the join of
+``serving/decode.py`` call it. Plain ``jax.numpy`` over arrays.
+
+A group of layers is ``"full"`` (position ``p`` at row ``p``) or a
+``"ring"`` (the last ``rows`` positions, ``p`` at row ``p mod rows``). A
+step never writes before it reads: each layer attends over its plane with
+the new token's row SELECTED in (a static leading-axis slice and a
+``where``, which fuse into the attention: what a write-then-read would
+see, bit for bit), and after the last layer all layers' rows go into the
+donated cache, which XLA then updates where it lies.
+
+The stored row: the TPU keeps a minor dimension of whole 128-lane tiles
+minor, so a new row is few tiles; a minor dimension of 64 it laid out
+``T``-minor, a hundred tiles a new row (PERF.md PR 29). Heads narrower
+than ``LANES`` therefore lie ``g = pack(head_dim)`` side by side in a row
+of ``W = g * D``, ``ceil(H / g)`` rows a position, what is left of the
+last one zero. The minor ``W`` is never reshaped (on a tiled plane that
+is a relayout); ``attend`` takes the ``g`` heads of a row as ``g`` queries.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+#: lanes of a TPU tile: a stored K/V row is a whole number of them
+LANES = 128
+
+
+def pack(head_dim):
+    """Heads side by side in one stored row: as many as fill ``LANES`` (2
+    for GPT-2's 64), and 1 (a row is a head) where a head is that wide
+    already or does not divide it."""
+    return LANES // head_dim \
+        if head_dim < LANES and LANES % head_dim == 0 else 1
+
+
+def store_rows(x, heads, g):
+    """K, V or Q as a fused product gives it, ``x`` (B, T, H*D), in the
+    stored form (B, P, T, W): one pad, one reshape of the product's minor
+    ``H*D`` (not a cache's) and one transpose."""
+    b, t, c = x.shape
+    w = c // heads * g
+    p = -(-heads // g)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, p * w - c)))
+    return x.reshape(b, t, p, w).transpose(0, 2, 1, 3)
+
+
+def address(cache_len, rows, kind):
+    """Once per group and step, from ``cache_len`` (S,), the tokens each
+    slot has cached: ``row`` (S,) where the new token's row lies, ``here``
+    (S, rows) the one-hot of it, ``see`` (S, rows) the rows the slot may
+    read with its new token (the first ``min(cache_len + 1, rows)``). A
+    freed slot's stale ``cache_len`` of ``rows`` lands on its own last
+    row."""
+    n = cache_len.astype(jnp.int32)
+    row = n % rows if kind == "ring" else jnp.clip(n, 0, rows - 1)
+    r = jnp.arange(rows, dtype=jnp.int32)[None, :]
+    return row, r == row[:, None], r < jnp.minimum(n + 1, rows)[:, None]
+
+
+def read(cache, layer, new, here):
+    """Layer ``layer``'s plane (S, H, rows, W) of the stacked ``cache``
+    with each slot's ``new`` row (S, H, 1, W) in place, without writing
+    it."""
+    return jnp.where(here[:, None, :, None], new, cache[layer])
+
+
+def attend(q, k, v, see, head_dim):
+    """One token's attention over planes ``k``/``v`` (S, H, rows, W) under
+    the mask ``see``: float32 scores scaled by ``head_dim ** -0.5``,
+    float32 softmax cast to the values' type. ``q`` is (S, H, G, W), ``G``
+    queries a K/V head; returns the same shape. Where the planes hold
+    ``g = W // head_dim > 1`` heads a row, ``q`` is one stored row
+    (S, H, 1, W) and its ``g`` heads are the queries, each zero outside
+    its own ``D`` lanes: the other lanes hold another head's finite values
+    or the pad's zeros and add exact zeros to a score, and of an output
+    row each head keeps its own lanes. That does ``g`` times the useful
+    work inside a fusion that waits on the plane's bytes."""
+    w = k.shape[-1]
+    g = w // head_dim
+    if g > 1:
+        own = jnp.arange(w, dtype=jnp.int32)[None, :] // head_dim \
+            == jnp.arange(g, dtype=jnp.int32)[:, None]            # (g, W)
+        q = jnp.where(own, q, 0)
+    sc = jnp.einsum("shgd,shtd->shgt", q, k,
+                    preferred_element_type=jnp.float32)
+    sc = jnp.where(see[:, None, None, :], sc * (1.0 / head_dim ** 0.5),
+                   -jnp.inf)
+    out = jnp.einsum("shgt,shtd->shgd",
+                     jax.nn.softmax(sc, axis=-1).astype(v.dtype), v)
+    return jnp.where(own, out, 0).sum(axis=2, keepdims=True) if g > 1 \
+        else out
+
+
+def write(cache, new, row):
+    """All layers' ``new`` rows (``L`` of (S, H, 1, W)) into ``cache``
+    (L, S, H, rows, W) at ``row`` (S,): one ``dynamic_update_slice`` a
+    slot, slot static, chained on the whole cache. They are the cache's
+    only writers in a step, so nothing of its or a plane's shape is built
+    beside it. (On the v5e an update's time goes by tiles touched, not by
+    calls; a scatter, which a vmapped update also lowers to, makes the TPU
+    compiler relayout its whole operand: PERF.md PR 26.)"""
+    u = jnp.stack(new, axis=0)                          # (L, S, H, 1, W)
+    for s in range(cache.shape[1]):
+        cache = jax.lax.dynamic_update_slice(cache, u[:, s:s + 1],
+                                             (0, s, 0, row[s], 0))
+    return cache
+
+
+def join(cache, plane, slot, n, kind):
+    """A prompt's prefilled ``plane`` (L, H, T, W) into slot ``slot``
+    (traced) of ``cache``: a full group from row 0; a ring the last
+    ``rows`` positions below the TRUE length ``n`` (traced), each at
+    ``position mod rows`` (rows no position below ``n`` maps to hold
+    garbage that ``see`` masks)."""
+    if kind == "ring":
+        rows = cache.shape[3]
+        r = jnp.arange(rows, dtype=jnp.int32)
+        p = n - 1 - (n - 1 - r) % rows
+        plane = jnp.take(plane, jnp.clip(p, 0, plane.shape[2] - 1), axis=2)
+    return jax.lax.dynamic_update_slice(cache, plane[:, None],
+                                        (0, slot, 0, 0, 0))

@@ -15,16 +15,16 @@ per-step host vectors, never as recompilation:
   forward over the padded prompt returning the greedy first token and
   the per-layer K/V planes.
 * **Join** (one tiny executable per bucket) writes a prefilled plane
-  into a slot's cache range with ``lax.dynamic_update_slice`` at a
-  TRACED slot index — any free slot, no recompile — donating the cache
-  so the write aliases in place. Into a ring it writes the last ``rows``
-  positions below the prompt's TRUE length, each at ``position mod
-  rows``.
+  into a slot's cache range at a TRACED slot index — any free slot, no
+  recompile — donating the cache so the write aliases in place.
 * **Decode** is ONE donated executable over the whole cache: every
   step advances EVERY slot one token; per-slot ``cache_len`` (a host
   int32 vector, H2D per step) makes the single program serve any mix
-  of sequence ages — flash attention reads exactly ``[0, cache_len)``
-  per slot via the ``cache_offset`` path.
+  of sequence ages.
+
+How a row of the cache is addressed, read, attended, written and joined
+(full groups and rings) is ``ops/kv_cache.py``'s; the block's
+``serve_step`` and the join here call it.
 
 **Continuous batching**: new sequences join the running batch at step
 boundaries (the scheduler assigns free slots and prefills between decode
@@ -55,6 +55,7 @@ import numpy as np
 
 from .. import profiler
 from .. import telemetry
+from ..ops import kv_cache
 from .artifacts import (ArtifactStore, environment_fingerprint,
                         params_fingerprint)
 from .batcher import (DeadlineExceededError, QueueFullError,
@@ -82,8 +83,9 @@ _PREFILL_PHASES = ("dispatch", "join", "fence")
 #: artifact is ``refused:program`` and recompiled, never deserialized
 #: (1, unwritten: the step that re-stacked the cache; 2: in place; 3:
 #: cache groups, the join takes ``[slot, true length]``; 4: GPT's cache
-#: in the stored form, several heads side by side in a 128-lane row).
-_PROGRAM_REVISION = 4
+#: in the stored form, several heads side by side in a 128-lane row; 5:
+#: both blocks and the join through ``ops/kv_cache.py``).
+_PROGRAM_REVISION = 5
 
 
 def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:
@@ -105,9 +107,8 @@ class KVCache:
     ``[Lg, S, H, rows, D]`` (k and v) per GROUP of layers.
 
     ``groups`` is what the served block declares (``cache_groups``): per
-    group ``layers``, ``heads``, ``rows``, ``head_dim`` and ``kind``:
-    ``"full"`` keeps a position at its own row, ``"ring"`` keeps the
-    last ``rows`` positions, position ``p`` at row ``p mod rows``.
+    group ``layers``, ``heads``, ``rows``, ``head_dim`` and ``kind``
+    (``"full"`` or ``"ring"``: ``ops/kv_cache.py`` holds the rules).
     ``heads`` and ``head_dim`` are the STORED form, which is the
     block's to choose and nothing here looks inside: a K/V head a row
     (the data-built decoder's 8 heads of 128), or several heads side by
@@ -119,10 +120,10 @@ class KVCache:
     v, group by group.
 
     Owned by a :class:`DecodeSession`; rebound on every donated
-    join/decode dispatch. Both executables only ever
-    ``dynamic_update_slice`` into the stacked arrays (the join one
-    slot's prompt range, the decode step one row per slot and layer
-    after every layer has read its plane), so with donation on the TPU
+    join/decode dispatch. Both executables only ever update the stacked
+    arrays in place (the join one slot's prompt range, the decode step
+    one row per slot and layer after every layer has read its plane:
+    ``kv_cache.join``, ``kv_cache.write``), so with donation on the TPU
     their outputs alias their inputs and the cache is updated where it
     lies; without donation (the CPU default) each dispatch copies it
     once. Freed slots are not zeroed — their ranges are
@@ -527,15 +528,11 @@ class DecodeSession:
         return ex
 
     def _join_exec(self, bucket: int):
-        """The per-bucket cache-join executable: writes each group's
-        prefilled ``[Lg, H, Lb, D]`` plane into slot ``slot``'s cache
-        range (``dynamic_update_slice`` with a TRACED slot index — one
-        executable serves every slot). A full group takes the plane at
-        position 0; a ring takes the last ``rows`` positions below the
-        prompt's TRUE length ``n``, position ``p`` at row ``p mod rows``
-        (rows no position below ``n`` maps to hold garbage that
-        ``cache_len`` masks). ``at`` is ``[slot, n]``. Cache operands are
-        donated."""
+        """The per-bucket cache-join executable: ``kv_cache.join`` of
+        each group's prefilled ``[Lg, H, Lb, D]`` plane into slot
+        ``slot``'s cache range (a TRACED slot index: one executable
+        serves every slot) for a prompt of TRUE length ``n``. ``at`` is
+        ``[slot, n]``. Cache operands are donated."""
         ex = self._joins.get(bucket)
         if ex is not None:
             return ex
@@ -550,18 +547,9 @@ class DecodeSession:
                 def join(*args):
                     caches, planes = args[:n_arrays], args[n_arrays:-1]
                     slot, n = args[-1][0], args[-1][1]
-                    out = []
-                    for cache, plane, kind in zip(caches, planes, kinds):
-                        if kind == "ring":
-                            rows = cache.shape[3]
-                            r = jnp.arange(rows, dtype=jnp.int32)
-                            p = n - 1 - (n - 1 - r) % rows
-                            plane = jnp.take(
-                                plane, jnp.clip(p, 0, plane.shape[2] - 1),
-                                axis=2)
-                        out.append(jax.lax.dynamic_update_slice(
-                            cache, plane[:, None], (0, slot, 0, 0, 0)))
-                    return tuple(out)
+                    return tuple(
+                        kv_cache.join(cache, plane, slot, n, kind)
+                        for cache, plane, kind in zip(caches, planes, kinds))
 
                 planes = [jax.ShapeDtypeStruct((l, h, bucket, d),
                                                self._kv.dtype)

@@ -1,0 +1,123 @@
+"""``ops/kv_cache.py`` against a by-hand numpy cache: write the new row,
+then dense attention over the rows the slot may read."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from incubator_mxnet_tpu.ops import kv_cache
+
+L, S, ROWS, D = 2, 3, 6, 16
+STALE = 1            # a freed slot whose cache_len stays at ``ROWS``
+
+
+def _np_step(cache_k, cache_v, q, k_new, v_new, lens, kind):
+    """Numpy reference of one step over plain heads: caches
+    (L, S, H, ROWS, D), the new rows (L, S, H, D) written at their row
+    first, then the queries (L, S, H, G, D) attend over their K/V head's
+    readable rows."""
+    k, v = cache_k.copy(), cache_v.copy()
+    out = np.zeros(q.shape, np.float64)
+    for s, n in enumerate(lens):
+        row = n % ROWS if kind == "ring" else min(n, ROWS - 1)
+        k[:, s, :, row], v[:, s, :, row] = k_new[:, s], v_new[:, s]
+        t = min(n + 1, ROWS)
+        for l, h, j in np.ndindex(q.shape[0], q.shape[2], q.shape[3]):
+            sc = k[l, s, h, :t].astype(np.float64) @ q[l, s, h, j] / D ** 0.5
+            w = np.exp(sc - sc.max())
+            out[l, s, h, j] = (w / w.sum()) @ v[l, s, h, :t]
+    return out, k, v
+
+
+def _pack(x, g):
+    """(..., H, D) heads -> (..., P, g * D) stored rows, the rest zero."""
+    h = x.shape[-2]
+    p = -(-h // g)
+    x = np.concatenate([x, np.zeros(x.shape[:-2] + (p * g - h, D),
+                                    x.dtype)], axis=-2)
+    return x.reshape(x.shape[:-2] + (p, g * D))
+
+
+def _stored(cache, g):
+    """(L, S, H, ROWS, D) -> the stored (L, S, P, ROWS, g * D)."""
+    return np.moveaxis(_pack(np.moveaxis(cache, 2, 3), g), 3, 2)
+
+
+@pytest.mark.parametrize("g", [1, 2, 8])
+@pytest.mark.parametrize("kind", ["full", "ring"])
+def test_step_is_write_then_dense_attention(kind, g):
+    """address + read + attend + write over steps that wrap a ring, one
+    slot stale at ``cache_len == rows``. ``g`` = 1 is the grouped-query
+    form (3 queries a K/V head); ``g`` > 1 the stored row: 3 heads in
+    rows of ``g``, so the last row is padded."""
+    rs = np.random.RandomState(7)
+    heads, queries = (2, 3) if g == 1 else (3, 1)
+    normal = lambda *shape: rs.standard_normal(shape).astype(np.float32)
+    ck, cv = normal(L, S, heads, ROWS, D), normal(L, S, heads, ROWS, D)
+    sk, sv = jnp.asarray(_stored(ck, g)), jnp.asarray(_stored(cv, g))
+    lens = np.array([1, ROWS, 4])
+    live = [s for s in range(S) if s != STALE]
+    for _ in range(5):
+        q = normal(L, S, heads, queries, D)
+        k_new, v_new = normal(L, S, heads, D), normal(L, S, heads, D)
+        want, ck2, cv2 = _np_step(ck, cv, q, k_new, v_new, lens, kind)
+        row, here, see = kv_cache.address(jnp.asarray(lens, jnp.int32), ROWS,
+                                          kind)
+        rows_k = [jnp.asarray(_pack(a, g))[:, :, None] for a in k_new]
+        rows_v = [jnp.asarray(_pack(a, g))[:, :, None] for a in v_new]
+        for l in range(L):
+            # (S, H, G, D) as it is, or the heads packed like a K/V row
+            ql = jnp.asarray(q[l] if g == 1
+                             else _pack(q[l, :, :, 0], g)[:, :, None])
+            got = kv_cache.attend(
+                ql, kv_cache.read(sk, l, rows_k[l], here),
+                kv_cache.read(sv, l, rows_v[l], here), see, D)
+            assert got.shape == ql.shape
+            got = np.asarray(got).reshape(S, -1)[:, :heads * queries * D]
+            np.testing.assert_allclose(
+                got[live], want[l].reshape(S, -1)[live], rtol=2e-5, atol=2e-5)
+        sk = kv_cache.write(sk, rows_k, row)
+        sv = kv_cache.write(sv, rows_v, row)
+        np.testing.assert_array_equal(np.asarray(sk), _stored(ck2, g))
+        np.testing.assert_array_equal(np.asarray(sv), _stored(cv2, g))
+        # one row a slot moved, the stale slot's inside its own rows
+        moved = (ck2 != ck).any(axis=(0, 2, 4))
+        assert [list(np.flatnonzero(m)) for m in moved] == [
+            [n % ROWS if kind == "ring" else min(n, ROWS - 1)] for n in lens]
+        ck, cv = ck2, cv2
+        lens = np.where(np.arange(S) == STALE, ROWS, lens + 1)
+
+
+def test_pack_and_store_rows():
+    assert [kv_cache.pack(d) for d in (16, 64, 128, 256, 96)] == [8, 2, 1, 1, 1]
+    x = np.arange(2 * 5 * 3 * D, dtype=np.float32).reshape(2, 5, 3 * D)
+    got = np.asarray(kv_cache.store_rows(jnp.asarray(x), 3, 2))
+    want = np.moveaxis(_pack(x.reshape(2, 5, 3, D), 2), 2, 1)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("n", [3, ROWS, ROWS + 1, 11])
+@pytest.mark.parametrize("kind", ["full", "ring"])
+def test_join_of_a_prompt_under_at_and_over_the_rows(kind, n):
+    """A ring takes the last ``rows`` positions below the true length,
+    each at ``position mod rows``; a full group the plane from row 0."""
+    bucket, heads, slot = 12, 2, 1
+    rows = ROWS if kind == "ring" else 16
+    rs = np.random.RandomState(n)
+    cache = rs.standard_normal((L, S, heads, rows, D)).astype(np.float32)
+    plane = rs.standard_normal((L, heads, bucket, D)).astype(np.float32)
+    got = np.asarray(kv_cache.join(jnp.asarray(cache), jnp.asarray(plane),
+                                   jnp.int32(slot), jnp.int32(n), kind))
+    others = [s for s in range(S) if s != slot]
+    np.testing.assert_array_equal(got[:, others], cache[:, others])
+    if kind == "full":
+        np.testing.assert_array_equal(got[:, slot, :, :bucket], plane)
+        np.testing.assert_array_equal(got[:, slot, :, bucket:],
+                                      cache[:, slot, :, bucket:])
+        return
+    for pos in range(max(0, n - rows), n):
+        np.testing.assert_array_equal(got[:, slot, :, pos % rows],
+                                      plane[:, :, pos])
+    # and a step after it reads exactly those positions
+    _, _, see = kv_cache.address(jnp.asarray([0, n, 0], jnp.int32), rows, kind)
+    assert int(see[slot].sum()) == min(n + 1, rows)
