@@ -337,11 +337,9 @@ class HybridDecoder(HybridBlock):
                 with jax.named_scope("attention"):
                     q, k_new, v_new = self._qkv(
                         lp, x, lens[:, None], self._window * (g == _RING))
-                    _, here, see = at[g]
-                    a = kv_cache.attend(
-                        q[:, :, :, 0], kv_cache.read(kv[g][0], j, k_new, here),
-                        kv_cache.read(kv[g][1], j, v_new, here), see,
-                        self._head_dim)
+                    a = kv_cache.attend_row(
+                        q[:, :, :, 0], kv[g][0], kv[g][1], j, k_new, v_new,
+                        lens, at[g], _KINDS[g], self._head_dim)
                     a = self._merge(lp, a[:, :, :, None])
                 new[g][0].append(k_new)
                 new[g][1].append(v_new)

@@ -116,24 +116,23 @@ class CausalSelfAttention(HybridBlock):
         q, k, v = self._qkv(x)
         return self._attend(q, k, v), self._stored(k), self._stored(v)
 
-    def decode_step(self, x, k_cache, v_cache, here, see, layer):
+    def decode_step(self, x, k_cache, v_cache, cache_len, at, layer):
         """One-token decode of layer ``layer`` over the stacked cache.
 
         ``x`` (S, 1, C) — the new token's activations per slot;
         ``k_cache``/``v_cache`` (L, S, P, T, W), ALL layers, read and not
-        written here; ``here``/``see`` the step's ``kv_cache.address``;
-        ``layer`` this layer's (static) index. Returns the attended
-        activations and the new token's K/V rows (S, P, 1, W) for the
-        caller to write."""
+        written here; ``at`` the step's ``kv_cache.address`` of
+        ``cache_len``; ``layer`` this layer's (static) index. Returns
+        the attended activations and the new token's K/V rows
+        (S, P, 1, W) for the caller to write."""
         from ...ndarray.ndarray import invoke
 
         q, k_new, v_new = (self._stored(a) for a in self._qkv(x))
         d = self._units // self._heads
         out = invoke(
-            lambda q_, kc, vc, kn, vn, at, ok: kv_cache.attend(
-                q_, kv_cache.read(kc, layer, kn, at),
-                kv_cache.read(vc, layer, vn, at), ok, d),
-            [q, k_cache, v_cache, k_new, v_new, here, see],
+            lambda q_, kc, vc, kn, vn, n, *at_: kv_cache.attend_row(
+                q_, kc, vc, layer, kn, vn, n, at_, "full", d),
+            [q, k_cache, v_cache, k_new, v_new, cache_len, *at],
             name="stored_attention", differentiable=False)
         s = out.shape[0]
         out = out.transpose((0, 2, 1, 3)).reshape(s, 1, -1) \
@@ -171,9 +170,9 @@ class GPTBlockCell(HybridBlock):
         x = x + a
         return x + self._ffn(self.ln2(x)), k, v
 
-    def decode_step(self, x, k_cache, v_cache, here, see, layer):
+    def decode_step(self, x, k_cache, v_cache, cache_len, at, layer):
         a, k_new, v_new = self.attn.decode_step(
-            self.ln1(x), k_cache, v_cache, here, see, layer)
+            self.ln1(x), k_cache, v_cache, cache_len, at, layer)
         x = x + a
         return x + self._ffn(self.ln2(x)), k_new, v_new
 
@@ -276,20 +275,20 @@ class GPTDecoder(HybridBlock):
 
         s = tokens.shape[0]
         x = self._embed(tokens.reshape(s, 1), cache_len.reshape(s, 1))
-        row, here, see = invoke(
+        at = invoke(
             lambda n: kv_cache.address(n, k_cache.shape[3], "full"),
             [cache_len], name="kv_address", differentiable=False)
         new_k, new_v = [], []
         for i in range(self._layers):
             x, k_l, v_l = getattr(self, f"layer{i}").decode_step(
-                x, k_cache, v_cache, here, see, i)
+                x, k_cache, v_cache, cache_len, at, i)
             new_k.append(k_l)
             new_v.append(v_l)
         logits = self.head(self.ln_f(x)).squeeze(1)
 
         def write(cache, new):
-            return invoke(lambda c, at, *us: kv_cache.write(c, us, at),
-                          [cache, row, *new], name="kv_cache_write",
+            return invoke(lambda c, row, *us: kv_cache.write(c, us, row),
+                          [cache, at[0], *new], name="kv_cache_write",
                           differentiable=False)
 
         return logits, write(k_cache, new_k), write(v_cache, new_v)

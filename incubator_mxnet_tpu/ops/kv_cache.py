@@ -2,15 +2,21 @@
 attends and writes it, and how a prefilled plane joins a slot. Every rule
 of ``serving.KVCache``'s arrays ``[L, S, H, rows, W]`` is here once; the
 served blocks (``gluon/model_zoo/gpt.py``, ``decoder.py``) and the join of
-``serving/decode.py`` call it. Plain ``jax.numpy`` over arrays.
+``serving/decode.py`` call it. Plain ``jax.numpy`` over arrays, but for
+the one kernel ``attend_row`` takes on the TPU (``ops/pallas_decode.py``).
 
 A group of layers is ``"full"`` (position ``p`` at row ``p``) or a
 ``"ring"`` (the last ``rows`` positions, ``p`` at row ``p mod rows``). A
 step never writes before it reads: each layer attends over its plane with
-the new token's row SELECTED in (a static leading-axis slice and a
-``where``, which fuse into the attention: what a write-then-read would
-see, bit for bit), and after the last layer all layers' rows go into the
-donated cache, which XLA then updates where it lies.
+the new token's row SELECTED in, and after the last layer all layers'
+rows go into the donated cache, which XLA then updates where it lies. The
+rule's plain statement is ``read`` + ``attend`` (a static leading-axis
+slice and a ``where``, which fuse into the attention: what a
+write-then-read would see, bit for bit); ``attend_row`` is the step's one
+entry and, where ``blocked`` says so (a full group of more than one block
+of rows) and the step is lowered for the TPU, fetches only the blocks
+below each slot's ``cache_len`` and joins the new row in the kernel
+instead.
 
 The stored row: the TPU keeps a minor dimension of whole 128-lane tiles
 minor, so a new row is few tiles; a minor dimension of 64 it laid out
@@ -25,9 +31,14 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from . import pallas_decode
 
 #: lanes of a TPU tile: a stored K/V row is a whole number of them
 LANES = 128
+#: rows of a block where a plane is read by blocks of live rows
+BLOCK = pallas_decode.BLOCK
 
 
 def pack(head_dim):
@@ -69,31 +80,101 @@ def read(cache, layer, new, here):
     return jnp.where(here[:, None, :, None], new, cache[layer])
 
 
+def _heads_of_a_row(fn, q, w, head_dim):
+    """``fn`` (queries (S, H, G, W) -> the same shape) over ``q``: as it
+    is where a stored row is one head. Where a row holds
+    ``g = w // head_dim > 1`` heads, ``q`` is one stored row (S, H, 1, W)
+    and its ``g`` heads are the queries, each zero outside its own ``D``
+    lanes: the other lanes hold another head's finite values or the pad's
+    zeros and add exact zeros to a score, and of an output row each head
+    keeps its own lanes."""
+    g = w // head_dim
+    if g == 1:
+        return fn(q)
+    own = jnp.arange(w, dtype=jnp.int32)[None, :] // head_dim \
+        == jnp.arange(g, dtype=jnp.int32)[:, None]                # (g, W)
+    return jnp.where(own, fn(jnp.where(own, q, 0)), 0).sum(
+        axis=2, keepdims=True)
+
+
 def attend(q, k, v, see, head_dim):
     """One token's attention over planes ``k``/``v`` (S, H, rows, W) under
     the mask ``see``: float32 scores scaled by ``head_dim ** -0.5``,
     float32 softmax cast to the values' type. ``q`` is (S, H, G, W), ``G``
-    queries a K/V head; returns the same shape. Where the planes hold
-    ``g = W // head_dim > 1`` heads a row, ``q`` is one stored row
-    (S, H, 1, W) and its ``g`` heads are the queries, each zero outside
-    its own ``D`` lanes: the other lanes hold another head's finite values
-    or the pad's zeros and add exact zeros to a score, and of an output
-    row each head keeps its own lanes. That does ``g`` times the useful
-    work inside a fusion that waits on the plane's bytes."""
-    w = k.shape[-1]
-    g = w // head_dim
-    if g > 1:
-        own = jnp.arange(w, dtype=jnp.int32)[None, :] // head_dim \
-            == jnp.arange(g, dtype=jnp.int32)[:, None]            # (g, W)
-        q = jnp.where(own, q, 0)
-    sc = jnp.einsum("shgd,shtd->shgt", q, k,
-                    preferred_element_type=jnp.float32)
-    sc = jnp.where(see[:, None, None, :], sc * (1.0 / head_dim ** 0.5),
-                   -jnp.inf)
-    out = jnp.einsum("shgt,shtd->shgd",
-                     jax.nn.softmax(sc, axis=-1).astype(v.dtype), v)
-    return jnp.where(own, out, 0).sum(axis=2, keepdims=True) if g > 1 \
-        else out
+    queries a K/V head, or one stored row of several heads
+    (``_heads_of_a_row``, which does ``g`` times the useful work inside a
+    fusion that waits on the plane's bytes); returns ``q``'s shape."""
+    def dense(q):
+        sc = jnp.einsum("shgd,shtd->shgt", q, k,
+                        preferred_element_type=jnp.float32)
+        sc = jnp.where(see[:, None, None, :], sc * (1.0 / head_dim ** 0.5),
+                       -jnp.inf)
+        return jnp.einsum("shgt,shtd->shgd",
+                          jax.nn.softmax(sc, axis=-1).astype(v.dtype), v)
+
+    return _heads_of_a_row(dense, q, k.shape[-1], head_dim)
+
+
+def blocked(rows, kind):
+    """Whether a step's attention over a plane of ``rows``, lowered for
+    the TPU, goes by blocks of live rows (``pallas_decode``): a full
+    group of more than one block. A ring is read whole (its rows are all
+    live once it has wrapped, and few), as is a plane of one block or
+    less, or of no whole number of blocks (the kernel's copies start on
+    a block's edge)."""
+    return kind == "full" and rows > BLOCK and rows % BLOCK == 0
+
+
+def fetched_rows(cache_len, rows, by_blocks):
+    """On the host, the rows ``attend_row`` reads for each slot of
+    ``cache_len`` (numpy) over one plane of ``rows``: every row where the
+    plane is read whole; ``by_blocks``, the whole blocks that hold a
+    cached row and the new token's row (which comes from the step, not
+    the cache), so never fewer than the slot's ``cache_len + 1`` live
+    rows."""
+    if not by_blocks:
+        return np.full(cache_len.shape, rows, np.int64)
+    return -(-pallas_decode.cached_rows(cache_len, rows) // BLOCK) * BLOCK + 1
+
+
+def attend_blocks(q, k_cache, v_cache, layer, k_new, v_new, cache_len,
+                  head_dim, interpret=None):
+    """``attend_row`` by blocks of live rows: ``pallas_decode.attend``
+    over the heads of a stored row (``interpret`` is the kernel's)."""
+    return _heads_of_a_row(
+        lambda q: pallas_decode.attend(q, k_cache, v_cache, layer, k_new,
+                                       v_new, cache_len, head_dim,
+                                       interpret=interpret),
+        q, k_cache.shape[-1], head_dim)
+
+
+def attend_row(q, k_cache, v_cache, layer, k_new, v_new, cache_len, at,
+               kind, head_dim):
+    """Layer ``layer``'s one-token attention over the stacked caches
+    (L, S, H, rows, W) with the new token's rows ``k_new``/``v_new``
+    (S, H, 1, W) where ``at`` (the group's ``address`` of ``cache_len``)
+    puts them: what writing the rows and then ``attend`` over the plane
+    gives, to float tolerance, without the write. ``q`` as ``attend``
+    takes it. ``read`` + ``attend`` is the rule's plain statement and the
+    path of every plane that is not ``blocked`` and of every platform but
+    the TPU; a ``blocked`` plane lowered for the TPU goes by blocks of
+    live rows (the platform is the lowering's to know, not the host's
+    default backend)."""
+    _, here, see = at
+
+    def dense(q, k_cache, v_cache, k_new, v_new, cache_len):
+        return attend(q, read(k_cache, layer, k_new, here),
+                      read(v_cache, layer, v_new, here), see, head_dim)
+
+    def by_blocks(q, k_cache, v_cache, k_new, v_new, cache_len):
+        return attend_blocks(q, k_cache, v_cache, layer, k_new, v_new,
+                             cache_len, head_dim, interpret=False)
+
+    operands = (q, k_cache, v_cache, k_new, v_new, cache_len)
+    if not blocked(k_cache.shape[3], kind):
+        return dense(*operands)
+    return jax.lax.platform_dependent(*operands, tpu=by_blocks,
+                                      default=dense)
 
 
 def write(cache, new, row):

@@ -84,8 +84,9 @@ _PREFILL_PHASES = ("dispatch", "join", "fence")
 #: (1, unwritten: the step that re-stacked the cache; 2: in place; 3:
 #: cache groups, the join takes ``[slot, true length]``; 4: GPT's cache
 #: in the stored form, several heads side by side in a 128-lane row; 5:
-#: both blocks and the join through ``ops/kv_cache.py``).
-_PROGRAM_REVISION = 5
+#: both blocks and the join through ``ops/kv_cache.py``; 6: a full
+#: group's attention by blocks of live rows on the TPU).
+_PROGRAM_REVISION = 6
 
 
 def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:
@@ -138,6 +139,13 @@ class KVCache:
                        for g in self.groups]
         self.arrays = [jax.device_put(jnp.zeros(shape, self.dtype))
                        for shape in self.shapes for _ in "kv"]
+        # which groups a step attends by blocks of live rows: the rule's
+        # answer for the platform the arrays lie on, asked once
+        on_tpu = {d.platform for a in self.arrays for d in a.devices()} \
+            == {"tpu"}
+        self._by_blocks = [on_tpu and kv_cache.blocked(r, g["kind"])
+                           for g, (_, _, _, r, _)
+                           in zip(self.groups, self.shapes)]
 
     # a cache of one group (every model before the window layers) reads
     # as the one array pair it is
@@ -184,6 +192,19 @@ class KVCache:
         lens = np.asarray(lens, np.int64)
         return int(sum(l * np.minimum(lens, r).sum()
                        for l, _, _, r, _ in self.shapes))
+
+    def read_rows(self, cache_len) -> int:
+        """The rows a decode step's attention READS for slots whose
+        cached lengths (before the step's token) are ``cache_len``:
+        whole blocks up to each length and the new row where a group
+        goes by blocks, every row of the plane where it is read whole
+        (``kv_cache.fetched_rows``). Never under ``live_rows`` of
+        ``cache_len + 1``."""
+        cache_len = np.asarray(cache_len, np.int64)
+        return int(sum(
+            l * kv_cache.fetched_rows(cache_len, r, by_blocks).sum()
+            for by_blocks, (l, _, _, r, _)
+            in zip(self._by_blocks, self.shapes)))
 
 
 _DONE = object()
@@ -1054,9 +1075,11 @@ class DecodeSession:
         self._t_mark = time.perf_counter()
         # the block's own per-step integers ride behind the tokens; the
         # cache's live rows are the scheduler's to know (the rows this
-        # step read: each active slot's length with its new token)
+        # step read: each active slot's length with its new token; the
+        # rows its attention fetched for them: whole blocks, or planes)
         turn.close(t0, dt, active=k,
                    kv_live_rows=self._kv.live_rows(cache_len[active] + 1),
+                   kv_read_rows=self._kv.read_rows(cache_len[active]),
                    kv_rows=self._kv.rows,
                    **dict(zip(self._counters,
                               nxt_np[self.max_slots:].tolist())))

@@ -15,6 +15,7 @@ for a described chip cannot be read back without one).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,10 +111,40 @@ def test_fused_conv_bn_forward_and_backward_resnet50_shape(one_chip, hw, c):
     _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), x, w, ab, ab)
 
 
+@pytest.mark.parametrize("shape,queries,head_dim", [
+    ((48, 16, 13, 1024, 128), 2, 64),     # GPT-2 XL: two heads a stored row
+    ((2, 32, 8, 4096, 128), 8, 128),      # K-EXAONE: 8 queries a K/V head
+])
+def test_decode_attention_kernel_benchmark_shapes(one_chip, shape, queries,
+                                                  head_dim):
+    """The block kernel of the decode step's attention alone, at the
+    served caches' whole shapes: the stacked cache goes in as it lies
+    (no slice or copy of a plane ahead of the call)."""
+    from incubator_mxnet_tpu.ops import pallas_decode
+
+    _, slots, heads, _, w = shape
+    row = _spec(one_chip, (slots, heads, 1, w))
+    compiled = _compile(
+        lambda q, k, v, kn, vn, n, layer: pallas_decode.attend(
+            q, k, v, layer, kn, vn, n, head_dim, interpret=False),
+        _spec(one_chip, (slots, heads, queries, w)), _spec(one_chip, shape),
+        _spec(one_chip, shape), row, row, _spec(one_chip, (slots,), jnp.int32),
+        _spec(one_chip, (), jnp.int32))
+    assert not re.search(r"= bf16\[%d,%d,%d,%d\]\S* (copy|slice|dynamic-slice)"
+                         % shape[1:], compiled.as_text())
+
+
+def _kernel_calls(text):
+    return len(re.findall(r"= \S+ custom-call\([^\n]*kv_decode_attention",
+                          text))
+
+
 def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
     """The served decode step at GPT-2 XL's widths (two layers of the
     48), 16 slots of 1024 positions, donated as ``DecodeSession`` lowers
-    it. The cache is in the stored form, two heads of 64 side by side in
+    it, its attention the block kernel (one call a layer, handed the
+    whole stacked caches: no slice of a plane ahead of it). The cache is
+    in the stored form, two heads of 64 side by side in
     a row of 128 lanes (13 rows for the 25 heads), and the compiler
     keeps that row minor (``{4,3,2,1,0}``; a ``[.., 25, 1024, 64]``
     cache it laid out ``T``-minor, a hundred tiles a new row). Both
@@ -122,8 +153,6 @@ def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
     ``dynamic-update-slice`` of the new rows — no copy, no relayout, no
     concatenate (a scatter in their place makes this compiler relayout
     the whole cache around it)."""
-    import re
-
     from incubator_mxnet_tpu import serving
     from incubator_mxnet_tpu.gluon.model_zoo import get_gpt
 
@@ -164,6 +193,7 @@ def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
             beside[m.group(2)] = beside.get(m.group(2), 0) + 1
     assert layouts == ["4,3,2,1,0"] * 2, layouts
     assert beside == {"dynamic-update-slice": 2 * slots}, beside
+    assert _kernel_calls(text) == layers
 
 
 def test_sparse_decoder_step_aliases_both_cache_groups_published_widths(
@@ -177,8 +207,6 @@ def test_sparse_decoder_step_aliases_both_cache_groups_published_widths(
     group, K and V of the ring) alias their outputs, the grouped expert
     products are the compiler's own ragged-dot kernels, and the step's
     temp memory stays under a tenth of the cache."""
-    import re
-
     from incubator_mxnet_tpu import serving
     from incubator_mxnet_tpu.gluon.model_zoo import get_decoder
 
@@ -211,3 +239,5 @@ def test_sparse_decoder_step_aliases_both_cache_groups_published_widths(
     assert mem.temp_size_in_bytes < kv_bytes // 10
     assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot-none",
                           text)) == 3 * 3      # gate, up, down x 3 layers
+    # the full layer attends by blocks, the three rings dense
+    assert _kernel_calls(text) == 1

@@ -102,6 +102,28 @@ def test_streaming_tokens_arrive_per_step():
         assert len(streamed) == 5
 
 
+def test_step_records_count_the_rows_fetched():
+    """Every ``step`` record carries ``kv_read_rows`` beside
+    ``kv_live_rows`` and ``kv_rows``: on the dense path (the CPU's) the
+    whole plane of every active slot, ``kv_rows``' share of them, and
+    never under the live rows."""
+    net = _tiny_net()
+    slots = 4
+    with serving.DecodeSession(net, max_slots=slots, max_len=48,
+                               prefill_buckets=(8,), name="fetched") as sess:
+        sess.warmup()
+        for h in [sess.submit(p, max_new_tokens=m)
+                  for p, m in zip(_prompts([6, 3, 8]), (5, 9, 2))]:
+            h.result(30)
+    steps = [r for r in telemetry.trace.ring()["steps"]
+             if r.get("site") == "decode.fetched"
+             and r.get("kind") != "prefill"]
+    assert steps and {r["active"] for r in steps} > {1}
+    for r in steps:
+        assert r["kv_read_rows"] * slots == r["kv_rows"] * r["active"]
+        assert r["kv_live_rows"] <= r["kv_read_rows"]
+
+
 def test_eos_stops_generation_inclusive():
     net = _tiny_net(seed=3)
     prompt = _prompts([9], seed=3)[0]

@@ -250,9 +250,14 @@ def test_session_streams_the_references_greedy_tokens_across_churn(cfg):
              if r.get("site") == "decode.churn" and r.get("kind") != "prefill"]
     assert steps and all(
         {"routed_here", "routed_all", "experts_hit", "expert_load_max",
-         "kv_live_rows", "kv_rows"} <= set(r) for r in steps)
+         "kv_live_rows", "kv_read_rows", "kv_rows"} <= set(r) for r in steps)
     assert all(r["routed_all"] == r["active"] * 2 * 2 for r in steps)
-    assert all(0 < r["kv_live_rows"] <= r["kv_rows"] for r in steps)
+    assert all(0 < r["kv_live_rows"] <= r["kv_read_rows"] <= r["kv_rows"]
+               for r in steps)
+    # the dense path (full group and rings alike, off the TPU): the whole
+    # plane of every active slot
+    assert all(r["kv_read_rows"] * 3 == r["kv_rows"] * r["active"]
+               for r in steps)
     assert steps[0]["kv_rows"] == 3 * (1 * 64 + 2 * WINDOW)
 
 
@@ -401,3 +406,4 @@ def test_kv_cache_counts_its_rows():
     assert kv.rows == 2 * 2 * 16 + 3 * 2 * 4 and kv.max_len == 16
     assert kv.nbytes == 2 * 4 * 4 * kv.rows
     assert kv.live_rows([3, 9]) == 2 * (3 + 9) + 3 * (3 + 4)
+    assert kv.read_rows([3, 9]) == kv.rows

@@ -61,18 +61,20 @@ def test_step_is_write_then_dense_attention(kind, g):
         q = normal(L, S, heads, queries, D)
         k_new, v_new = normal(L, S, heads, D), normal(L, S, heads, D)
         want, ck2, cv2 = _np_step(ck, cv, q, k_new, v_new, lens, kind)
-        row, here, see = kv_cache.address(jnp.asarray(lens, jnp.int32), ROWS,
-                                          kind)
+        n = jnp.asarray(lens, jnp.int32)
+        at = row, here, see = kv_cache.address(n, ROWS, kind)
         rows_k = [jnp.asarray(_pack(a, g))[:, :, None] for a in k_new]
         rows_v = [jnp.asarray(_pack(a, g))[:, :, None] for a in v_new]
         for l in range(L):
             # (S, H, G, D) as it is, or the heads packed like a K/V row
             ql = jnp.asarray(q[l] if g == 1
                              else _pack(q[l, :, :, 0], g)[:, :, None])
-            got = kv_cache.attend(
-                ql, kv_cache.read(sk, l, rows_k[l], here),
-                kv_cache.read(sv, l, rows_v[l], here), see, D)
+            got = kv_cache.attend_row(ql, sk, sv, l, rows_k[l], rows_v[l],
+                                      n, at, kind, D)
             assert got.shape == ql.shape
+            np.testing.assert_array_equal(got, kv_cache.attend(
+                ql, kv_cache.read(sk, l, rows_k[l], here),
+                kv_cache.read(sv, l, rows_v[l], here), see, D))
             got = np.asarray(got).reshape(S, -1)[:, :heads * queries * D]
             np.testing.assert_allclose(
                 got[live], want[l].reshape(S, -1)[live], rtol=2e-5, atol=2e-5)
@@ -86,6 +88,101 @@ def test_step_is_write_then_dense_attention(kind, g):
             [n % ROWS if kind == "ring" else min(n, ROWS - 1)] for n in lens]
         ck, cv = ck2, cv2
         lens = np.where(np.arange(S) == STALE, ROWS, lens + 1)
+
+
+BLOCK = kv_cache.BLOCK
+#: how a length is named -> the length, given the plane's rows
+_LENS = {"0": lambda rows: 0, "1": lambda rows: 1,
+         "block-1": lambda rows: BLOCK - 1, "block": lambda rows: BLOCK,
+         "block+1": lambda rows: BLOCK + 1, "rows-1": lambda rows: rows - 1,
+         "rows(stale)": lambda rows: rows}
+#: g heads a stored row, G queries a K/V head, the head's width
+_FORMS = {"g1-3q": (1, 3, 128), "g1-8q": (1, 8, 128), "g2": (2, 1, 64)}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows", [2 * BLOCK, 4 * BLOCK])
+@pytest.mark.parametrize("form", sorted(_FORMS))
+@pytest.mark.parametrize("lens", sorted(_LENS) + ["unlike"])
+def test_block_kernel_is_the_dense_attention(lens, form, rows, dtype):
+    """``attend_blocks`` (the kernel, in the interpreter) against
+    ``read`` + ``attend`` on the same step: every slot at one length, or
+    all seven lengths side by side in one call. Blocks above a slot's
+    last live one are NaN in the kernel's cache, so a dead block fetched
+    and let through a mask fails; the rows of the last live block above
+    ``cache_len``, and the row the new token will take, hold finite
+    garbage, which the mask and ``k_new``/``v_new`` keep out."""
+    g, queries, d = _FORMS[form]
+    layers, heads, w, layer = 2, 2, g * d, 1
+    ns = [f(rows) for f in _LENS.values()] if lens == "unlike" \
+        else [_LENS[lens](rows)] * 2
+    rs = np.random.RandomState(len(lens) + rows)
+    normal = lambda *shape: jnp.asarray(
+        rs.standard_normal(shape).astype(np.float32)).astype(dtype)
+    k, v = (normal(layers, len(ns), heads, rows, w) for _ in "kv")
+    q = normal(len(ns), heads, queries, w)
+    k_new, v_new = normal(len(ns), heads, 1, w), normal(len(ns), heads, 1, w)
+    n = jnp.asarray(ns, jnp.int32)
+    at = kv_cache.address(n, rows, "full")
+    # off the TPU the step's entry reads dense, whatever the plane
+    assert kv_cache.blocked(rows, "full")
+    want = kv_cache.attend_row(q, k, v, layer, k_new, v_new, n, at, "full", d)
+    np.testing.assert_array_equal(want, kv_cache.attend(
+        q, kv_cache.read(k, layer, k_new, at[1]),
+        kv_cache.read(v, layer, v_new, at[1]), at[2], d))
+    dead = np.arange(rows)[None, :] >= np.array(
+        [-(-min(n, rows - 1) // BLOCK) * BLOCK for n in ns])[:, None]
+    k, v = (jnp.where(dead[None, :, None, :, None], jnp.nan, a)
+            for a in (k, v))
+    got = kv_cache.attend_blocks(q, k, v, layer, k_new, v_new, n, d)
+    assert got.shape == q.shape and got.dtype == want.dtype
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
+def test_rings_small_and_ragged_planes_are_read_dense():
+    """``blocked`` sends to the kernel a full group of more than one
+    block of rows, in whole blocks, and nothing else; ``fetched_rows``
+    counts what each path reads: the plane, or whole blocks of cached
+    rows and the new row, at a block's edge too never under the
+    ``cache_len + 1`` rows that are live."""
+    assert [kv_cache.blocked(r, "full") for r in
+            (48, 128, 200, 256, 1024, 4096)] == [False] * 3 + [True] * 3
+    assert not any(kv_cache.blocked(r, "ring") for r in (128, 1024, 4096))
+    lens = np.array([0, 1, 127, 128, 129, 1022, 1023, 1024, 4000])
+    np.testing.assert_array_equal(kv_cache.fetched_rows(lens, 1024, False),
+                                  [1024] * 9)
+    by_blocks = kv_cache.fetched_rows(lens, 1024, True)
+    np.testing.assert_array_equal(
+        by_blocks, [1, 129, 129, 129, 257, 1025, 1025, 1025, 1025])
+    assert (by_blocks >= np.minimum(lens + 1, 1024)).all()
+
+
+@pytest.mark.parametrize("platform,read", [("tpu", 2 * (129 + 257) + 3 * 256),
+                                           ("cpu", 2 * 512 + 3 * 256)])
+def test_kv_cache_counts_what_its_platform_reads(monkeypatch, platform, read):
+    """``KVCache`` asks the rule once, for the platform its arrays lie
+    on: a full group of two blocks goes by blocks on the TPU alone, the
+    ring and every other platform read their planes whole."""
+    import jax
+
+    from incubator_mxnet_tpu import serving
+
+    class On:
+        def __init__(self, array):
+            self.shape = array.shape
+
+        def devices(self):
+            return {type("Device", (), {"platform": platform})}
+
+    monkeypatch.setattr(jax, "device_put", On)
+    kv = serving.KVCache(
+        [dict(layers=2, heads=2, rows=256, head_dim=128, kind="full"),
+         dict(layers=3, heads=2, rows=128, head_dim=128, kind="ring")], 2)
+    assert kv.read_rows([128, 129]) == read
+    assert kv.live_rows([129, 130]) <= read <= kv.rows + 2 * 2
 
 
 def test_pack_and_store_rows():
