@@ -1,28 +1,47 @@
 """A decoder-only language model built from data: per layer an attention
-kind (``full`` or a sliding ``window``) and an FFN kind (``dense`` or
-``sparse``), over one set of widths.
+kind (``full``, a sliding ``window`` or ``latent``) and an FFN kind
+(``dense`` or ``sparse``), over one set of widths, under one of two
+residual rules.
 
-The layer is the one today's large open decoders share: grouped-query
-attention (``num_heads`` query heads over ``num_kv_heads`` K/V heads of
-``head_dim``), RMSNorm over each query and key head, rotary positions on
-the window layers and none on the full ones, RMSNorm on each sub-layer's
-OUTPUT (``h = h + norm(attn(h))``, ``h = h + norm(ffn(h))``), a
-SiLU-gated FFN, and on ``sparse`` layers a router over ``num_experts``
-experts of which the layer HOLDS ``experts_held`` (share ``expert_share``
-of ``num_experts // experts_held``: what one chip of an expert-parallel
-deployment holds) plus a shared expert. Routing drops nothing
-(``ops.moe.moe_route`` / ``moe_held_ffn``); what the experts held
-elsewhere would add is left out, as it is on that chip before the
-exchange.
+The layers are those today's large open decoders are built from:
+
+* grouped-query attention (``num_heads`` query heads over
+  ``num_kv_heads`` K/V heads of ``head_dim``), RMSNorm over each query and
+  key head, rotary positions on the window layers and none on the full
+  ones;
+* latent attention (DeepSeek-V2's, arXiv:2405.04434; ``latent`` gives its
+  ranks and head parts): queries through a normed ``q_rank`` bottleneck,
+  keys and values through ONE normed ``kv_rank`` latent a position beside
+  a rotated ``rope_dim`` key that every head shares, YaRN's frequencies.
+  What is cached is that one row ``[c | k_r]``. It has two formulations
+  of one arithmetic: whole sequences EXPAND the latent to every head's
+  key and value (``_latent_expanded``); the decode step ABSORBS the
+  expansion into the query and the output and attends over the cached
+  rows themselves (``_latent_absorbed``);
+* a SiLU-gated FFN, and on ``sparse`` layers a router over
+  ``num_experts`` experts of which the layer HOLDS ``experts_held`` (share
+  ``expert_share`` of ``num_experts // experts_held``: what one chip of an
+  expert-parallel deployment holds) plus a shared expert. Routing drops
+  nothing (``ops.moe.moe_route`` / ``moe_held_ffn``); what the experts
+  held elsewhere would add is left out, as it is on that chip before the
+  exchange;
+* the residual rule (``_sub``): one stream, ``h = h + g(h)``, or
+  ``streams`` of them under manifold-constrained hyper-connections
+  (``ops/hyper_connection.py``: per token and sub-layer a read, a write
+  and a doubly stochastic mix of the streams); and where the norm stands:
+  on each sub-layer's OUTPUT (``g = norm . f``) or, ``pre_norm``, on its
+  input (``g = f . norm``).
 
 What the block declares to be served by ``serving.DecodeSession``
 (docs/SERVING.md "What a block declares"):
 
-* ``cache_groups(max_len)``: the K/V cache as groups of layers with
-  their own row count: the full layers keep ``max_len`` rows, the window
-  layers a ring of ``window`` rows;
+* ``cache_groups(max_len)``: the cache as groups of layers with their own
+  row count and kind: the full layers keep ``max_len`` rows of K and V,
+  the window layers a ring of ``window`` rows, the latent layers
+  ``max_len`` rows of one tensor;
 * ``serve_prefill(tokens, n)``: one padded prompt -> the logits at its
-  last TRUE position and each group's K/V planes ``[Lg, Hkv, T, D]``;
+  last TRUE position and each group's planes ``[Lg, H, T, W]`` (K then V;
+  a latent group its one);
 * ``serve_step(tokens, cache_len, *caches)``: every slot one token on,
   the caches updated where they lie. Where a row lies, what a slot may
   read, the one-token attention and the writes are ``ops/kv_cache.py``'s;
@@ -30,8 +49,8 @@ What the block declares to be served by ``serving.DecodeSession``
 
 The arithmetic is plain ``jax.numpy`` over the parameter arrays (one
 ``invoke`` per entry point); the matrix products take their operands'
-type and sum in float32, the router, RoPE and every norm's statistics
-are float32.
+type and sum in float32, the router, RoPE, every norm's statistics and
+every hyper-connection coefficient are float32.
 """
 
 from __future__ import annotations
@@ -42,16 +61,19 @@ import jax
 import jax.numpy as jnp
 
 from ...ndarray.ndarray import invoke
-from ...ops import kv_cache
+from ...ops import hyper_connection, kv_cache
 from ...ops.moe import moe_held_ffn, moe_route
 from ..block import HybridBlock
 
 __all__ = ["HybridDecoder", "get_decoder"]
 
 #: the cache groups, in ``cache_groups`` order: full-attention layers keep
-#: every position, window layers a ring of the window's rows
-_FULL, _RING = 0, 1
-_KINDS = ("full", "ring")
+#: every position, window layers a ring of the window's rows, latent
+#: layers every position in one tensor
+_FULL, _RING, _LATENT = 0, 1, 2
+_KINDS = ("full", "ring", "latent")
+_GROUP_OF = {"full_attention": _FULL, "sliding_attention": _RING,
+             "latent_attention": _LATENT}
 
 #: queries per block of the prefill attention: scores are built a block
 #: at a time against the keys that block may see, so a 2048-token prompt
@@ -70,11 +92,32 @@ def rms_norm(x, g, eps):
     return (y * g.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, positions, theta):
+def yarn_inv_freq(dim, theta, factor, original_max_position_embeddings,
+                  beta_fast, beta_slow, **_):
+    """YaRN's inverse frequencies of a ``dim``-wide rotary part
+    (arXiv:2309.00071): ``theta ** (-2i / dim)`` where a frequency turns
+    more than ``beta_fast`` times over the original positions, that over
+    ``factor`` where it turns fewer than ``beta_slow`` times, a linear
+    ramp between."""
+    def turns_at(n):
+        return dim * math.log(original_max_position_embeddings
+                              / (n * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    keep = 1.0 - jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    inv = theta ** (-2.0 * i / dim)
+    return inv / factor * (1.0 - keep) + inv * keep
+
+
+def rope(x, positions, theta, inv=None):
     """Rotary embedding (the half-split convention) of ``x`` (..., T, D)
-    at ``positions`` (..., T), angles in float32."""
+    at ``positions`` (..., T), angles in float32; ``inv`` (D/2,) where
+    the inverse frequencies are not ``theta``'s plain ones."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if inv is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     ang = positions.astype(jnp.float32)[..., None] * inv      # (..., T, D/2)
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)
@@ -91,9 +134,15 @@ def gated_ffn(x, w_gate, w_up, w_down):
 class HybridDecoder(HybridBlock):
     """tokens (B, T) int32 -> logits (B, T, V); see the module docstring.
 
-    ``layer_types[i]`` is ``"full_attention"`` or ``"sliding_attention"``,
-    ``mlp_layer_types[i]`` ``"dense"`` or ``"sparse"`` (the published
-    configs' own words)."""
+    ``layer_types[i]`` is ``"full_attention"``, ``"sliding_attention"`` or
+    ``"latent_attention"``, ``mlp_layer_types[i]`` ``"dense"`` or
+    ``"sparse"`` (the published configs' own words). ``latent``, where a
+    layer is latent, is a dict: ``q_rank``, ``kv_rank``, ``nope_dim``,
+    ``rope_dim``, ``v_dim`` and ``rope_scaling`` (YaRN's keys); a cached
+    row ``[c | k_r]`` is stored at ``kv_cache.whole_tiles`` of ``kv_rank +
+    rope_dim`` lanes, the rest zero. ``streams`` over 1 puts the hyper-connections'
+    ``streams`` streams in the one's place, with ``hc`` their ``iters``,
+    ``eps`` and ``clamp``."""
 
     step_counters = ("routed_here", "routed_all", "experts_hit",
                      "expert_load_max")
@@ -102,8 +151,8 @@ class HybridDecoder(HybridBlock):
                  layer_types, mlp_layer_types, hidden_size, window=128,
                  rope_theta=1e6, eps=1e-5, num_experts=0, experts_held=0,
                  expert_share=0, experts_per_token=0, expert_hidden=0,
-                 routed_scale=1.0, max_length=4096, prefix=None,
-                 params=None):
+                 routed_scale=1.0, max_length=4096, latent=None, streams=1,
+                 hc=None, pre_norm=False, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         if len(layer_types) != len(mlp_layer_types):
             raise ValueError("layer_types and mlp_layer_types differ in "
@@ -118,6 +167,8 @@ class HybridDecoder(HybridBlock):
             raise ValueError(
                 f"share {expert_share} of {experts_held} experts does not "
                 f"lie in {num_experts}, or top-{experts_per_token} does not")
+        if "latent_attention" in layer_types and not latent:
+            raise ValueError("a latent_attention layer needs ``latent``")
         self._vocab, self._units = int(vocab_size), int(units)
         self._heads, self._kv_heads = int(num_heads), int(num_kv_heads)
         self._head_dim = int(head_dim)
@@ -128,24 +179,49 @@ class HybridDecoder(HybridBlock):
         self._first_expert = int(expert_share) * int(experts_held)
         self._top_k, self._scale = int(experts_per_token), float(routed_scale)
         self._max_length = int(max_length)
+        self._latent = dict(latent or {})
+        if self._latent:
+            self._latent["row"] = kv_cache.whole_tiles(
+                self._latent["kv_rank"] + self._latent["rope_dim"])
+        self._streams, self._pre_norm = int(streams), bool(pre_norm)
+        self._hc = dict(hc or {})
         # per layer (cache group, index in the group); layers per group
-        self._group_counts, self._group_of = [0, 0], []
+        self._group_counts, self._group_of = [0, 0, 0], []
         for attn, _ in self._kinds:
-            g = _FULL if attn == "full_attention" else _RING
+            g = _GROUP_OF[attn]
             self._group_of.append((g, self._group_counts[g]))
             self._group_counts[g] += 1
         c, d = self._units, self._head_dim
         hq, hkv = self._heads * d, self._kv_heads * d
         f, fe, e = int(hidden_size), int(expert_hidden), self._held
+        n = self._streams
         get = self.params.get
         with self.name_scope():
             self.embed = get("embed", shape=(self._vocab, c))
             self.final_norm = get("final_norm", shape=(c,), init="ones")
             self.head = get("head", shape=(self._vocab, c))
-            for i, (_, ffn) in enumerate(self._kinds):
-                shapes = {"q": (hq, c), "k": (hkv, c), "v": (hkv, c),
-                          "o": (c, hq), "q_norm": (d,), "k_norm": (d,),
-                          "attn_norm": (c,), "ffn_norm": (c,)}
+            for i, (attn, ffn) in enumerate(self._kinds):
+                shapes = {"attn_norm": (c,), "ffn_norm": (c,)}
+                if attn == "latent_attention":
+                    la, h = self._latent, self._heads
+                    shapes.update(
+                        q_a=(la["q_rank"], c), q_a_norm=(la["q_rank"],),
+                        q_b=(h * (la["nope_dim"] + la["rope_dim"]),
+                             la["q_rank"]),
+                        kv_a=(la["kv_rank"] + la["rope_dim"], c),
+                        kv_norm=(la["kv_rank"],),
+                        kv_b=(h * (la["nope_dim"] + la["v_dim"]),
+                              la["kv_rank"]),
+                        o=(c, h * la["v_dim"]))
+                else:
+                    shapes.update(q=(hq, c), k=(hkv, c), v=(hkv, c),
+                                  o=(c, hq), q_norm=(d,), k_norm=(d,))
+                if n > 1:
+                    for sub in ("attn", "ffn"):
+                        shapes.update({
+                            f"hc_{sub}_w": (2 * n + n * n, n * c),
+                            f"hc_{sub}_scale": (3,),
+                            f"hc_{sub}_bias": (2 * n + n * n,)})
                 if ffn == "dense":
                     shapes.update(gate=(f, c), up=(f, c), down=(c, f))
                 else:
@@ -156,8 +232,8 @@ class HybridDecoder(HybridBlock):
                         experts_down=(e, fe, c), shared_gate=(fe, c),
                         shared_up=(fe, c), shared_down=(c, fe))
                 for name, shape in shapes.items():
-                    init = "ones" if name.endswith("norm") else (
-                        "zeros" if name == "router_bias" else None)
+                    init = "ones" if name.endswith(("norm", "_scale")) \
+                        else ("zeros" if name.endswith("bias") else None)
                     setattr(self, f"layer{i}_{name}",
                             get(f"layer{i}_{name}", shape=shape, init=init))
 
@@ -167,15 +243,17 @@ class HybridDecoder(HybridBlock):
         return self._max_length
 
     def cache_groups(self, max_len):
-        """The K/V cache this block is served with, a dict per group of
+        """The cache this block is served with, a dict per group of
         layers: ``layers``, ``heads`` (K/V heads), ``rows``, ``head_dim``
-        and ``kind`` (``ops/kv_cache.py``). Groups a model has no layer
-        of are left out of the cache but keep their place in the
-        order."""
-        rows = (int(max_len), min(self._window, int(max_len)))
-        return [dict(layers=n, heads=self._kv_heads, rows=r,
-                     head_dim=self._head_dim, kind=kind)
-                for n, r, kind in zip(self._group_counts, rows, _KINDS)]
+        and ``kind`` (``ops/kv_cache.py``; a ``latent`` group is one
+        tensor of one ``row``-wide head). Groups a model has no layer of
+        are left out but keep their place in the order."""
+        rows = (int(max_len), min(self._window, int(max_len)), int(max_len))
+        heads = (self._kv_heads, self._kv_heads, 1)
+        widths = (self._head_dim, self._head_dim, self._latent.get("row"))
+        return [dict(layers=n, heads=h, rows=r, head_dim=w, kind=kind)
+                for n, h, r, w, kind in zip(self._group_counts, heads, rows,
+                                            widths, _KINDS) if n]
 
     # -- the arithmetic, over plain arrays -------------------------------------
     def _arrays(self):
@@ -186,6 +264,30 @@ class HybridDecoder(HybridBlock):
     def _layer(self, p, i):
         pre = f"layer{i}_"
         return {k[len(pre):]: v for k, v in p.items() if k.startswith(pre)}
+
+    def _sub(self, lp, x, name, f):
+        """Sub-layer ``f`` (``attn`` or ``ffn``) under the block's
+        residual rule: ``x`` is the stream, or the ``streams`` streams
+        (n, ..., C)."""
+        norm = lambda a: rms_norm(a, lp[name + "_norm"], self._eps)
+        g = (lambda u: f(norm(u))) if self._pre_norm \
+            else (lambda u: norm(f(u)))
+        if self._streams == 1:
+            return x + g(x)
+        with jax.named_scope("hyper_connection"):
+            pre, post, res = hyper_connection.coefficients(
+                x, lp[f"hc_{name}_w"], lp[f"hc_{name}_scale"],
+                lp[f"hc_{name}_bias"], **self._hc)
+            u = hyper_connection.read(x, pre)
+        y = g(u)
+        with jax.named_scope("hyper_connection"):
+            return hyper_connection.write(x, res, post, y)
+
+    def _embed(self, p, tokens):
+        """The stream a token enters on: its row, in every stream."""
+        x = jnp.take(p["embed"], tokens, axis=0)
+        return x if self._streams == 1 \
+            else jnp.broadcast_to(x, (self._streams,) + x.shape)
 
     def _qkv(self, lp, x, positions, window):
         """``x`` (B, T, C) -> q (B, Hkv, G, T, D), k and v (B, Hkv, T, D),
@@ -208,12 +310,11 @@ class HybridDecoder(HybridBlock):
         out = out.transpose(0, 3, 1, 2, 4).reshape(b, t, hkv * g * d)
         return _mm(out, lp["o"])
 
-    def _attend_sequence(self, q, k, v, window):
+    def _attend_sequence(self, q, k, v, window, scale):
         """Causal attention of whole sequences, a block of queries at a
         time over the keys that block may see: all before it, or on a
         window layer those less than ``window`` behind."""
         t = q.shape[3]
-        scale = 1.0 / math.sqrt(self._head_dim)
         outs = []
         for q0 in range(0, t, _Q_BLOCK):
             q1 = min(t, q0 + _Q_BLOCK)
@@ -230,6 +331,84 @@ class HybridDecoder(HybridBlock):
             outs.append(jnp.einsum("bhgqk,bhkd->bhgqd", w.astype(v.dtype),
                                    v[:, :, k0:q1]))
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=3)
+
+    # -- latent attention ------------------------------------------------------
+    def _latent_scale(self):
+        """The softmax scale: ``(nope + rope) ** -0.5`` times the square of
+        YaRN's ``0.1 mscale_all_dim ln(factor) + 1``."""
+        la = self._latent
+        ys = la["rope_scaling"]
+        m = 0.1 * ys.get("mscale_all_dim", 0) * math.log(ys["factor"]) + 1.0
+        return (la["nope_dim"] + la["rope_dim"]) ** -0.5 * m * m
+
+    def _latent_rows(self, lp, x, positions):
+        """``x`` (B, T, C), already normed -> the queries' two parts
+        ``q_nope`` (B, T, H, nope) and ``q_rope`` (B, T, H, rope), rotated,
+        and what is cached of a position, ``row`` (B, T, row) = ``[c |
+        k_r | 0]``: the normed latent and the rotated shared key."""
+        la, h = self._latent, self._heads
+        b, t, _ = x.shape
+        inv = yarn_inv_freq(la["rope_dim"], self._theta, **la["rope_scaling"])
+        c_q = rms_norm(_mm(x, lp["q_a"]), lp["q_a_norm"], self._eps)
+        q = _mm(c_q, lp["q_b"]).reshape(b, t, h, -1)
+        q_nope, q_rope = q[..., :la["nope_dim"]], q[..., la["nope_dim"]:]
+        q_rope = rope(q_rope.transpose(0, 2, 1, 3), positions[:, None, :],
+                      self._theta, inv).transpose(0, 2, 1, 3)
+        ckr = _mm(x, lp["kv_a"])
+        c = rms_norm(ckr[..., :la["kv_rank"]], lp["kv_norm"], self._eps)
+        k_r = rope(ckr[..., la["kv_rank"]:], positions, self._theta, inv)
+        row = jnp.concatenate([c, k_r], axis=-1)
+        return q_nope, q_rope, jnp.pad(
+            row, ((0, 0), (0, 0), (0, la["row"] - row.shape[-1])))
+
+    def _latent_expanded(self, lp, x, positions):
+        """Whole sequences: the latent expanded to every head's key
+        ``[k_nope | k_r]`` and value, causal attention over them. Returns
+        the attention's output (B, T, C) and the cached rows
+        (B, 1, T, row)."""
+        la, h = self._latent, self._heads
+        b, t, _ = x.shape
+        q_nope, q_rope, row = self._latent_rows(lp, x, positions)
+        with jax.named_scope("expand"):
+            kv = _mm(row[..., :la["kv_rank"]], lp["kv_b"]).reshape(
+                b, t, h, -1)
+            k_r = row[..., la["kv_rank"]:la["kv_rank"] + la["rope_dim"]]
+            k = jnp.concatenate(
+                [kv[..., :la["nope_dim"]],
+                 jnp.broadcast_to(k_r[:, :, None], (b, t, h, la["rope_dim"]))],
+                axis=-1).transpose(0, 2, 1, 3)
+            v = kv[..., la["nope_dim"]:].transpose(0, 2, 1, 3)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1).transpose(
+                0, 2, 1, 3)[:, :, None]
+        with jax.named_scope("attend"):
+            a = self._attend_sequence(q, k, v, 0, self._latent_scale())
+        return self._merge(lp, a), row[:, None]
+
+    def _latent_absorbed(self, lp, x, lens, cache, layer, at):
+        """The decode step: ``x`` (S, 1, C), every slot's new token. The
+        expansion's key half goes into the query and its value half into
+        the output, and the ``H`` heads attend as ``H`` queries over the
+        cached rows themselves (``kv_cache``'s ``latent`` kind). Returns
+        the attention's output (S, 1, C) and the new rows (S, 1, 1, row)."""
+        la, h = self._latent, self._heads
+        q_nope, q_rope, row = self._latent_rows(lp, x, lens[:, None])
+        w_kv = lp["kv_b"].reshape(h, la["nope_dim"] + la["v_dim"],
+                                  la["kv_rank"])
+        with jax.named_scope("absorb"):
+            q_lat = jnp.einsum("sthd,hdr->sthr", q_nope,
+                               w_kv[:, :la["nope_dim"]])
+            q = jnp.concatenate([q_lat, q_rope], axis=-1)
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, 0),
+                            (0, la["row"] - q.shape[-1])))
+        with jax.named_scope("attend"):
+            o_lat = kv_cache.attend_row(
+                q, cache, None, layer, row[:, None], None, lens, at,
+                "latent", la["row"], scale=self._latent_scale(),
+                v_width=la["kv_rank"])
+        with jax.named_scope("expand"):
+            out = jnp.einsum("sthr,hdr->sthd", o_lat,
+                             w_kv[:, la["nope_dim"]:])
+        return _mm(out.reshape(out.shape[0], 1, -1), lp["o"]), row[:, None]
 
     def _ffn(self, lp, x, live=None):
         """``x`` (N, C) -> the FFN's output and the routing's counts
@@ -253,22 +432,38 @@ class HybridDecoder(HybridBlock):
 
     def _sequence(self, p, tokens):
         """``tokens`` (B, T) -> the last layer's output (B, T, C) and
-        per layer the K/V planes (B, Hkv, T, D)."""
+        per layer its cache planes: K and V (B, Hkv, T, D), or the one
+        (B, 1, T, row) of a latent layer."""
         b, t = tokens.shape
-        x = jnp.take(p["embed"], tokens, axis=0)
+        x = self._embed(p, tokens)
         positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
         planes = []
-        for i, (attn, _) in enumerate(self._kinds):
-            lp = self._layer(p, i)
-            window = self._window if attn == "sliding_attention" else 0
-            with jax.named_scope("attention"):
-                q, k, v = self._qkv(lp, x, positions, window)
-                a = self._merge(lp, self._attend_sequence(q, k, v, window))
-            planes.append((k, v))
-            x = x + rms_norm(a, lp["attn_norm"], self._eps)
-            y, _ = self._ffn(lp, x.reshape(b * t, -1))
-            x = x + rms_norm(y.reshape(b, t, -1), lp["ffn_norm"], self._eps)
-        return x, planes
+        for attn, _ in self._kinds:
+            lp = self._layer(p, len(planes))
+
+            def attention(u):
+                if attn == "latent_attention":
+                    with jax.named_scope("latent_attention"):
+                        a, row = self._latent_expanded(lp, u, positions)
+                    planes.append((row,))
+                    return a
+                window = self._window if attn == "sliding_attention" else 0
+                with jax.named_scope("attention"):
+                    q, k, v = self._qkv(lp, u, positions, window)
+                    a = self._merge(lp, self._attend_sequence(
+                        q, k, v, window, 1.0 / math.sqrt(self._head_dim)))
+                planes.append((k, v))
+                return a
+
+            x = self._sub(lp, x, "attn", attention)
+            x = self._sub(lp, x, "ffn", lambda u: self._ffn(
+                lp, u.reshape(b * t, -1))[0].reshape(b, t, -1))
+        return self._out(x), planes
+
+    def _out(self, x):
+        """The streams summed into the one the head reads."""
+        return x if self._streams == 1 \
+            else x.astype(jnp.float32).sum(axis=0).astype(x.dtype)
 
     def _logits(self, p, x):
         return _mm(rms_norm(x, p["final_norm"], self._eps), p["head"])
@@ -289,9 +484,10 @@ class HybridDecoder(HybridBlock):
     def serve_prefill(self, tokens, n):
         """One padded prompt ``tokens`` (T,) of true length ``n`` (a
         traced scalar): the logits (V,) at position ``n - 1`` (the head
-        is applied there and nowhere else) and, per cache group, the K
-        and V planes ``[Lg, Hkv, T, D]`` of the whole bucket (positions
-        from ``n`` on hold garbage that no true position attended)."""
+        is applied there and nowhere else) and, per cache group, its
+        planes ``[Lg, H, T, W]`` of the whole bucket: K then V, or a
+        latent group's one (positions from ``n`` on hold garbage that no
+        true position attended)."""
         group_of, counts = self._group_of, self._group_counts
 
         def fn(p, tok, n_true):
@@ -303,55 +499,73 @@ class HybridDecoder(HybridBlock):
                 if count:
                     mine = [planes[i] for i, (gi, _) in enumerate(group_of)
                             if gi == g]
-                    out.append(jnp.stack([k[0] for k, _ in mine]))
-                    out.append(jnp.stack([v[0] for _, v in mine]))
+                    out += [jnp.stack([a[0] for a in tensor])
+                            for tensor in zip(*mine)]
             return tuple(out)
 
         return self._run(fn, [tokens, n], "hybrid_decoder_prefill")
 
     def serve_step(self, tokens, cache_len, *caches):
         """Every slot one token on. ``tokens``/``cache_len`` (S,);
-        ``caches`` the K and V array ``[Lg, S, Hkv, rows, D]`` of each
-        cache group (K then V, groups in ``cache_groups`` order, groups
-        of no layer left out). Returns the logits (S, V), the
-        ``step_counters`` as one int32 vector (over the slots whose
-        ``cache_len`` is not 0: a free slot's is), and the caches with
-        each slot's new row written (``kv_cache.address``)."""
+        ``caches`` the arrays ``[Lg, S, H, rows, W]`` of each cache group
+        (K then V, or a latent group's one; groups in ``cache_groups``
+        order). Returns the logits (S, V), the ``step_counters`` as one
+        int32 vector (over the slots whose ``cache_len`` is not 0: a free
+        slot's is), and the caches with each slot's new row written
+        (``kv_cache.address``)."""
         group_of = self._group_of
         present = [g for g, c in enumerate(self._group_counts) if c]
 
         def fn(p, tok, lens, *cs):
             lens = lens.astype(jnp.int32)
-            kv = {g: (cs[2 * j], cs[2 * j + 1])
-                  for j, g in enumerate(present)}
+            cs = iter(cs)
+            kv = {g: tuple(next(cs)
+                           for _ in range(kv_cache.tensors(_KINDS[g])))
+                  for g in present}
             at = {g: kv_cache.address(lens, kv[g][0].shape[3], _KINDS[g])
                   for g in present}
-            x = jnp.take(p["embed"], tok, axis=0)[:, None]     # (S, 1, C)
+            x = self._embed(p, tok)[..., None, :]          # (S, 1, C)
             live = lens > 0
-            new = {g: ([], []) for g in present}
+            new = {g: tuple([] for _ in kv[g]) for g in present}
             totals = dict.fromkeys(("routed_here", "experts_hit"), 0)
             load_max, sparse = 0, 0
-            for i, (attn, _) in enumerate(self._kinds):
+            for i in range(len(self._kinds)):
                 lp = self._layer(p, i)
                 g, j = group_of[i]
-                with jax.named_scope("attention"):
-                    q, k_new, v_new = self._qkv(
-                        lp, x, lens[:, None], self._window * (g == _RING))
-                    a = kv_cache.attend_row(
-                        q[:, :, :, 0], kv[g][0], kv[g][1], j, k_new, v_new,
-                        lens, at[g], _KINDS[g], self._head_dim)
-                    a = self._merge(lp, a[:, :, :, None])
-                new[g][0].append(k_new)
-                new[g][1].append(v_new)
-                x = x + rms_norm(a, lp["attn_norm"], self._eps)
-                y, c = self._ffn(lp, x[:, 0], live=live)
-                if c is not None:
+
+                def attention(u):
+                    if g == _LATENT:
+                        with jax.named_scope("latent_attention"):
+                            a, *rows = self._latent_absorbed(
+                                lp, u, lens, kv[g][0], j, at[g])
+                    else:
+                        with jax.named_scope("attention"):
+                            q, *rows = self._qkv(
+                                lp, u, lens[:, None],
+                                self._window * (g == _RING))
+                            a = kv_cache.attend_row(
+                                q[:, :, :, 0], *kv[g], j, *rows, lens,
+                                at[g], _KINDS[g], self._head_dim)
+                            a = self._merge(lp, a[:, :, :, None])
+                    for rows_new, row in zip(new[g], rows):
+                        rows_new.append(row)
+                    return a
+
+                counts = []
+
+                def ffn(u):
+                    y, c = self._ffn(lp, u[:, 0], live=live)
+                    counts.append(c)
+                    return y[:, None]
+
+                x = self._sub(lp, x, "attn", attention)
+                x = self._sub(lp, x, "ffn", ffn)
+                if counts[0] is not None:
                     sparse += 1
                     for name in totals:
-                        totals[name] = totals[name] + c[name]
-                    load_max = jnp.maximum(load_max, c["load_max"])
-                x = x + rms_norm(y[:, None], lp["ffn_norm"], self._eps)
-            logits = self._logits(p, x[:, 0])
+                        totals[name] = totals[name] + counts[0][name]
+                    load_max = jnp.maximum(load_max, counts[0]["load_max"])
+            logits = self._logits(p, self._out(x)[:, 0])
             counters = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
                 totals["routed_here"],
                 live.sum() * self._top_k * sparse,
@@ -370,8 +584,9 @@ class HybridDecoder(HybridBlock):
 #: callers' keyword arguments override any of it (depth, the experts
 #: held, the vocabulary slice: chipbench/configs/*.json say which)
 _SPECS = {
-    # LGAI-EXAONE/K-EXAONE-236B-A23B config.json: 48 layers ``LLLG``,
-    # layer 0 dense, 128 experts top-8 + 1 shared, window 128
+    # https://huggingface.co/LGAI-EXAONE/K-EXAONE-236B-A23B/blob/main/
+    # config.json: 48 layers ``LLLG``, layer 0 dense, 128 experts top-8
+    # + 1 shared, window 128
     "exaone_moe": dict(
         vocab_size=153600, units=6144, num_heads=64, num_kv_heads=8,
         head_dim=128, hidden_size=18432, window=128, rope_theta=1e6,
@@ -384,15 +599,45 @@ _SPECS = {
         experts_held=4, experts_per_token=2, expert_hidden=32,
         routed_scale=2.5, num_layers=3, pattern="LG", dense_layers=1,
         max_length=128),
+    # https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B/blob/main/
+    # config.json (``xing4_0``): 40 latent-attention layers (q rank 768,
+    # one 512 + 64 row a position, 32 heads of 128 + 64 / 128, YaRN x64
+    # over 4096), layers 0-1 dense, 64 experts top-4 + 1 shared, four
+    # hyper-connection streams of 20 Sinkhorn rounds, pre-norm
+    "xing4_29b": dict(
+        vocab_size=131072, units=3584, num_heads=32, num_kv_heads=32,
+        head_dim=192, hidden_size=9216, rope_theta=1e4, eps=1e-6,
+        num_experts=64, experts_held=64, experts_per_token=4,
+        expert_hidden=1024, routed_scale=2.0, num_layers=40, pattern="M",
+        dense_layers=2, pre_norm=True, streams=4,
+        hc=dict(iters=20, eps=1e-6, clamp=(-30.0, 30.0)),
+        latent=dict(q_rank=768, kv_rank=512, nope_dim=128, rope_dim=64,
+                    v_dim=128, rope_scaling=dict(
+                        factor=64, original_max_position_embeddings=4096,
+                        beta_fast=32, beta_slow=1, mscale_all_dim=1))),
+    "xing4_tiny": dict(
+        vocab_size=97, units=64, num_heads=4, num_kv_heads=4, head_dim=24,
+        hidden_size=96, rope_theta=1e4, eps=1e-6, num_experts=8,
+        experts_held=4, experts_per_token=2, expert_hidden=32,
+        routed_scale=2.0, num_layers=3, pattern="M", dense_layers=1,
+        pre_norm=True, streams=4,
+        hc=dict(iters=20, eps=1e-6, clamp=(-30.0, 30.0)),
+        latent=dict(q_rank=24, kv_rank=32, nope_dim=16, rope_dim=8,
+                    v_dim=16, rope_scaling=dict(
+                        factor=4, original_max_position_embeddings=16,
+                        beta_fast=32, beta_slow=1, mscale_all_dim=1)),
+        max_length=128),
 }
+_PATTERN = {"G": "full_attention", "L": "sliding_attention",
+            "M": "latent_attention"}
 
 
 def get_decoder(model_name="exaone_moe", **kwargs):
     """Decoder factory (``get_gpt``'s analog for the data-built decoder).
     ``num_layers``, ``pattern`` (``L`` a window layer, ``G`` a full one,
-    repeated) and ``dense_layers`` (the leading layers whose FFN is
-    dense) expand to the per-layer kinds unless ``layer_types`` /
-    ``mlp_layer_types`` are given."""
+    ``M`` a latent one, repeated) and ``dense_layers`` (the leading layers
+    whose FFN is dense) expand to the per-layer kinds unless
+    ``layer_types`` / ``mlp_layer_types`` are given."""
     if model_name not in _SPECS:
         raise ValueError(f"unknown decoder spec {model_name!r}; "
                          f"known {sorted(_SPECS)}")
@@ -401,8 +646,7 @@ def get_decoder(model_name="exaone_moe", **kwargs):
     n = int(spec.pop("num_layers"))
     pattern, dense = spec.pop("pattern"), int(spec.pop("dense_layers"))
     spec.setdefault("layer_types", [
-        "full_attention" if pattern[i % len(pattern)] == "G"
-        else "sliding_attention" for i in range(n)])
+        _PATTERN[pattern[i % len(pattern)]] for i in range(n)])
     spec.setdefault("mlp_layer_types", [
         "dense" if i < dense else "sparse" for i in range(n)])
     return HybridDecoder(**spec)

@@ -5,18 +5,22 @@ served blocks (``gluon/model_zoo/gpt.py``, ``decoder.py``) and the join of
 ``serving/decode.py`` call it. Plain ``jax.numpy`` over arrays, but for
 the one kernel ``attend_row`` takes on the TPU (``ops/pallas_decode.py``).
 
-A group of layers is ``"full"`` (position ``p`` at row ``p``) or a
-``"ring"`` (the last ``rows`` positions, ``p`` at row ``p mod rows``). A
+A group of layers is ``"full"`` (position ``p`` at row ``p``), a
+``"ring"`` (the last ``rows`` positions, ``p`` at row ``p mod rows``) or
+``"latent"``: addressed as ``full``, but ONE tensor a layer and not a K/V
+pair (``tensors``): a row is read as the key and its first ``v_width``
+lanes as the value (latent attention's compressed row ``[c | k_r]``, over
+which every query head attends: ``H`` 1, ``G`` the heads). A
 step never writes before it reads: each layer attends over its plane with
 the new token's row SELECTED in, and after the last layer all layers'
 rows go into the donated cache, which XLA then updates where it lies. The
 rule's plain statement is ``read`` + ``attend`` (a static leading-axis
 slice and a ``where``, which fuse into the attention: what a
 write-then-read would see, bit for bit); ``attend_row`` is the step's one
-entry and, where ``blocked`` says so (a full group of more than one block
-of rows) and the step is lowered for the TPU, fetches only the blocks
-below each slot's ``cache_len`` and joins the new row in the kernel
-instead.
+entry and, where ``blocked`` says so (a full or latent group of more than
+one block of rows) and the step is lowered for the TPU, fetches only the
+blocks below each slot's ``cache_len`` and joins the new row in the
+kernel instead.
 
 The stored row: the TPU keeps a minor dimension of whole 128-lane tiles
 minor, so a new row is few tiles; a minor dimension of 64 it laid out
@@ -41,12 +45,25 @@ LANES = 128
 BLOCK = pallas_decode.BLOCK
 
 
+def tensors(kind):
+    """Arrays a group of ``kind`` keeps a layer: K and V, or the one
+    tensor of a ``latent`` group that is read as both."""
+    return 1 if kind == "latent" else 2
+
+
 def pack(head_dim):
     """Heads side by side in one stored row: as many as fill ``LANES`` (2
     for GPT-2's 64), and 1 (a row is a head) where a head is that wide
     already or does not divide it."""
     return LANES // head_dim \
         if head_dim < LANES and LANES % head_dim == 0 else 1
+
+
+def whole_tiles(width):
+    """The lanes a row of ``width`` values is stored at: whole 128-lane
+    tiles, the rest zero (a latent row of 512 + 64 is kept 640 wide; at
+    576 the TPU lays the cache rows-minor and copies every plane)."""
+    return -(-width // LANES) * LANES
 
 
 def store_rows(x, heads, g):
@@ -97,18 +114,20 @@ def _heads_of_a_row(fn, q, w, head_dim):
         axis=2, keepdims=True)
 
 
-def attend(q, k, v, see, head_dim):
+def attend(q, k, v, see, head_dim, scale=None):
     """One token's attention over planes ``k``/``v`` (S, H, rows, W) under
-    the mask ``see``: float32 scores scaled by ``head_dim ** -0.5``,
-    float32 softmax cast to the values' type. ``q`` is (S, H, G, W), ``G``
-    queries a K/V head, or one stored row of several heads
-    (``_heads_of_a_row``, which does ``g`` times the useful work inside a
-    fusion that waits on the plane's bytes); returns ``q``'s shape."""
+    the mask ``see``: float32 scores scaled by ``scale`` (``head_dim **
+    -0.5`` where none is given), float32 softmax cast to the values' type.
+    ``q`` is (S, H, G, W), ``G`` queries a K/V head, or one stored row of
+    several heads (``_heads_of_a_row``, which does ``g`` times the useful
+    work inside a fusion that waits on the plane's bytes); returns ``q``'s
+    shape, at ``v``'s width where ``v`` is narrower than ``k``."""
+    scale = 1.0 / head_dim ** 0.5 if scale is None else scale
+
     def dense(q):
         sc = jnp.einsum("shgd,shtd->shgt", q, k,
                         preferred_element_type=jnp.float32)
-        sc = jnp.where(see[:, None, None, :], sc * (1.0 / head_dim ** 0.5),
-                       -jnp.inf)
+        sc = jnp.where(see[:, None, None, :], sc * scale, -jnp.inf)
         return jnp.einsum("shgt,shtd->shgd",
                           jax.nn.softmax(sc, axis=-1).astype(v.dtype), v)
 
@@ -117,12 +136,12 @@ def attend(q, k, v, see, head_dim):
 
 def blocked(rows, kind):
     """Whether a step's attention over a plane of ``rows``, lowered for
-    the TPU, goes by blocks of live rows (``pallas_decode``): a full
-    group of more than one block. A ring is read whole (its rows are all
-    live once it has wrapped, and few), as is a plane of one block or
-    less, or of no whole number of blocks (the kernel's copies start on
-    a block's edge)."""
-    return kind == "full" and rows > BLOCK and rows % BLOCK == 0
+    the TPU, goes by blocks of live rows (``pallas_decode``): a full or
+    latent group of more than one block. A ring is read whole (its rows
+    are all live once it has wrapped, and few), as is a plane of one
+    block or less, or of no whole number of blocks (the kernel's copies
+    start on a block's edge)."""
+    return kind != "ring" and rows > BLOCK and rows % BLOCK == 0
 
 
 def fetched_rows(cache_len, rows, by_blocks):
@@ -138,39 +157,46 @@ def fetched_rows(cache_len, rows, by_blocks):
 
 
 def attend_blocks(q, k_cache, v_cache, layer, k_new, v_new, cache_len,
-                  head_dim, interpret=None):
+                  head_dim, scale=None, v_width=None, interpret=None):
     """``attend_row`` by blocks of live rows: ``pallas_decode.attend``
     over the heads of a stored row (``interpret`` is the kernel's)."""
     return _heads_of_a_row(
         lambda q: pallas_decode.attend(q, k_cache, v_cache, layer, k_new,
                                        v_new, cache_len, head_dim,
+                                       scale=scale, v_width=v_width,
                                        interpret=interpret),
         q, k_cache.shape[-1], head_dim)
 
 
 def attend_row(q, k_cache, v_cache, layer, k_new, v_new, cache_len, at,
-               kind, head_dim):
+               kind, head_dim, scale=None, v_width=None):
     """Layer ``layer``'s one-token attention over the stacked caches
     (L, S, H, rows, W) with the new token's rows ``k_new``/``v_new``
     (S, H, 1, W) where ``at`` (the group's ``address`` of ``cache_len``)
     puts them: what writing the rows and then ``attend`` over the plane
     gives, to float tolerance, without the write. ``q`` as ``attend``
-    takes it. ``read`` + ``attend`` is the rule's plain statement and the
-    path of every plane that is not ``blocked`` and of every platform but
-    the TPU; a ``blocked`` plane lowered for the TPU goes by blocks of
-    live rows (the platform is the lowering's to know, not the host's
-    default backend)."""
+    takes it. A ``latent`` group has no ``v_cache``/``v_new`` (None): the
+    values are the first ``v_width`` lanes of the keys, and ``head_dim``
+    is the row's whole width (a row is one head). ``read`` + ``attend``
+    is the rule's plain statement and the path of every plane that is not
+    ``blocked`` and of every platform but the TPU; a ``blocked`` plane
+    lowered for the TPU goes by blocks of live rows (the platform is the
+    lowering's to know, not the host's default backend)."""
     _, here, see = at
 
-    def dense(q, k_cache, v_cache, k_new, v_new, cache_len):
-        return attend(q, read(k_cache, layer, k_new, here),
-                      read(v_cache, layer, v_new, here), see, head_dim)
+    def dense(q, cache_len, k_cache, k_new, v_cache=None, v_new=None):
+        k = read(k_cache, layer, k_new, here)
+        v = k[..., :v_width] if v_cache is None \
+            else read(v_cache, layer, v_new, here)
+        return attend(q, k, v, see, head_dim, scale)
 
-    def by_blocks(q, k_cache, v_cache, k_new, v_new, cache_len):
+    def by_blocks(q, cache_len, k_cache, k_new, v_cache=None, v_new=None):
         return attend_blocks(q, k_cache, v_cache, layer, k_new, v_new,
-                             cache_len, head_dim, interpret=False)
+                             cache_len, head_dim, scale, v_width,
+                             interpret=False)
 
-    operands = (q, k_cache, v_cache, k_new, v_new, cache_len)
+    operands = (q, cache_len, k_cache, k_new) \
+        + (() if v_cache is None else (v_cache, v_new))
     if not blocked(k_cache.shape[3], kind):
         return dense(*operands)
     return jax.lax.platform_dependent(*operands, tpu=by_blocks,
@@ -194,8 +220,8 @@ def write(cache, new, row):
 
 def join(cache, plane, slot, n, kind):
     """A prompt's prefilled ``plane`` (L, H, T, W) into slot ``slot``
-    (traced) of ``cache``: a full group from row 0; a ring the last
-    ``rows`` positions below the TRUE length ``n`` (traced), each at
+    (traced) of ``cache``: a full or latent group from row 0; a ring the
+    last ``rows`` positions below the TRUE length ``n`` (traced), each at
     ``position mod rows`` (rows no position below ``n`` maps to hold
     garbage that ``see`` masks)."""
     if kind == "ring":

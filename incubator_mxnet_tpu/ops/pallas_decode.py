@@ -19,8 +19,14 @@ The new token's row is never read from the cache: its score and value
 new row's score, sum 1, accumulator its value), which is what a
 write-then-read sees. A slot of no cached row (a free slot) is that state
 alone. Precisions are the dense path's: float32 scores scaled by
-``head_dim ** -0.5``, a running float32 maximum and sum, probabilities
-cast to the values' type before the product, float32 accumulation.
+``head_dim ** -0.5`` (or the caller's ``scale``), a running float32
+maximum and sum, probabilities cast to the values' type before the
+product, float32 accumulation.
+
+A group of ONE tensor (``kv_cache``'s ``latent`` kind: no ``v_cache``) is
+the same algorithm adapted by shape: one block is fetched a pair and its
+first ``v_width`` lanes are the values, so the compressed row is read once
+for both products, by all the ``G`` query heads that share it.
 
 Off the TPU the same kernel runs through the Pallas interpreter
 (``interpret=True``), which the tests use; the served step takes the
@@ -65,21 +71,24 @@ def _plan(cache_len, rows):
 
 
 def _kernel(layer_ref, total_ref, n_ref, slot_ref, block_ref,
-            q_ref, kn_ref, vn_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sem, *, scale, precision):
+            q_ref, kn_ref, vn_ref, *refs, scale, precision, v_width):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    # K and V planes with their buffers, or the one plane read as both
+    planes = len(refs) // 2 - 1
+    o_ref, sem = refs[planes], refs[-1]
+    fetched = list(zip(refs[:planes], refs[planes + 1:-1]))
     layer, total = layer_ref[0], total_ref[0]
-    h, g, w = q_ref.shape[1:]
+    h, g = q_ref.shape[1:3]
+    w = o_ref.shape[-1]
 
     def copies(t, buf):
         rows = pl.ds(pl.multiple_of(block_ref[t] * BLOCK, BLOCK), BLOCK)
         return [pltpu.make_async_copy(
             hbm.at[layer, slot_ref[t], :, rows, :], vmem.at[buf],
             sem.at[i, buf])
-            for i, (hbm, vmem) in enumerate(((k_hbm, k_buf),
-                                             (v_hbm, v_buf)))]
+            for i, (hbm, vmem) in enumerate(fetched)]
 
     # a slot of no cached row attends its new row alone
     o_ref[...] = jnp.broadcast_to(vn_ref[...], o_ref.shape)
@@ -111,7 +120,8 @@ def _kernel(layer_ref, total_ref, n_ref, slot_ref, block_ref,
                               (h, g, w))), carry))
         for c in copies(t, buf):
             c.wait()
-        k, v = k_buf[buf], v_buf[buf]                       # (H, block, W)
+        k = fetched[0][1][buf]                              # (H, block, W)
+        v = k[:, :, :v_width] if v_width else fetched[1][1][buf]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32, precision=precision) * scale
@@ -137,17 +147,24 @@ def _kernel(layer_ref, total_ref, n_ref, slot_ref, block_ref,
 
 
 def attend(q, k_cache, v_cache, layer, k_new, v_new, cache_len, head_dim,
-           interpret=None):
+           scale=None, v_width=None, interpret=None):
     """Layer ``layer``'s one-token attention: ``q`` (S, H, G, W), ``G``
     queries a stored K/V row; ``k_cache``/``v_cache`` the stacked
     (L, S, H, rows, W); ``k_new``/``v_new`` (S, H, 1, W) the new token's
     rows; ``cache_len`` (S,). Returns (S, H, G, W) in the values' type:
-    softmax over the slot's cached rows and its new row."""
+    softmax over the slot's cached rows and its new row. With no
+    ``v_cache`` (and no ``v_new``) the values are the first ``v_width``
+    lanes of the keys, and the result is that wide."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s, h, g, w = q.shape
     rows = k_cache.shape[3]
+    if v_cache is None:
+        v_new, caches = k_new[..., :v_width], (k_cache,)
+    else:
+        v_width, caches = None, (k_cache, v_cache)
+    w_out = v_new.shape[-1]
     if interpret is None:
         interpret = not pallas_attention.pallas_available()
     gp = -(-g // _SUBLANES) * _SUBLANES
@@ -158,22 +175,22 @@ def attend(q, k_cache, v_cache, layer, k_new, v_new, cache_len, head_dim,
                  else jax.lax.Precision.HIGHEST)
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=head_dim ** -0.5,
-                          precision=precision),
+        functools.partial(
+            _kernel, scale=head_dim ** -0.5 if scale is None else scale,
+            precision=precision, v_width=v_width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5, grid=(1,),
-            in_specs=[whole, whole, whole,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[whole, whole, whole]
+            + [pl.BlockSpec(memory_space=pl.ANY) for _ in caches],
             out_specs=whole,
-            scratch_shapes=[pltpu.VMEM((2, h, BLOCK, w), k_cache.dtype),
-                            pltpu.VMEM((2, h, BLOCK, w), v_cache.dtype),
-                            pltpu.SemaphoreType.DMA((2, 2))]),
-        out_shape=jax.ShapeDtypeStruct((s, h, gp, w), v_cache.dtype),
-        # the four blocks in flight, and room for the rest
+            scratch_shapes=[pltpu.VMEM((2, h, BLOCK, w), c.dtype)
+                            for c in caches]
+            + [pltpu.SemaphoreType.DMA((len(caches), 2))]),
+        out_shape=jax.ShapeDtypeStruct((s, h, gp, w_out), k_cache.dtype),
+        # the blocks in flight (two a plane), and room for the rest
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=(16 << 20)
             + 4 * h * BLOCK * w * k_cache.dtype.itemsize),
         interpret=interpret, name="kv_decode_attention",
     )(jnp.asarray(layer, jnp.int32).reshape(1), total, n, slot_of,
-      block_of, q, k_new, v_new, k_cache, v_cache)
+      block_of, q, k_new, v_new, *caches)
     return out[:, :, :g]

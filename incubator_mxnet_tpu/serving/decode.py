@@ -4,7 +4,7 @@ The serving half of the decoder-LLM workload (ISSUE 12): a
 **prefill/decode split** over a slot-based, device-resident KV cache
 (one ``[layers, slots, heads, rows, head_dim]`` array pair per group of
 layers the block declares: ``max_len`` rows, or a ring of a window's
-rows), in the full-AOT stance of
+rows; a ``latent`` group one array, not a pair), in the full-AOT stance of
 arXiv:1810.09868 / arXiv:1605.08695 — a small FIXED set of pre-compiled
 executables with ALL dynamism carried as device-resident state or tiny
 per-step host vectors, never as recompilation:
@@ -85,8 +85,9 @@ _PREFILL_PHASES = ("dispatch", "join", "fence")
 #: cache groups, the join takes ``[slot, true length]``; 4: GPT's cache
 #: in the stored form, several heads side by side in a 128-lane row; 5:
 #: both blocks and the join through ``ops/kv_cache.py``; 6: a full
-#: group's attention by blocks of live rows on the TPU).
-_PROGRAM_REVISION = 6
+#: group's attention by blocks of live rows on the TPU; 7: a group of
+#: one tensor, the block kernel's operands by planes).
+_PROGRAM_REVISION = 7
 
 
 def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:
@@ -104,21 +105,25 @@ def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:
 
 
 class KVCache:
-    """Device-resident per-slot K/V planes, one stacked array pair
-    ``[Lg, S, H, rows, D]`` (k and v) per GROUP of layers.
+    """Device-resident per-slot cache planes, stacked arrays
+    ``[Lg, S, H, rows, D]`` per GROUP of layers: a K and V pair, or the
+    one tensor of a ``latent`` group (``kv_cache.tensors``).
 
     ``groups`` is what the served block declares (``cache_groups``): per
     group ``layers``, ``heads``, ``rows``, ``head_dim`` and ``kind``
-    (``"full"`` or ``"ring"``: ``ops/kv_cache.py`` holds the rules).
-    ``heads`` and ``head_dim`` are the STORED form, which is the
+    (``"full"``, ``"ring"`` or ``"latent"``: ``ops/kv_cache.py`` holds the
+    rules). ``heads`` and ``head_dim`` are the STORED form, which is the
     block's to choose and nothing here looks inside: a K/V head a row
-    (the data-built decoder's 8 heads of 128), or several heads side by
+    (the data-built decoder's 8 heads of 128), several heads side by
     side in one row of 128 lanes (``gpt.py``: GPT-2 XL's 25 heads of 64
-    are ``heads`` 13, ``head_dim`` 128). The block's ``serve_prefill``
+    are ``heads`` 13, ``head_dim`` 128), or latent attention's one
+    compressed row a position that every head reads (``heads`` 1,
+    ``head_dim`` the row's stored width). The block's ``serve_prefill``
     returns its planes, and its ``serve_step`` reads and writes the
     cache, in that same form. A group of no layer holds nothing.
-    ``arrays`` is the flat list the executables take and return: k then
-    v, group by group.
+    ``arrays`` is the flat list the executables take and return: group
+    by group, k then v or the one; ``kinds`` and ``shapes`` name each
+    group, ``array_kinds`` and ``specs()`` each array.
 
     Owned by a :class:`DecodeSession`; rebound on every donated
     join/decode dispatch. Both executables only ever update the stacked
@@ -134,21 +139,31 @@ class KVCache:
     def __init__(self, groups, slots: int, dtype="float32"):
         self.groups = [dict(g) for g in groups if int(g["layers"])]
         self.dtype = jnp.dtype(dtype)
+        self.kinds = [g["kind"] for g in self.groups]
         self.shapes = [(int(g["layers"]), int(slots), int(g["heads"]),
                         int(g["rows"]), int(g["head_dim"]))
                        for g in self.groups]
+        #: per array of ``arrays``: its group's kind and shape
+        self.array_kinds, self._array_shapes = [], []
+        for kind, shape in zip(self.kinds, self.shapes):
+            n = kv_cache.tensors(kind)
+            self.array_kinds += [kind] * n
+            self._array_shapes += [shape] * n
         self.arrays = [jax.device_put(jnp.zeros(shape, self.dtype))
-                       for shape in self.shapes for _ in "kv"]
+                       for shape in self._array_shapes]
         # which groups a step attends by blocks of live rows: the rule's
         # answer for the platform the arrays lie on, asked once
         on_tpu = {d.platform for a in self.arrays for d in a.devices()} \
             == {"tpu"}
-        self._by_blocks = [on_tpu and kv_cache.blocked(r, g["kind"])
-                           for g, (_, _, _, r, _)
-                           in zip(self.groups, self.shapes)]
+        self._by_blocks = [on_tpu and kv_cache.blocked(shape[3], kind)
+                           for kind, shape in zip(self.kinds, self.shapes)]
+        # bytes a position of a layer holds as stored, every tensor
+        self._row_bytes = [h * d * kv_cache.tensors(kind)
+                           * self.dtype.itemsize
+                           for kind, (_, _, h, _, d)
+                           in zip(self.kinds, self.shapes)]
 
-    # a cache of one group (every model before the window layers) reads
-    # as the one array pair it is
+    # a cache of one K/V group (GPT-2's) reads as the one array pair it is
     @property
     def shape(self) -> Tuple[int, ...]:
         (shape,) = self.shapes
@@ -156,16 +171,18 @@ class KVCache:
 
     @property
     def k(self):
-        return self.arrays[0]
+        k, _ = self.arrays
+        return k
 
     @property
     def v(self):
-        return self.arrays[1]
+        _, v = self.arrays
+        return v
 
     def specs(self) -> list:
         """``jax.ShapeDtypeStruct`` of every array, in ``arrays`` order."""
         return [jax.ShapeDtypeStruct(shape, self.dtype)
-                for shape in self.shapes for _ in "kv"]
+                for shape in self._array_shapes]
 
     @property
     def slots(self) -> int:
@@ -177,13 +194,14 @@ class KVCache:
 
     @property
     def nbytes(self) -> int:
-        return 2 * self.dtype.itemsize * sum(
-            int(np.prod(shape)) for shape in self.shapes)
+        return self.dtype.itemsize * sum(
+            int(np.prod(shape)) for shape in self._array_shapes)
 
     @property
     def rows(self) -> int:
         """Rows the cache holds in all: layers x slots x rows, summed
-        over the groups."""
+        over the groups (a row is a position of a layer, whatever it
+        stores there)."""
         return sum(l * s * r for l, s, _, r, _ in self.shapes)
 
     def live_rows(self, lens) -> int:
@@ -193,18 +211,20 @@ class KVCache:
         return int(sum(l * np.minimum(lens, r).sum()
                        for l, _, _, r, _ in self.shapes))
 
-    def read_rows(self, cache_len) -> int:
+    def read(self, cache_len) -> Tuple[int, int]:
         """The rows a decode step's attention READS for slots whose
-        cached lengths (before the step's token) are ``cache_len``:
-        whole blocks up to each length and the new row where a group
-        goes by blocks, every row of the plane where it is read whole
-        (``kv_cache.fetched_rows``). Never under ``live_rows`` of
-        ``cache_len + 1``."""
+        cached lengths (before the step's token) are ``cache_len``, and
+        the bytes they hold as stored. Rows: whole blocks up to each
+        length and the new row where a group goes by blocks, every row
+        of the plane where it is read whole (``kv_cache.fetched_rows``),
+        summed over the groups; never under ``live_rows`` of ``cache_len
+        + 1``. Bytes: a row's ``heads x head_dim`` values in every tensor
+        of its group (K and V, or a latent group's one)."""
         cache_len = np.asarray(cache_len, np.int64)
-        return int(sum(
-            l * kv_cache.fetched_rows(cache_len, r, by_blocks).sum()
-            for by_blocks, (l, _, _, r, _)
-            in zip(self._by_blocks, self.shapes)))
+        rows = [int(l * kv_cache.fetched_rows(cache_len, r, by_blocks).sum())
+                for by_blocks, (l, _, _, r, _)
+                in zip(self._by_blocks, self.shapes)]
+        return sum(rows), sum(n * b for n, b in zip(rows, self._row_bytes))
 
 
 _DONE = object()
@@ -472,6 +492,9 @@ class DecodeSession:
         # waits with nothing to run its ``idle``
         self._turn = telemetry.trace.Turn(self._site, _STEP_PHASES)
         self._t_mark = time.perf_counter()
+        # the last step's tokens and record while they wait for the next
+        # step to be on the device (``_hand_on``); the scheduler's alone
+        self._held: Optional[tuple] = None
         self._worker = threading.Thread(
             target=self._loop, name=f"mxtpu-decode-{self.name}",
             daemon=True)
@@ -561,7 +584,7 @@ class DecodeSession:
             ex = self._joins.get(bucket)
             if ex is not None:
                 return ex
-            kinds = [g["kind"] for g in self._kv.groups for _ in "kv"]
+            kinds = self._kv.array_kinds
             n_arrays = len(kinds)
 
             def compile_join():
@@ -572,10 +595,9 @@ class DecodeSession:
                         kv_cache.join(cache, plane, slot, n, kind)
                         for cache, plane, kind in zip(caches, planes, kinds))
 
-                planes = [jax.ShapeDtypeStruct((l, h, bucket, d),
-                                               self._kv.dtype)
-                          for l, _, h, _, d in self._kv.shapes
-                          for _ in "kv"]
+                planes = [jax.ShapeDtypeStruct(
+                    (spec.shape[0], spec.shape[2], bucket, spec.shape[4]),
+                    spec.dtype) for spec in self._kv.specs()]
                 at = jax.ShapeDtypeStruct((2,), jnp.int32)
                 jitted = jax.jit(join, donate_argnums=tuple(range(n_arrays))
                                  if self._donate else ())
@@ -866,6 +888,7 @@ class DecodeSession:
         while True:
             admits, shed = self._wait_for_work()
             if admits is None:
+                self._hand_on()
                 return
             for req in shed:
                 self.metrics.observe_shed()
@@ -895,6 +918,7 @@ class DecodeSession:
                 except Exception as exc:   # noqa: BLE001 — worker survives
                     logger.exception("decode step failed; failing the "
                                      "active sequences")
+                    self._hand_on()     # what the step before it gave
                     with self._cv:
                         active = [(i, s) for i, s in enumerate(self._slots)
                                   if s is not None]
@@ -972,6 +996,9 @@ class DecodeSession:
                     *self._kv.arrays, *planes,
                     np.asarray([slot, n], np.int32)))
             with turn.phase("fence"):
+                # the step before's tokens, under the prefill and not
+                # behind it: as late after their fence as a step hands on
+                self._hand_on()
                 first_tok = int(first)                # the D2H fence
         t_fence = time.perf_counter()
         self._t_mark = t_fence
@@ -1018,7 +1045,14 @@ class DecodeSession:
         """One decode step for every occupied slot (free slots compute
         too — their rows are ignored and their writes land in freed
         space). The ONLY hot-path dispatch: no shape in it depends on
-        which slots are live or how old their sequences are."""
+        which slots are live or how old their sequences are.
+
+        Between one step's fence and the next step's dispatch the device
+        waits for this thread, so only what the next dispatch needs is
+        done there: the mirrors advance and finished slots retire. The
+        tokens go to their callers, and the step's record is written
+        (:meth:`_hand_on`), once the NEXT step is on the device, or an
+        admission's prefill; at once where a slot finished."""
         with self._cv:
             active = [i for i, s in enumerate(self._slots)
                       if s is not None]
@@ -1043,10 +1077,11 @@ class DecodeSession:
                 nxt, *self._kv.arrays = ex(
                     self._params, *self._kv.arrays, cache_len_d, tokens_d)
             with turn.phase("fence"):
+                self._hand_on()          # the last step's, under this one
                 nxt_np = np.asarray(nxt)              # the D2H fence
         t1 = time.perf_counter()
-        dt = t1 - t0
-        self.metrics.observe_step(k, dt, k)
+        self.metrics.observe_step(k, t1 - t0, k)
+        toks = nxt_np.tolist()
         finished: List[int] = []
         first_steps: List[_Request] = []
         with turn.phase("deliver"), self._cv:
@@ -1055,10 +1090,9 @@ class DecodeSession:
                 if st is None:        # closed underneath us
                     continue
                 self._cache_len[i] += 1
-                tok = int(nxt_np[i])
+                tok = toks[i]
                 self._tokens[i] = tok
                 st.generated += 1
-                st.req.handle._put(tok)
                 if st.t0_steps is None:
                     st.t0_steps = t0
                     if st.req.trace is not None:
@@ -1066,24 +1100,42 @@ class DecodeSession:
                 if (tok == st.req.eos_id or st.generated >= st.req.max_new
                         or self._cache_len[i] >= self.max_len):
                     finished.append(i)
-        for req in first_steps:
-            telemetry.trace.record(req.trace, "first_step", t0, t1,
-                                   active=k)
+        # the handles are looked up when the tokens are handed on: no
+        # slot changes hands in between
+        self._held = (turn, t0, t1, active, cache_len[active], toks,
+                      first_steps)
         with turn.phase("finish"):
+            if finished:
+                self._hand_on()     # a last token before its stream ends
             for i in finished:
                 self._finish_slot(i)
         self._t_mark = time.perf_counter()
-        # the block's own per-step integers ride behind the tokens; the
-        # cache's live rows are the scheduler's to know (the rows this
-        # step read: each active slot's length with its new token; the
-        # rows its attention fetched for them: whole blocks, or planes)
-        turn.close(t0, dt, active=k,
-                   kv_live_rows=self._kv.live_rows(cache_len[active] + 1),
-                   kv_read_rows=self._kv.read_rows(cache_len[active]),
-                   kv_rows=self._kv.rows,
-                   **dict(zip(self._counters,
-                              nxt_np[self.max_slots:].tolist())))
         self.metrics.observe_slots(self.active_slots)
+
+    def _hand_on(self) -> None:
+        """Hand the held step's tokens to their callers and write its
+        ledger record; no slot has changed hands since the step. The
+        block's own per-step integers ride behind the tokens; the cache's
+        live rows are the scheduler's to know (the rows the step read:
+        each active slot's length with its new token; the rows its
+        attention fetched for them: whole blocks, or planes)."""
+        held, self._held = self._held, None
+        if held is None:
+            return
+        turn, t0, t1, active, lens, toks, first_steps = held
+        for i in active:
+            st = self._slots[i]
+            if st is not None:        # not closed underneath us
+                st.req.handle._put(toks[i])
+        for req in first_steps:
+            telemetry.trace.record(req.trace, "first_step", t0, t1,
+                                   active=len(active))
+        read_rows, read_bytes = self._kv.read(lens)
+        turn.close(t0, t1 - t0, active=len(active),
+                   kv_live_rows=self._kv.live_rows(lens + 1),
+                   kv_read_rows=read_rows, kv_read_bytes=read_bytes,
+                   kv_rows=self._kv.rows,
+                   **dict(zip(self._counters, toks[self.max_slots:])))
 
     def _finish_slot(self, slot: int) -> None:
         """Retire a finished sequence: resolve its handle, free the slot

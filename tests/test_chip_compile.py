@@ -241,3 +241,71 @@ def test_sparse_decoder_step_aliases_both_cache_groups_published_widths(
                           text)) == 3 * 3      # gate, up, down x 3 layers
     # the full layer attends by blocks, the three rings dense
     assert _kernel_calls(text) == 1
+
+
+def test_latent_decoder_step_aliases_its_one_cache_tensor_published_widths(
+        one_chip):
+    """The data-built decoder's served decode step at Xing4.0-29B-A4B's
+    widths (hidden 3584, 32 latent-attention heads over a 512 + 64 row,
+    four hyper-connection streams; two layers of the 40, one dense and
+    one sparse with 8 experts held of 64), 32 slots of 2048 positions,
+    donated as ``DecodeSession`` lowers it. The one cache tensor, a row
+    stored 640 lanes wide, aliases its output and keeps its rows minor
+    (``{4,3,2,1,0}``: a 576-wide row this compiler lays ``T``-minor, 72
+    tiles a new row and a relayout of each plane a step, and Mosaic
+    refuses a 576-lane slice of it); outside fusions the only ops of the
+    cache's or a plane's shape are the in-place ``dynamic-update-slice``
+    of the new rows; each layer attends through the block kernel (one
+    plane fetched, its first 512 lanes the values) and each sub-layer's
+    Sinkhorn rounds are one kernel."""
+    from incubator_mxnet_tpu import serving
+    from incubator_mxnet_tpu.gluon.model_zoo import get_decoder
+
+    layers, slots, t, row = 2, 32, 2048, 640
+    net = get_decoder("xing4_29b", num_layers=layers, dense_layers=1,
+                      experts_held=8, expert_share=0, vocab_size=16384,
+                      max_length=t)
+    net.cast("bfloat16")
+    net.initialize(init="zeros")
+    with serving.DecodeSession(net, max_slots=slots, max_len=t,
+                               prefill_buckets=(256,), name="xing2",
+                               donate=True, artifact_dir="") as sess:
+        assert sess._kv.shapes == [(layers, slots, 1, t, row)]
+        (cache,) = [_spec(one_chip, s.shape, s.dtype)
+                    for s in sess._kv.specs()]
+        vec = _spec(one_chip, (slots,), jnp.int32)
+        params = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
+        compiled = jax.jit(sess._decode_apply, donate_argnums=(1,)).lower(
+            params, cache, vec, vec).compile()
+        kv_bytes = sess._kv.nbytes
+    n = len(params)
+    text = compiled.as_text()
+    alias = re.search(r"input_output_alias=\{[^\n]*?\}, entry", text)
+    assert alias and f"{{1}}: ({n}, {{}}" in alias.group(0), \
+        "the cache is not aliased"
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= kv_bytes == layers * slots * t * row * 2
+    assert mem.temp_size_in_bytes < kv_bytes // 10
+    big = re.compile(r"bf16\[(%d,)?(1,)?%d,1,%d,%d\]" % (layers, slots, t,
+                                                         row))
+    whole = "bf16[%d,%d,1,%d,%d]{" % (layers, slots, t, row)
+    beside, layouts, fused = {}, [], False
+    for line in text.splitlines():
+        if line and not line.startswith((" ", "}")):
+            fused = not line.startswith("ENTRY")   # only the entry's ops
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if fused or not m or not big.match(m.group(1)):
+            continue
+        if m.group(2) == "parameter" and m.group(1).startswith(whole):
+            layouts.append(m.group(1)[len(whole):].split(":")[0].rstrip("}"))
+        if m.group(2) not in ("parameter", "bitcast", "get-tuple-element"):
+            beside[m.group(2)] = beside.get(m.group(2), 0) + 1
+    assert layouts == ["4,3,2,1,0"], layouts
+    assert beside == {"dynamic-update-slice": slots}, beside
+    assert _kernel_calls(text) == layers
+    assert len(re.findall(
+        r"= \S+ custom-call\([^\n]*hyper_connection_coefficients",
+        text)) == 2 * layers
+    assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot-none",
+                          text)) == 3          # gate, up, down of one layer

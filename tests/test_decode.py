@@ -102,6 +102,49 @@ def test_streaming_tokens_arrive_per_step():
         assert len(streamed) == 5
 
 
+def test_a_steps_tokens_are_handed_on_under_the_next_step():
+    """Between a fence and the next dispatch the scheduler does only what
+    the dispatch needs: when a step is dispatched its caller has not yet
+    been handed the token of the step before (it is, under this step's
+    device time); an admission's prefill hands on under ITS device time,
+    so no token waits behind it, and a stream's last token comes before
+    its end."""
+    net = _tiny_net(max_length=256)   # a stream long enough to be joined
+    with serving.DecodeSession(net, max_slots=2, max_len=256,
+                               prefill_buckets=(8,), name="handon") as sess:
+        sess.warmup()
+        seen = {"step": [], "prefill": []}
+        step, prefill = sess._dec_ex, sess._prefill
+
+        def stepping(*args):
+            seen["step"].append(len(first.tokens))
+            return step(*args)
+
+        class Prefilling:
+            def __getattr__(self, name):
+                return getattr(prefill, name)
+
+            def __call__(self, prompt):
+                if seen["step"]:
+                    seen["prefill"].append((len(seen["step"]),
+                                            len(first.tokens)))
+                return prefill(prompt)
+
+        sess._dec_ex, sess._prefill = stepping, Prefilling()
+        first = sess.submit(_prompts([6])[0], max_new_tokens=200)
+        while len(seen["step"]) < 3:
+            time.sleep(0.005)
+        second = sess.submit(_prompts([5], seed=3)[0], max_new_tokens=2)
+        assert len(first.result(60)) == 200 and len(second.result(60)) == 2
+        assert list(first) == first.result(1)
+    # the prefill's token, then one a step: the step before's still held
+    held = [1 + i - n for i, n in enumerate(seen["step"])]
+    assert held[0] == 0 and set(held[1:]) <= {0, 1} and 1 in held
+    # held when the prefill is called, handed on before the step after it
+    (n_steps, n_tokens), = seen["prefill"]
+    assert n_tokens == n_steps and held[n_steps] == 0
+
+
 def test_step_records_count_the_rows_fetched():
     """Every ``step`` record carries ``kv_read_rows`` beside
     ``kv_live_rows`` and ``kv_rows``: on the dense path (the CPU's) the

@@ -406,4 +406,4 @@ def test_kv_cache_counts_its_rows():
     assert kv.rows == 2 * 2 * 16 + 3 * 2 * 4 and kv.max_len == 16
     assert kv.nbytes == 2 * 4 * 4 * kv.rows
     assert kv.live_rows([3, 9]) == 2 * (3 + 9) + 3 * (3 + 4)
-    assert kv.read_rows([3, 9]) == kv.rows
+    assert kv.read([3, 9]) == (kv.rows, kv.nbytes)

@@ -181,7 +181,7 @@ def test_kv_cache_counts_what_its_platform_reads(monkeypatch, platform, read):
     kv = serving.KVCache(
         [dict(layers=2, heads=2, rows=256, head_dim=128, kind="full"),
          dict(layers=3, heads=2, rows=128, head_dim=128, kind="ring")], 2)
-    assert kv.read_rows([128, 129]) == read
+    assert kv.read([128, 129])[0] == read
     assert kv.live_rows([129, 130]) <= read <= kv.rows + 2 * 2
 
 
