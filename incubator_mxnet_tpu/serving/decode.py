@@ -32,7 +32,10 @@ steps), finished sequences free their slot without disturbing
 neighbours. The scheduler mirrors ``cache_len``/active state on the
 host — it is fully determined by its own actions, so the only per-step
 device→host traffic is the ``[slots]`` next-token vector the clients
-need anyway.
+need anyway. While every slot stays busy a second step is launched
+before the first one's tokens are fetched (its ``tokens`` are the first
+one's device output), so the device does not wait a host round trip per
+token (docs/SERVING.md "A second step in flight").
 
 Front-door semantics mirror :class:`~.server.ModelServer`: bounded-queue
 backpressure (``QueueFullError.retry_after``), per-request
@@ -86,8 +89,9 @@ _PREFILL_PHASES = ("dispatch", "join", "fence")
 #: in the stored form, several heads side by side in a 128-lane row; 5:
 #: both blocks and the join through ``ops/kv_cache.py``; 6: a full
 #: group's attention by blocks of live rows on the TPU; 7: a group of
-#: one tensor, the block kernel's operands by planes).
-_PROGRAM_REVISION = 7
+#: one tensor, the block kernel's operands by planes; 8: the step returns
+#: its tokens once more, as the vector a step launched ahead takes).
+_PROGRAM_REVISION = 8
 
 
 def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:
@@ -364,6 +368,23 @@ class _Active:
         self.t0_steps: Optional[float] = None   # first decode-step start
 
 
+class _Flight:
+    """A decode step on the device whose tokens are not yet on the host:
+    the ``(slot, _Active)`` pairs it was launched for (a slot may change
+    hands before it lands), their cached lengths before it, its open
+    turn and meter scope, its outputs (``out``: tokens and counters, the
+    one fetch; ``fed``: the tokens as the next step takes them), and
+    whether it was dispatched while the step before it was unfetched."""
+
+    __slots__ = ("turn", "scope", "t_launch", "pairs", "lens", "out",
+                 "fed", "ahead", "first_steps")
+
+    def __init__(self, turn, scope, t_launch, pairs, lens, ahead,
+                 first_steps):
+        self.turn, self.scope, self.t_launch = turn, scope, t_launch
+        self.pairs, self.lens, self.ahead = pairs, lens, ahead
+        self.first_steps = first_steps
+        self.out = self.fed = None
 
 
 class DecodeSession:
@@ -478,7 +499,9 @@ class DecodeSession:
         self._swap_lock = threading.Lock()
 
         # host mirrors of the device cache state — fully determined by
-        # scheduler actions, so they are inputs each step, never fetched
+        # scheduler actions, so they are inputs each step, never fetched;
+        # ``_cache_len`` (and ``_Active.generated``) count a step from
+        # its dispatch, ``_tokens`` holds what the last fetch brought
         self._cache_len = np.zeros((max_slots,), np.int32)
         self._tokens = np.zeros((max_slots,), np.int32)
         self._slots: List[Optional[_Active]] = [None] * max_slots
@@ -495,6 +518,10 @@ class DecodeSession:
         # the last step's tokens and record while they wait for the next
         # step to be on the device (``_hand_on``); the scheduler's alone
         self._held: Optional[tuple] = None
+        # the step launched ahead of the last fetch, and when that fetch
+        # returned (a step's time starts there, or at its own dispatch)
+        self._flying: Optional[_Flight] = None
+        self._t_fetch = 0.0
         self._worker = threading.Thread(
             target=self._loop, name=f"mxtpu-decode-{self.name}",
             daemon=True)
@@ -530,17 +557,19 @@ class DecodeSession:
         return (jnp.argmax(last, axis=-1).astype(jnp.int32), *planes)
 
     def _decode_apply(self, pvals, *args):
-        """``(pvals, *caches, cache_len, tokens)`` -> ``(out, *caches)``:
-        ``out`` (S,) int32 holds the greedy next token of every slot and,
-        behind them, the block's ``step_counters`` (one fetch brings
-        both)."""
+        """``(pvals, *caches, cache_len, tokens)`` -> ``(out, *caches,
+        next_tokens)``: ``out`` holds the greedy next token of every
+        slot and, behind them, the block's ``step_counters`` (one fetch
+        brings both); ``next_tokens`` (S,) int32 is those tokens alone,
+        never fetched and never donated: the ``tokens`` of a step
+        launched before this one's ``out`` is on the host."""
         *caches, cache_len, tokens = args
         logits, *rest = self._run(self._block.serve_step, pvals, tokens,
                                   cache_len, *caches)
-        out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        out = nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         if self._counters:
-            out = jnp.concatenate([out, rest.pop(0).astype(jnp.int32)])
-        return (out, *rest)
+            out = jnp.concatenate([nxt, rest.pop(0).astype(jnp.int32)])
+        return (out, *rest, nxt)
 
     def _load_or_compile(self, logical: dict, compile_fn):
         """Artifact-or-compile for one engine executable (caller holds
@@ -888,6 +917,7 @@ class DecodeSession:
         while True:
             admits, shed = self._wait_for_work()
             if admits is None:
+                self._flying = None     # its streams were failed by close()
                 self._hand_on()
                 return
             for req in shed:
@@ -912,12 +942,13 @@ class DecodeSession:
                         if self._slots[slot] is not None:
                             self._slots[slot] = None
                             self._free.append(slot)
-            if self.active_slots:
+            if self.active_slots or self._flying is not None:
                 try:
                     self._step()
                 except Exception as exc:   # noqa: BLE001 — worker survives
                     logger.exception("decode step failed; failing the "
                                      "active sequences")
+                    self._flying = None     # the step behind it: discarded
                     self._hand_on()     # what the step before it gave
                     with self._cv:
                         active = [(i, s) for i, s in enumerate(self._slots)
@@ -944,7 +975,8 @@ class DecodeSession:
                 if self._state == "closed":
                     return None, []
                 n_active = sum(1 for s in self._slots if s is not None)
-                if n_active or (self._pending and self._free):
+                if n_active or self._flying is not None \
+                        or (self._pending and self._free):
                     break
                 if self._state == "draining" and not self._pending:
                     return None, []
@@ -967,7 +999,9 @@ class DecodeSession:
                 cutoff = now - self.deadline_ms / 1e3
                 while self._pending and self._pending[0].t_submit < cutoff:
                     shed.append(self._pending.popleft())
-            while self._pending and self._free:
+            # (a step still in flight lands first: only an ``eos_id``
+            # ending, found one step late, frees a slot underneath one)
+            while self._pending and self._free and self._flying is None:
                 req = self._pending.popleft()
                 slot = self._free.popleft()
                 self._slots[slot] = _Active(req)
@@ -1042,68 +1076,136 @@ class DecodeSession:
         self.metrics.observe_slots(self.active_slots)
 
     def _step(self) -> None:
-        """One decode step for every occupied slot (free slots compute
-        too — their rows are ignored and their writes land in freed
-        space). The ONLY hot-path dispatch: no shape in it depends on
-        which slots are live or how old their sequences are.
+        """Land one decode step: the one launched ahead of the last
+        fetch, or one dispatched here. Every step advances every occupied
+        slot (free slots compute too — their rows are ignored and their
+        writes land in freed space) through the ONLY hot-path executable:
+        no shape in it depends on which slots are live or how old their
+        sequences are.
 
-        Between one step's fence and the next step's dispatch the device
-        waits for this thread, so only what the next dispatch needs is
-        done there: the mirrors advance and finished slots retire. The
-        tokens go to their callers, and the step's record is written
-        (:meth:`_hand_on`), once the NEXT step is on the device, or an
-        admission's prefill; at once where a slot finished."""
+        While :meth:`_stays_full` holds the NEXT step is dispatched
+        before this one's tokens are fetched, its ``tokens`` this one's
+        device output: the device goes from step to step and the host's
+        round trip lies under its time. Otherwise the device waits for
+        this thread between a fetch and the next dispatch, so only what
+        that dispatch needs is done there (:meth:`_land`) and the tokens
+        go to their callers under the next step, or an admission's
+        prefill (:meth:`_hand_on`)."""
+        flight, self._flying = self._flying, None
+        if flight is None:
+            flight = self._launch()
+        if self._stays_full(flight):
+            self._flying = self._launch(flight)
+        self._land(flight)
+
+    def _stays_full(self, flight: _Flight) -> bool:
+        """Whether the step after ``flight`` may be launched ahead of
+        ``flight``'s fetch, from what the scheduler can see: every slot
+        is busy with the stream ``flight`` was launched for and none of
+        them ends with it by ``max_new_tokens`` or cache capacity (both
+        counted from the dispatch), the session is running, no weight
+        swap is staged. Then no admission and no swap could happen at
+        the boundary that is skipped: launching ahead delays neither. A
+        stream that ends by its ``eos_id`` is found when the token
+        arrives, one step late (:meth:`_land` drops what the step behind
+        computed for it)."""
         with self._cv:
-            active = [i for i, s in enumerate(self._slots)
-                      if s is not None]
-            cache_len = self._cache_len.copy()
-            tokens = self._tokens.copy()
-        k = len(active)
+            return (self._state == "running" and self._pending_swap is None
+                    and len(flight.pairs) == self.max_slots
+                    and all(self._slots[i] is st
+                            and st.generated < st.req.max_new
+                            and self._cache_len[i] < self.max_len
+                            for i, st in flight.pairs))
+
+    def _launch(self, before: Optional[_Flight] = None) -> _Flight:
+        """Dispatch one decode step and start its tokens' copy to the
+        host. ``before`` is the step still in flight that this one is
+        launched ahead of: its device token vector is this one's
+        ``tokens`` (it was launched for the same streams, so nothing has
+        to be mixed in); without it the host's. The mirrors count the
+        step from here: +1 row and +1 token for each slot it is launched
+        for."""
         # this step takes the open turn; the next one opens here, so a
         # step that fails leaves nothing of itself in a later record
         turn, self._turn = self._turn, telemetry.trace.Turn(
             self._site, _STEP_PHASES)
         t0 = time.perf_counter()
         turn.add("sched", t0 - self._t_mark)
-        with self._meter.step(
-                h2d_bytes=int(cache_len.nbytes + tokens.nbytes),
-                detail=f"active={k}", flops_fn=self._decode_flops,
-                turn=turn):
-            ex = self._decode_exec()
-            with turn.phase("h2d"):
-                cache_len_d = jnp.asarray(cache_len)
-                tokens_d = jnp.asarray(tokens)
-            with turn.phase("dispatch"):
-                nxt, *self._kv.arrays = ex(
-                    self._params, *self._kv.arrays, cache_len_d, tokens_d)
-            with turn.phase("fence"):
-                self._hand_on()          # the last step's, under this one
-                nxt_np = np.asarray(nxt)              # the D2H fence
-        t1 = time.perf_counter()
-        self.metrics.observe_step(k, t1 - t0, k)
-        toks = nxt_np.tolist()
-        finished: List[int] = []
         first_steps: List[_Request] = []
-        with turn.phase("deliver"), self._cv:
-            for i in active:
-                st = self._slots[i]
-                if st is None:        # closed underneath us
-                    continue
+        with self._cv:
+            pairs = [(i, s) for i, s in enumerate(self._slots)
+                     if s is not None]
+            cache_len = self._cache_len.copy()
+            tokens = self._tokens.copy() if before is None else before.fed
+            for i, st in pairs:
                 self._cache_len[i] += 1
-                tok = toks[i]
-                self._tokens[i] = tok
                 st.generated += 1
                 if st.t0_steps is None:
                     st.t0_steps = t0
                     if st.req.trace is not None:
                         first_steps.append(st.req)
-                if (tok == st.req.eos_id or st.generated >= st.req.max_new
+        h2d_bytes = cache_len.nbytes + (tokens.nbytes if before is None else 0)
+        flight = _Flight(
+            turn, self._meter.step(
+                h2d_bytes=h2d_bytes, detail=f"active={len(pairs)}",
+                flops_fn=self._decode_flops, turn=turn, defer=True),
+            t0, pairs, cache_len[[i for i, _ in pairs]],
+            int(before is not None), first_steps)
+        with flight.scope:
+            ex = self._decode_exec()
+            with turn.phase("h2d"):
+                cache_len = jnp.asarray(cache_len)
+                if before is None:
+                    tokens = jnp.asarray(tokens)
+            with turn.phase("dispatch"):
+                flight.out, *self._kv.arrays, flight.fed = ex(
+                    self._params, *self._kv.arrays, cache_len, tokens)
+                flight.out.copy_to_host_async()
+        self._t_mark = time.perf_counter()
+        return flight
+
+    def _land(self, flight: _Flight) -> None:
+        """Fetch ``flight``'s tokens and do what the next dispatch waits
+        for: the host's token mirror, the slots that finished. A step's
+        time runs from the later of its dispatch and the fetch before it
+        to its own fetch: what it added to every stream.
+
+        A token goes only where the slot still holds the stream the step
+        was launched for. With a step in flight behind this one nothing
+        ends here by length (:meth:`_stays_full`), the tokens are handed
+        on at once (the device is busy) and a stream whose ``eos_id``
+        arrived is un-counted the token that step computes for it."""
+        turn, behind = flight.turn, self._flying
+        with turn.phase("fence"):
+            self._hand_on()     # what the step before it left held
+            toks = np.asarray(flight.out).tolist()    # the D2H fence
+        t1 = time.perf_counter()
+        t0, self._t_fetch = max(flight.t_launch, self._t_fetch), t1
+        live: List[Tuple[_Active, int]] = []
+        finished: List[int] = []
+        with turn.phase("deliver"), self._cv:
+            for i, st in flight.pairs:
+                if self._slots[i] is not st:
+                    continue    # ended a step ago, or closed underneath us
+                tok = toks[i]
+                self._tokens[i] = tok
+                live.append((st, tok))
+                if tok == st.req.eos_id:
+                    if behind is not None:
+                        st.generated -= 1
+                    finished.append(i)
+                elif behind is None and (
+                        st.generated >= st.req.max_new
                         or self._cache_len[i] >= self.max_len):
                     finished.append(i)
-        # the handles are looked up when the tokens are handed on: no
-        # slot changes hands in between
-        self._held = (turn, t0, t1, active, cache_len[active], toks,
-                      first_steps)
+        dropped = len(flight.pairs) - len(live)
+        flight.scope.commit(t0, t1, ahead=flight.ahead, dropped=dropped)
+        self.metrics.observe_step(len(flight.pairs), t1 - t0, len(live),
+                                  flight.ahead, dropped)
+        self._held = (flight, t0, t1, toks, live)
+        if behind is not None:
+            with behind.turn.phase("fence"):    # under ITS device time
+                self._hand_on()
         with turn.phase("finish"):
             if finished:
                 self._hand_on()     # a last token before its stream ends
@@ -1114,28 +1216,26 @@ class DecodeSession:
 
     def _hand_on(self) -> None:
         """Hand the held step's tokens to their callers and write its
-        ledger record; no slot has changed hands since the step. The
-        block's own per-step integers ride behind the tokens; the cache's
-        live rows are the scheduler's to know (the rows the step read:
-        each active slot's length with its new token; the rows its
-        attention fetched for them: whole blocks, or planes)."""
+        ledger record. The block's own per-step integers ride behind the
+        tokens; the cache's live rows are the scheduler's to know (the
+        rows the step read: each slot's length with its new token; the
+        rows its attention fetched for them: whole blocks, or planes)."""
         held, self._held = self._held, None
         if held is None:
             return
-        turn, t0, t1, active, lens, toks, first_steps = held
-        for i in active:
-            st = self._slots[i]
-            if st is not None:        # not closed underneath us
-                st.req.handle._put(toks[i])
-        for req in first_steps:
+        flight, t0, t1, toks, live = held
+        for st, tok in live:
+            st.req.handle._put(tok)
+        active, lens = len(flight.pairs), flight.lens
+        for req in flight.first_steps:
             telemetry.trace.record(req.trace, "first_step", t0, t1,
-                                   active=len(active))
+                                   active=active)
         read_rows, read_bytes = self._kv.read(lens)
-        turn.close(t0, t1 - t0, active=len(active),
-                   kv_live_rows=self._kv.live_rows(lens + 1),
-                   kv_read_rows=read_rows, kv_read_bytes=read_bytes,
-                   kv_rows=self._kv.rows,
-                   **dict(zip(self._counters, toks[self.max_slots:])))
+        flight.turn.close(t0, t1 - t0, active=active,
+                          kv_live_rows=self._kv.live_rows(lens + 1),
+                          kv_read_rows=read_rows, kv_read_bytes=read_bytes,
+                          kv_rows=self._kv.rows,
+                          **dict(zip(self._counters, toks[self.max_slots:])))
 
     def _finish_slot(self, slot: int) -> None:
         """Retire a finished sequence: resolve its handle, free the slot

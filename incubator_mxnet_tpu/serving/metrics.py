@@ -296,6 +296,8 @@ class DecodeMetrics:
         self.tokens = 0
         self.prefills = 0
         self.steps = 0
+        self.steps_ahead = 0       # dispatched before the fetch before them
+        self.tokens_dropped = 0    # computed for a stream found ended
         self.prefill_seconds = 0.0
         self.decode_seconds = 0.0
         self.slots_active = 0
@@ -317,6 +319,14 @@ class DecodeMetrics:
             "mxtpu_decode_tokens_total", "tokens generated", **lbl)
         self._t_steps = telemetry.counter(
             "mxtpu_decode_steps_total", "decode steps executed", **lbl)
+        self._t_steps_ahead = telemetry.counter(
+            "mxtpu_decode_steps_ahead_total",
+            "decode steps dispatched while the step before them was "
+            "still unfetched", **lbl)
+        self._t_dropped = telemetry.counter(
+            "mxtpu_decode_tokens_dropped_total",
+            "slot-tokens a step launched ahead computed for a stream "
+            "that its eos_id had ended", **lbl)
         self._t_prefills = telemetry.counter(
             "mxtpu_decode_prefills_total", "prefills executed", **lbl)
         self._t_prefill_s = telemetry.counter(
@@ -325,8 +335,9 @@ class DecodeMetrics:
             "the prefill/decode split)", **lbl)
         self._t_decode_s = telemetry.counter(
             "mxtpu_decode_seconds_total",
-            "wall time in decode-step dispatches (the decode half of "
-            "the prefill/decode split)", **lbl)
+            "time the decode steps added to every stream: from the later "
+            "of a step's dispatch and the fetch before it to its own "
+            "fetch (the decode half of the prefill/decode split)", **lbl)
         self._t_slots = telemetry.gauge(
             "mxtpu_decode_slots_active",
             "KV-cache slots occupied by live sequences", **lbl)
@@ -388,14 +399,20 @@ class DecodeMetrics:
         with self._lock:
             self._ttfts.append(ttft_s)
 
-    def observe_step(self, active: int, seconds: float,
-                     new_tokens: int) -> None:
+    def observe_step(self, active: int, seconds: float, new_tokens: int,
+                     ahead: bool = False, dropped: int = 0) -> None:
         with self._lock:
             self.steps += 1
+            self.steps_ahead += ahead
+            self.tokens_dropped += dropped
             self.decode_seconds += seconds
             self.tokens += new_tokens
             self._active_hist.append(active)
         self._t_steps.inc()
+        if ahead:
+            self._t_steps_ahead.inc()
+        if dropped:
+            self._t_dropped.inc(dropped)
         self._t_decode_s.inc(seconds)
         self._t_tokens.inc(new_tokens)
         self._t_occupancy.observe(active)
@@ -431,6 +448,8 @@ class DecodeMetrics:
                 "finished": self.finished,
                 "tokens": self.tokens,
                 "steps": self.steps,
+                "steps_ahead": self.steps_ahead,
+                "tokens_dropped": self.tokens_dropped,
                 "prefills": self.prefills,
                 "slots_active": self.slots_active,
                 "cache_bytes": self.cache_bytes,

@@ -144,6 +144,9 @@ class _NullCtx:
     def __exit__(self, *exc):
         return False
 
+    def commit(self, t0, t1, **fields):
+        return None
+
 
 _NULL_CTX = _NullCtx()
 
@@ -166,10 +169,11 @@ class _StepScope:
     compiles, commits instruments on exit."""
 
     __slots__ = ("meter", "h2d_bytes", "dispatches", "count", "flops_fn",
-                 "detail", "turn", "_t0", "_attr", "_compiles0", "record")
+                 "detail", "turn", "defer", "_t0", "_attr", "_compiles0",
+                 "record")
 
     def __init__(self, meter, h2d_bytes, dispatches, count, flops_fn,
-                 detail, turn):
+                 detail, turn, defer):
         self.meter = meter
         self.h2d_bytes = h2d_bytes
         self.dispatches = dispatches
@@ -177,6 +181,7 @@ class _StepScope:
         self.flops_fn = flops_fn
         self.detail = detail
         self.turn = turn
+        self.defer = defer
         self.record: Dict = {}
 
     def __enter__(self):
@@ -203,19 +208,29 @@ class _StepScope:
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dt = time.perf_counter() - self._t0
+        t1 = time.perf_counter()
         self._attr.__exit__(exc_type, exc, tb)
-        if exc_type is None:
-            turn = self.turn
-            if turn is None:
-                self.meter._commit(self, dt, self._compiles0)
-            else:
-                # the commit is a phase of the caller's turn, and its
-                # record the turn's: close() adds t0/dur_s/phases to it
-                with turn.phase("meter"):
-                    self.meter._commit(self, dt, self._compiles0)
-                turn.rec = self.record
+        if exc_type is None and not self.defer:
+            self.commit(self._t0, t1)
         return False
+
+    def commit(self, t0: float, t1: float, **fields) -> None:
+        """Commit the step as running from ``t0`` to ``t1``, ``fields``
+        in its record: at the scope's exit, or, for a scope made with
+        ``defer``, when the caller says (a decode step is launched
+        inside its scope and fetched later, maybe after the step behind
+        it was launched; compiles are attributed inside the scope)."""
+        self._t0 = t0
+        self.record = fields
+        turn = self.turn
+        if turn is None:
+            self.meter._commit(self, t1 - t0, self._compiles0)
+        else:
+            # the commit is a phase of the caller's turn, and its
+            # record the turn's: close() adds t0/dur_s/phases to it
+            with turn.phase("meter"):
+                self.meter._commit(self, t1 - t0, self._compiles0)
+            turn.rec = self.record
 
 
 class StepMeter:
@@ -285,20 +300,23 @@ class StepMeter:
     # -- the hot-path API ---------------------------------------------------
     def step(self, h2d_bytes: int = 0, dispatches: int = 1,
              count: int = 1, flops_fn: Optional[Callable] = None,
-             detail: str = "", turn=None):
+             detail: str = "", turn=None, defer: bool = False):
         """Context manager around one step (or ``count`` fused steps —
         ``run_steps`` drives N device-side steps in one dispatch).
         ``flops_fn`` is a zero-arg callable returning per-step FLOPs (or
         None); it is only called when MFU accounting is observed.
         ``turn`` is the caller's open ``trace.Turn`` (one with a phase
         ``meter``): the commit is then timed as that phase and its
-        ledger record becomes the turn's."""
+        ledger record becomes the turn's. With ``defer`` the scope's
+        exit commits nothing: the caller calls the scope's
+        ``commit(t0, t1, **fields)`` when the step's end is known."""
         from . import enabled
 
         if not enabled():
             return _NULL_CTX
         return _StepScope(self, int(h2d_bytes), int(dispatches),
-                          max(1, int(count)), flops_fn, detail, turn)
+                          max(1, int(count)), flops_fn, detail, turn,
+                          bool(defer))
 
     # -- commit -------------------------------------------------------------
     def _commit(self, scope: _StepScope, dt: float,
@@ -348,10 +366,10 @@ class StepMeter:
             insts["mem"].set(mem.get("bytes_in_use", 0))
             if "peak_bytes_in_use" in mem:
                 insts["mem_peak"].set(mem["peak_bytes_in_use"])
-        rec = {"kind": "step", "site": self.site, "step": self._last_step,
-               "t0": scope._t0, "wall_ms": round(per * 1e3, 4),
-               "dispatches": scope.dispatches,
-               "h2d_bytes": scope.h2d_bytes}
+        rec = dict(scope.record, kind="step", site=self.site,
+                   step=self._last_step, t0=scope._t0,
+                   wall_ms=round(per * 1e3, 4),
+                   dispatches=scope.dispatches, h2d_bytes=scope.h2d_bytes)
         if scope.count > 1:
             rec["fused_steps"] = scope.count
         if compiled:

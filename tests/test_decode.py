@@ -10,6 +10,7 @@ train (SuperStep + ZeRO-2) -> sharded checkpoint -> ``from_checkpoint``
 -> decode end-to-end."""
 
 import os
+import threading
 import time
 
 import jax
@@ -198,6 +199,253 @@ def test_cache_capacity_finishes_and_frees_slot():
 
 
 # ---------------------------------------------------------------------------
+# a second step in flight: launched before the first one's tokens are fetched
+# ---------------------------------------------------------------------------
+def _step_records(name):
+    return [r for r in telemetry.trace.ring()["steps"]
+            if r.get("site") == f"decode.{name}"
+            and r.get("kind") != "prefill"]
+
+
+def _count_calls(sess):
+    """Wrap the session's executables: ``calls`` gets ``"p"`` a prefill
+    and ``"s"`` / ``"a"`` a decode step dispatched with the host's tokens
+    / launched ahead (its ``tokens`` the step before's device output)."""
+    calls, last_fed = [], [None]
+    step, prefill = sess._dec_ex, sess._prefill
+
+    def stepping(*args):
+        calls.append("a" if args[-1] is last_fed[0] else "s")
+        outs = step(*args)
+        last_fed[0] = outs[-1]
+        return outs
+
+    class Prefilling:
+        def __getattr__(self, name):
+            return getattr(prefill, name)
+
+        def __call__(self, prompt):
+            calls.append("p")
+            return prefill(prompt)
+
+    sess._dec_ex, sess._prefill = stepping, Prefilling()
+    return calls
+
+
+_CHURN = ([5, 11, 3, 9, 7], [20, 26, 16, 22, 18])
+
+
+@pytest.mark.parametrize("slots,ahead", [(2, True), (6, False)],
+                         ids=["full", "one_slot_free"])
+def test_a_full_session_launches_the_next_step_ahead(slots, ahead):
+    """While every slot stays busy the next step is dispatched before
+    this one's tokens are fetched (``ahead`` 1 in its record) and the
+    streams are the oracle's bit for bit across joins and leaves; the
+    same streams with a slot left free run one step at a time."""
+    net = _tiny_net()
+    name = f"ahead{slots}"
+    prompts = _prompts(_CHURN[0])
+    with serving.DecodeSession(net, max_slots=slots, max_len=48,
+                               prefill_buckets=(16,), name=name) as sess:
+        sess.warmup()
+        handles = [sess.submit(p, max_new_tokens=n)
+                   for p, n in zip(prompts, _CHURN[1])]
+        got = [h.result(120) for h in handles]
+        stats = sess.stats()
+    for i, (p, n, g) in enumerate(zip(prompts, _CHURN[1], got)):
+        assert g == _oracle(net, p, n), f"request {i} diverged"
+    steps = _step_records(name)
+    flags = [r["ahead"] for r in steps]
+    assert len(steps) == stats["steps"] and sum(flags) == stats["steps_ahead"]
+    assert stats["tokens"] == sum(_CHURN[1]) and stats["tokens_dropped"] == 0
+    assert all(r["dropped"] == 0 for r in steps)
+    if ahead:
+        assert sum(flags) > len(flags) / 2, flags
+        # a step's time starts at the fetch before it where that is later
+        # than its dispatch: records of steps launched ahead do not overlap
+        for a, b in zip(steps, steps[1:]):
+            assert b["t0"] >= a["t0"] + a["dur_s"] - 1e-9
+    else:
+        assert not any(flags)
+
+
+@pytest.mark.parametrize("slots", [1, 2])
+def test_an_eos_found_one_step_late_drops_one_token(slots):
+    """A stream's ``eos_id`` is seen when its token arrives; the step
+    launched ahead has computed one more token for that slot, which goes
+    nowhere and is counted nowhere, and the request that joins the freed
+    slot gets its own tokens only."""
+    net = _tiny_net(seed=5)
+    p_eos, p_long, p_next = _prompts([9, 6, 4], seed=3)
+    free_run = _oracle(net, p_eos, 8)
+    eos = free_run[4]
+    want = _oracle(net, p_eos, 8, eos=eos)
+    assert want[-1] == eos and len(want) == 5   # steps 1-4 run before it
+    with serving.DecodeSession(net, max_slots=slots, max_len=48,
+                               prefill_buckets=(16,),
+                               name=f"late{slots}") as sess:
+        sess.warmup()
+        go, step = threading.Event(), sess._dec_ex
+
+        def gated(*args):       # every request is queued before step 1
+            assert go.wait(30)
+            return step(*args)
+
+        sess._dec_ex = gated
+        h_eos = sess.submit(p_eos, max_new_tokens=8, eos_id=eos)
+        h_long = sess.submit(p_long, max_new_tokens=14) if slots == 2 \
+            else None
+        h_next = sess.submit(p_next, max_new_tokens=5)
+        go.set()
+        assert h_eos.result(60) == want
+        assert h_next.result(60) == _oracle(net, p_next, 5)
+        n = len(want) + 5
+        if h_long is not None:
+            assert h_long.result(60) == _oracle(net, p_long, 14)
+            n += 14
+        stats = sess.stats()
+    assert stats["tokens_dropped"] == 1 and stats["tokens"] == n
+    assert sum(r["dropped"] for r in _step_records(f"late{slots}")) == 1
+
+
+def test_no_step_is_launched_ahead_of_an_admission():
+    """A request queued at a full session is prefilled at the first
+    boundary after the ending that frees its slot: the step after a
+    stream's last (by ``max_new_tokens``) is never launched ahead."""
+    net = _tiny_net()
+    a, b = _prompts([6, 5])
+    with serving.DecodeSession(net, max_slots=1, max_len=48,
+                               prefill_buckets=(8,), name="edge") as sess:
+        sess.warmup()
+        calls = _count_calls(sess)
+        h_a = sess.submit(a, max_new_tokens=12)
+        h_b = sess.submit(b, max_new_tokens=4)
+        assert h_a.result(60) == _oracle(net, a, 12)
+        assert h_b.result(60) == _oracle(net, b, 4)
+    # the prefill's token and one a step, then the next prefill at once;
+    # all but a stream's first step ride ahead of the fetch before them
+    assert "".join(calls) == "p" + "s" + "a" * 10 + "p" + "s" + "a" * 2
+
+
+def test_a_staged_swap_is_flipped_between_two_steps_of_a_full_session():
+    """``publish_weights`` staged while a full session decides about its
+    next step: none is launched ahead of the flip, so the swap takes
+    effect with the very next step (which takes the host's tokens), every
+    step runs under one version, later steps are launched ahead again and
+    both streams are whole."""
+    net_a, net_b = (_tiny_net(seed=s, dropout=0.0, max_length=256)
+                    for s in (0, 1))
+    weights = {k: p.data().asnumpy()
+               for k, p in parallel.spmd.collect_params(net_b).items()}
+    with serving.DecodeSession(net_a, max_slots=2, max_len=256,
+                               prefill_buckets=(8,), name="flip") as sess:
+        sess.warmup()
+        seen, last_fed, step = [], [None], sess._dec_ex
+        publisher = threading.Thread(
+            target=lambda: sess.publish_weights(weights, version=2))
+        staged_at = []
+
+        def stepping(*args):
+            if not staged_at and sess.active_slots == 2:
+                # the first step of the FULL session, dispatched with the
+                # host's tokens: the swap is staged before the scheduler
+                # decides about the step after it
+                staged_at.append(len(seen))
+                publisher.start()
+                while sess._pending_swap is None:
+                    time.sleep(0.001)
+            seen.append((id(args[0]), args[-1] is last_fed[0]))
+            outs = step(*args)
+            last_fed[0] = outs[-1]
+            return outs
+
+        sess._dec_ex = stepping
+        handles = [sess.submit(p, max_new_tokens=60)
+                   for p in _prompts([6, 5])]
+        assert [len(h.result(60)) for h in handles] == [60, 60]
+        publisher.join(30)
+        assert not publisher.is_alive() and sess.weights_version == 2
+    (at,) = staged_at
+    versions = [v for v, _ in seen]
+    assert len(set(versions[:at + 1])) == 1 == len(set(versions[at + 1:]))
+    assert versions[at] != versions[at + 1], "a step was launched ahead"
+    assert not seen[at][1] and not seen[at + 1][1]
+    assert sum(ahead for _, ahead in seen[at + 2:]) > len(seen) // 2
+
+
+class _Unfetchable:
+    """Stands for a step's ``out`` whose copy to the host fails."""
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, *args, **kwargs):
+        raise RuntimeError("injected fetch failure")
+
+
+@pytest.mark.parametrize("fault", ["dispatch", "dispatch_ahead", "fetch"])
+def test_a_failing_step_fails_its_streams_and_no_others(fault):
+    """A step that fails at its dispatch (with the host's tokens, or
+    launched ahead with a step in flight before it) or at its fetch (a
+    step in flight behind it, which is discarded) fails exactly the
+    streams it was launched for; the session serves the next request."""
+    net = _tiny_net()
+    p1, p2, p3 = _prompts([6, 5, 7])
+    with serving.DecodeSession(net, max_slots=2, max_len=48,
+                               prefill_buckets=(8,),
+                               name=f"fault_{fault}") as sess:
+        sess.warmup()
+        go, step, n_calls = threading.Event(), sess._dec_ex, [0]
+        k = {"dispatch": 1, "dispatch_ahead": 4, "fetch": 3}[fault]
+
+        def failing(*args):
+            assert go.wait(30)
+            n_calls[0] += 1
+            if n_calls[0] != k:
+                return step(*args)
+            if fault == "fetch":
+                return (_Unfetchable(), *step(*args)[1:])
+            raise RuntimeError("injected dispatch failure")
+
+        sess._dec_ex = failing
+        doomed = [sess.submit(p, max_new_tokens=30) for p in (p1, p2)]
+        go.set()
+        for h in doomed:
+            with pytest.raises(RuntimeError, match="injected"):
+                h.result(60)
+        assert sess.generate(p3, max_new_tokens=6) == _oracle(net, p3, 6)
+        assert sess.healthz()["ready"]
+        assert sess.stats()["finished"] == 1
+
+
+def test_close_underneath_two_steps_in_flight_returns():
+    net = _tiny_net(max_length=256)
+    with serving.DecodeSession(net, max_slots=2, max_len=256,
+                               prefill_buckets=(8,), name="closing") as sess:
+        sess.warmup()
+        step, n_calls, closer = sess._dec_ex, [0], []
+
+        def stepping(*args):
+            n_calls[0] += 1
+            if n_calls[0] == 6:     # launched ahead: step 5 is in flight
+                closer.append(threading.Thread(target=sess.close))
+                closer[0].start()
+                while sess.healthz()["state"] != "closed":
+                    time.sleep(0.001)
+            return step(*args)
+
+        sess._dec_ex = stepping
+        handles = [sess.submit(p, max_new_tokens=200)
+                   for p in _prompts([6, 5])]
+        for h in handles:
+            with pytest.raises(serving.ServerClosedError):
+                h.result(60)
+        closer[0].join(30)
+        assert not closer[0].is_alive() and not sess._worker.is_alive()
+        assert n_calls[0] == 6
+
+
+# ---------------------------------------------------------------------------
 # the cache is updated where it lies: the lowered program, and stale slots
 # ---------------------------------------------------------------------------
 def test_decode_step_builds_nothing_of_the_caches_shape():
@@ -247,7 +495,8 @@ def test_stale_full_slot_leaves_other_slots_rows_alone():
 
         def run(stale_len):
             lens = np.array([3, stale_len, 7, 0], np.int32)
-            nxt, k, v = step(sess._params, k0, v0, lens, tokens)
+            nxt, k, v, fed = step(sess._params, k0, v0, lens, tokens)
+            np.testing.assert_array_equal(fed, nxt)   # no counters here
             return np.asarray(nxt), np.asarray(k), np.asarray(v), lens
 
         nxt_a, k_a, v_a, lens = run(T)
@@ -459,14 +708,17 @@ def test_defaults_come_from_config_knobs():
 # ---------------------------------------------------------------------------
 # the recompile contract (satellite): zero post-warmup compiles
 # ---------------------------------------------------------------------------
-def test_steady_state_decode_zero_recompiles_under_watchdog():
+@pytest.mark.parametrize("slots", [3, 2], ids=["churn", "full"])
+def test_steady_state_decode_zero_recompiles_under_watchdog(slots):
     """Mixed-age churn against the armed PR 4 watchdog: after warmup,
     the fixed executable set must serve ANY mix of prompt lengths,
-    sequence ages and slot occupancies without one more XLA compile."""
+    sequence ages and slot occupancies without one more XLA compile;
+    with two slots every one stays busy and most steps are launched
+    ahead, their ``tokens`` the step before's device output."""
     net = _tiny_net()
     wd = telemetry.get_watchdog()
     assert wd is not None
-    sess = serving.DecodeSession(net, max_slots=3, max_len=48,
+    sess = serving.DecodeSession(net, max_slots=slots, max_len=48,
                                  prefill_buckets=(8, 16), name="steady")
     try:
         sess.warmup()
@@ -488,6 +740,7 @@ def test_steady_state_decode_zero_recompiles_under_watchdog():
         assert wd.compile_count == compiles_before, \
             "steady-state decode compiled something"
         assert not wd.flagged(), [e.__dict__ for e in wd.flagged()]
+        assert sess.stats()["steps_ahead"] > 0
     finally:
         sess.close()
 
@@ -549,8 +802,12 @@ def test_decode_metrics_family_and_report(tmp_path):
         snap = sess.stats()
     telemetry.set_jsonl(None)
     assert snap["tokens"] >= 12 and snap["cache_bytes"] > 0
+    assert 0 < snap["steps_ahead"] < snap["steps"]
+    assert snap["tokens_dropped"] == 0
     text = telemetry.prometheus_text()
     for fam in ("mxtpu_decode_tokens_total", "mxtpu_decode_slots_active",
+                "mxtpu_decode_steps_ahead_total",
+                "mxtpu_decode_tokens_dropped_total",
                 "mxtpu_decode_prefill_seconds_total",
                 "mxtpu_decode_seconds_total", "mxtpu_decode_cache_bytes",
                 "mxtpu_decode_queue_wait_seconds"):
@@ -569,6 +826,8 @@ def test_decode_metrics_family_and_report(tmp_path):
 
     out = telemetry_report.summarize(path)
     assert "decode (per request)" in out and "tele" in out
+    assert (f"decode.tele: {snap['steps_ahead']} of {snap['steps']} steps "
+            "launched ahead, 0 slot-tokens dropped") in out
     keys = telemetry_report._comparable_metrics(records)
     assert keys["decode/tele/requests"] == 3.0
     assert keys["decode/tele/tokens"] == 12.0

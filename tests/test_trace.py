@@ -668,9 +668,10 @@ def test_ledger_holds_every_turn_with_sampling_off():
     assert len(prefills) == sess.metrics.prefills == 3
     for r in steps:
         assert set(r["phases"]) == STEP_PHASES and r["active"] >= 1
-        inside = sum(r["phases"][k] for k in ("h2d", "dispatch", "fence",
-                                              "meter"))
-        assert 0 < inside <= r["dur_s"]
+        # one stream at a time in two slots: every step is serial, from
+        # its dispatch to its fetch (the meter's commit comes after it)
+        inside = sum(r["phases"][k] for k in ("h2d", "dispatch", "fence"))
+        assert r["ahead"] == 0 and 0 < inside <= r["dur_s"]
     for r in prefills:
         assert set(r["phases"]) == {"dispatch", "join", "fence"}
         assert 0 < sum(r["phases"].values()) <= r["dur_s"]

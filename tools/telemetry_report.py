@@ -143,6 +143,17 @@ def summarize(path: str, merge: bool = False) -> str:
                 f"{_pctl(walls, 50):9.3f} {_pctl(walls, 95):9.3f} "
                 f"{disp:10.3f} "
                 f"{trend:>16s} {recompiles.get(site, 0):11d}")
+    for site in sorted(sites):
+        # decode steps say whether they were dispatched before the fetch
+        # of the step before them (docs/SERVING.md "A second step in
+        # flight") and what they computed for a stream already ended
+        ahead = [r["ahead"] for r in sites[site] if "ahead" in r]
+        if ahead:
+            lines.append(
+                f"{site}: {sum(ahead)} of {len(ahead)} steps launched "
+                f"ahead, "
+                f"{sum(r.get('dropped', 0) for r in sites[site])} "
+                "slot-tokens dropped")
     for site, n in sorted(recompiles.items()):
         if site not in sites:
             lines.append(f"recompiles at un-stepped site {site}: {n}")
