@@ -1,14 +1,22 @@
-"""A decoder-only language model built from data: per layer an attention
-kind (``full``, a sliding ``window`` or ``latent``) and an FFN kind
-(``dense`` or ``sparse``), over one set of widths, under one of two
-residual rules.
+"""A decoder-only language model built from data: per layer a sequence
+mixer (attention that is ``full``, a sliding ``window`` or ``latent``, or
+a selective state-space layer, ``mamba``) and an FFN kind (``dense`` or
+``sparse``), over one set of widths, under one of two residual rules.
 
 The layers are those today's large open decoders are built from:
 
 * grouped-query attention (``num_heads`` query heads over
   ``num_kv_heads`` K/V heads of ``head_dim``), RMSNorm over each query and
-  key head, rotary positions on the window layers and none on the full
-  ones;
+  key head (``qk_norm``), rotary positions on the window layers and none
+  on the full ones;
+* a selective state-space mixer (Jamba's, arXiv:2403.19887; ``mamba`` gives
+  ``expand``, ``d_state``, ``dt_rank``, ``d_conv``): ``[x, z] = W_in u``, a
+  causal depthwise convolution over the last ``d_conv`` inputs, ``[d, B,
+  C] = W_x x`` each RMS-normed, ``dt = softplus(W_dt d + b_dt)``, the
+  selective scan over a state of ``d_state x E`` a sequence
+  (``ops/state_space.py``), the output gated by ``silu(z)``. It keeps no
+  row a position: what a sequence carries is that state and the
+  convolution's last inputs, the same size at every length;
 * latent attention (DeepSeek-V2's, arXiv:2405.04434; ``latent`` gives its
   ranks and head parts): queries through a normed ``q_rank`` bottleneck,
   keys and values through ONE normed ``kv_rank`` latent a position beside
@@ -38,19 +46,23 @@ What the block declares to be served by ``serving.DecodeSession``
 * ``cache_groups(max_len)``: the cache as groups of layers with their own
   row count and kind: the full layers keep ``max_len`` rows of K and V,
   the window layers a ring of ``window`` rows, the latent layers
-  ``max_len`` rows of one tensor;
+  ``max_len`` rows of one tensor, the state-space layers a ``state``
+  group (no rows: two tensors a layer that a step replaces);
 * ``serve_prefill(tokens, n)``: one padded prompt -> the logits at its
   last TRUE position and each group's planes ``[Lg, H, T, W]`` (K then V;
-  a latent group its one);
+  a latent group its one; a state group the state AFTER position ``n -
+  1``, ``[Lg, d_state, E]`` and ``[Lg, d_conv - 1, E]``);
 * ``serve_step(tokens, cache_len, *caches)``: every slot one token on,
   the caches updated where they lie. Where a row lies, what a slot may
-  read, the one-token attention and the writes are ``ops/kv_cache.py``'s;
+  read, the one-token attention, the writes and how a state advances are
+  ``ops/kv_cache.py``'s;
 * ``step_counters``: the integers a step returns beside the logits.
 
 The arithmetic is plain ``jax.numpy`` over the parameter arrays (one
 ``invoke`` per entry point); the matrix products take their operands'
 type and sum in float32, the router, RoPE, every norm's statistics and
-every hyper-connection coefficient are float32.
+every hyper-connection coefficient are float32, as are the state-space
+layer's ``dt``, recurrence and state.
 """
 
 from __future__ import annotations
@@ -61,7 +73,7 @@ import jax
 import jax.numpy as jnp
 
 from ...ndarray.ndarray import invoke
-from ...ops import hyper_connection, kv_cache
+from ...ops import hyper_connection, kv_cache, state_space
 from ...ops.moe import moe_held_ffn, moe_route
 from ..block import HybridBlock
 
@@ -69,11 +81,11 @@ __all__ = ["HybridDecoder", "get_decoder"]
 
 #: the cache groups, in ``cache_groups`` order: full-attention layers keep
 #: every position, window layers a ring of the window's rows, latent
-#: layers every position in one tensor
-_FULL, _RING, _LATENT = 0, 1, 2
-_KINDS = ("full", "ring", "latent")
+#: layers every position in one tensor, state-space layers a state
+_FULL, _RING, _LATENT, _STATE = 0, 1, 2, 3
+_KINDS = ("full", "ring", "latent", "state")
 _GROUP_OF = {"full_attention": _FULL, "sliding_attention": _RING,
-             "latent_attention": _LATENT}
+             "latent_attention": _LATENT, "mamba": _STATE}
 
 #: queries per block of the prefill attention: scores are built a block
 #: at a time against the keys that block may see, so a 2048-token prompt
@@ -134,9 +146,13 @@ def gated_ffn(x, w_gate, w_up, w_down):
 class HybridDecoder(HybridBlock):
     """tokens (B, T) int32 -> logits (B, T, V); see the module docstring.
 
-    ``layer_types[i]`` is ``"full_attention"``, ``"sliding_attention"`` or
-    ``"latent_attention"``, ``mlp_layer_types[i]`` ``"dense"`` or
-    ``"sparse"`` (the published configs' own words). ``latent``, where a
+    ``layer_types[i]`` is ``"full_attention"``, ``"sliding_attention"``,
+    ``"latent_attention"`` or ``"mamba"``, ``mlp_layer_types[i]``
+    ``"dense"`` or ``"sparse"`` (the published configs' own words).
+    ``mamba``, where a layer is one, is a dict: ``expand``, ``d_state``,
+    ``dt_rank``, ``d_conv``. ``qk_norm`` off leaves the
+    attention heads un-normed, ``tie_embeddings`` reads the logits off the
+    embedding table (no ``head``). ``latent``, where a
     layer is latent, is a dict: ``q_rank``, ``kv_rank``, ``nope_dim``,
     ``rope_dim``, ``v_dim`` and ``rope_scaling`` (YaRN's keys); a cached
     row ``[c | k_r]`` is stored at ``kv_cache.whole_tiles`` of ``kv_rank +
@@ -152,7 +168,8 @@ class HybridDecoder(HybridBlock):
                  rope_theta=1e6, eps=1e-5, num_experts=0, experts_held=0,
                  expert_share=0, experts_per_token=0, expert_hidden=0,
                  routed_scale=1.0, max_length=4096, latent=None, streams=1,
-                 hc=None, pre_norm=False, prefix=None, params=None):
+                 hc=None, pre_norm=False, mamba=None, qk_norm=True,
+                 tie_embeddings=False, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         if len(layer_types) != len(mlp_layer_types):
             raise ValueError("layer_types and mlp_layer_types differ in "
@@ -169,6 +186,8 @@ class HybridDecoder(HybridBlock):
                 f"lie in {num_experts}, or top-{experts_per_token} does not")
         if "latent_attention" in layer_types and not latent:
             raise ValueError("a latent_attention layer needs ``latent``")
+        if "mamba" in layer_types and not mamba:
+            raise ValueError("a mamba layer needs ``mamba``")
         self._vocab, self._units = int(vocab_size), int(units)
         self._heads, self._kv_heads = int(num_heads), int(num_kv_heads)
         self._head_dim = int(head_dim)
@@ -185,8 +204,10 @@ class HybridDecoder(HybridBlock):
                 self._latent["kv_rank"] + self._latent["rope_dim"])
         self._streams, self._pre_norm = int(streams), bool(pre_norm)
         self._hc = dict(hc or {})
+        self._ssm = dict(mamba or {})
+        self._qk_norm, self._tied = bool(qk_norm), bool(tie_embeddings)
         # per layer (cache group, index in the group); layers per group
-        self._group_counts, self._group_of = [0, 0, 0], []
+        self._group_counts, self._group_of = [0] * len(_KINDS), []
         for attn, _ in self._kinds:
             g = _GROUP_OF[attn]
             self._group_of.append((g, self._group_counts[g]))
@@ -199,7 +220,8 @@ class HybridDecoder(HybridBlock):
         with self.name_scope():
             self.embed = get("embed", shape=(self._vocab, c))
             self.final_norm = get("final_norm", shape=(c,), init="ones")
-            self.head = get("head", shape=(self._vocab, c))
+            if not self._tied:
+                self.head = get("head", shape=(self._vocab, c))
             for i, (attn, ffn) in enumerate(self._kinds):
                 shapes = {"attn_norm": (c,), "ffn_norm": (c,)}
                 if attn == "latent_attention":
@@ -213,9 +235,20 @@ class HybridDecoder(HybridBlock):
                         kv_b=(h * (la["nope_dim"] + la["v_dim"]),
                               la["kv_rank"]),
                         o=(c, h * la["v_dim"]))
+                elif attn == "mamba":
+                    ma = self._ssm
+                    ex, ns = ma["expand"] * c, ma["d_state"]
+                    shapes.update(
+                        in_proj=(2 * ex, c), conv_w=(ma["d_conv"], ex),
+                        conv_bias=(ex,), x_proj=(ma["dt_rank"] + 2 * ns, ex),
+                        dt_norm=(ma["dt_rank"],), b_norm=(ns,), c_norm=(ns,),
+                        dt_proj=(ex, ma["dt_rank"]), dt_bias=(ex,),
+                        a_log=(ns, ex), d_skip=(ex,), out_proj=(c, ex))
                 else:
                     shapes.update(q=(hq, c), k=(hkv, c), v=(hkv, c),
-                                  o=(c, hq), q_norm=(d,), k_norm=(d,))
+                                  o=(c, hq))
+                    if self._qk_norm:
+                        shapes.update(q_norm=(d,), k_norm=(d,))
                 if n > 1:
                     for sub in ("attn", "ffn"):
                         shapes.update({
@@ -232,8 +265,10 @@ class HybridDecoder(HybridBlock):
                         experts_down=(e, fe, c), shared_gate=(fe, c),
                         shared_up=(fe, c), shared_down=(c, fe))
                 for name, shape in shapes.items():
-                    init = "ones" if name.endswith(("norm", "_scale")) \
-                        else ("zeros" if name.endswith("bias") else None)
+                    init = "ones" if name.endswith(("norm", "_scale",
+                                                     "d_skip")) \
+                        else ("zeros" if name.endswith(("bias", "a_log"))
+                              else None)
                     setattr(self, f"layer{i}_{name}",
                             get(f"layer{i}_{name}", shape=shape, init=init))
 
@@ -246,14 +281,26 @@ class HybridDecoder(HybridBlock):
         """The cache this block is served with, a dict per group of
         layers: ``layers``, ``heads`` (K/V heads), ``rows``, ``head_dim``
         and ``kind`` (``ops/kv_cache.py``; a ``latent`` group is one
-        tensor of one ``row``-wide head). Groups a model has no layer of
-        are left out but keep their place in the order."""
+        tensor of one ``row``-wide head). A ``state`` group declares the
+        ``width`` of a state-space layer's channels, its ``state`` and
+        ``taps`` a channel and the state's ``dtype`` instead: float32
+        whatever the model is served in, because the scan sums into it
+        at every step. Groups a model has no layer of are left out but
+        keep their place in the order."""
         rows = (int(max_len), min(self._window, int(max_len)), int(max_len))
         heads = (self._kv_heads, self._kv_heads, 1)
         widths = (self._head_dim, self._head_dim, self._latent.get("row"))
-        return [dict(layers=n, heads=h, rows=r, head_dim=w, kind=kind)
-                for n, h, r, w, kind in zip(self._group_counts, heads, rows,
-                                            widths, _KINDS) if n]
+        # the three kinds of rows (``zip`` stops before the state group)
+        groups = [dict(layers=n, heads=h, rows=r, head_dim=w, kind=kind)
+                  for n, h, r, w, kind in zip(self._group_counts, heads,
+                                              rows, widths, _KINDS)]
+        ma = self._ssm
+        if ma:
+            groups.append(dict(
+                layers=self._group_counts[_STATE], kind=_KINDS[_STATE],
+                width=ma["expand"] * self._units, state=ma["d_state"],
+                taps=ma["d_conv"] - 1, dtype="float32"))
+        return [g for g in groups if g["layers"]]
 
     # -- the arithmetic, over plain arrays -------------------------------------
     def _arrays(self):
@@ -291,13 +338,16 @@ class HybridDecoder(HybridBlock):
 
     def _qkv(self, lp, x, positions, window):
         """``x`` (B, T, C) -> q (B, Hkv, G, T, D), k and v (B, Hkv, T, D),
-        normed per head; rotated on window layers."""
+        normed per head where the block norms them; rotated on window
+        layers."""
         b, t, _ = x.shape
         d, hkv = self._head_dim, self._kv_heads
         heads = lambda a, n: a.reshape(b, t, n, d).transpose(0, 2, 1, 3)
-        q = rms_norm(heads(_mm(x, lp["q"]), self._heads), lp["q_norm"],
-                     self._eps)
-        k = rms_norm(heads(_mm(x, lp["k"]), hkv), lp["k_norm"], self._eps)
+        q, k = heads(_mm(x, lp["q"]), self._heads), heads(_mm(x, lp["k"]),
+                                                          hkv)
+        if self._qk_norm:
+            q = rms_norm(q, lp["q_norm"], self._eps)
+            k = rms_norm(k, lp["k_norm"], self._eps)
         v = heads(_mm(x, lp["v"]), hkv)
         if window:
             q = rope(q, positions[:, None, :], self._theta)
@@ -410,6 +460,46 @@ class HybridDecoder(HybridBlock):
                              w_kv[:, la["nope_dim"]:])
         return _mm(out.reshape(out.shape[0], 1, -1), lp["o"]), row[:, None]
 
+    # -- the state-space mixer -----------------------------------------------
+    def _mamba_gates(self, lp, x):
+        """The convolution's output ``x`` (..., E) -> what selects the
+        scan there: ``dt`` (..., E) float32 and ``B``, ``C`` (..., N), each
+        of ``W_x x``'s three parts RMS-normed with its own gain."""
+        ma = self._ssm
+        r, ns = ma["dt_rank"], ma["d_state"]
+        dbc = _mm(x, lp["x_proj"])
+        d = rms_norm(dbc[..., :r], lp["dt_norm"], self._eps)
+        b = rms_norm(dbc[..., r:r + ns], lp["b_norm"], self._eps)
+        c = rms_norm(dbc[..., r + ns:], lp["c_norm"], self._eps)
+        dt = jax.nn.softplus(
+            jnp.einsum("...i,oi->...o", d, lp["dt_proj"],
+                       preferred_element_type=jnp.float32)
+            + lp["dt_bias"].astype(jnp.float32))
+        return dt, b, c
+
+    def _mamba(self, lp, u, state=None, n=None):
+        """The mixer over ``u`` (B, T, C), already normed. Whole sequences
+        (``state`` None) of true length ``n`` (traced; ``T`` where None),
+        or with ``state`` = (``h`` (S, N, E), ``taps`` (S, K - 1, E)) every
+        slot's one token ``u`` (S, 1, C). Returns the mixer's output and
+        the state after it: ``h``, ``taps``."""
+        xz = _mm(u, lp["in_proj"])
+        x, z = jnp.split(xz, 2, axis=-1)
+        a = -jnp.exp(lp["a_log"].astype(jnp.float32))
+        if state is None:
+            x, taps = state_space.conv_sequence(x, lp["conv_w"],
+                                                lp["conv_bias"], n)
+            dt, b, c = self._mamba_gates(lp, x)
+            y, h = state_space.scan_sequence(x, dt, a, b, c, lp["d_skip"], n)
+        else:
+            h, taps = state
+            x, taps = state_space.conv_step(x[:, 0], taps, lp["conv_w"],
+                                            lp["conv_bias"])
+            dt, b, c = self._mamba_gates(lp, x)
+            y, h = state_space.scan_step(h, x, dt, a, b, c, lp["d_skip"])
+            y = y[:, None]
+        return _mm(y * jax.nn.silu(z), lp["out_proj"]), h, taps
+
     def _ffn(self, lp, x, live=None):
         """``x`` (N, C) -> the FFN's output and the routing's counts
         (None on a dense layer)."""
@@ -430,10 +520,12 @@ class HybridDecoder(HybridBlock):
                               lp["shared_down"]).astype(jnp.float32)
         return y.astype(x.dtype), counts
 
-    def _sequence(self, p, tokens):
+    def _sequence(self, p, tokens, n=None):
         """``tokens`` (B, T) -> the last layer's output (B, T, C) and
-        per layer its cache planes: K and V (B, Hkv, T, D), or the one
-        (B, 1, T, row) of a latent layer."""
+        per layer its cache planes: K and V (B, Hkv, T, D), the one
+        (B, 1, T, row) of a latent layer, or a state-space layer's state
+        (B, N, E) and taps (B, K - 1, E) after position ``n - 1`` (the
+        true length of padded sequences, traced; ``T`` where None)."""
         b, t = tokens.shape
         x = self._embed(p, tokens)
         positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
@@ -446,6 +538,11 @@ class HybridDecoder(HybridBlock):
                     with jax.named_scope("latent_attention"):
                         a, row = self._latent_expanded(lp, u, positions)
                     planes.append((row,))
+                    return a
+                if attn == "mamba":
+                    with jax.named_scope("state_space"):
+                        a, *state = self._mamba(lp, u, n=n)
+                    planes.append(tuple(state))
                     return a
                 window = self._window if attn == "sliding_attention" else 0
                 with jax.named_scope("attention"):
@@ -466,7 +563,8 @@ class HybridDecoder(HybridBlock):
             else x.astype(jnp.float32).sum(axis=0).astype(x.dtype)
 
     def _logits(self, p, x):
-        return _mm(rms_norm(x, p["final_norm"], self._eps), p["head"])
+        return _mm(rms_norm(x, p["final_norm"], self._eps),
+                   p["embed" if self._tied else "head"])
 
     def _run(self, fn, inputs, name):
         names, arrays = self._arrays()
@@ -487,11 +585,12 @@ class HybridDecoder(HybridBlock):
         is applied there and nowhere else) and, per cache group, its
         planes ``[Lg, H, T, W]`` of the whole bucket: K then V, or a
         latent group's one (positions from ``n`` on hold garbage that no
-        true position attended)."""
+        true position attended); a state group's ``[Lg, N, E]`` and
+        ``[Lg, K - 1, E]``, the state after position ``n - 1``."""
         group_of, counts = self._group_of, self._group_counts
 
         def fn(p, tok, n_true):
-            x, planes = self._sequence(p, tok[None])
+            x, planes = self._sequence(p, tok[None], n_true)
             last = jax.lax.dynamic_index_in_dim(x[0], n_true - 1, axis=0,
                                                 keepdims=False)
             out = [self._logits(p, last)]
@@ -508,25 +607,27 @@ class HybridDecoder(HybridBlock):
     def serve_step(self, tokens, cache_len, *caches):
         """Every slot one token on. ``tokens``/``cache_len`` (S,);
         ``caches`` the arrays ``[Lg, S, H, rows, W]`` of each cache group
-        (K then V, or a latent group's one; groups in ``cache_groups``
-        order). Returns the logits (S, V), the ``step_counters`` as one
-        int32 vector (over the slots whose ``cache_len`` is not 0: a free
+        (K then V, or a latent group's one; a state group's ``[Lg, S, N,
+        E]`` and ``[Lg, S, K - 1, E]``; groups in ``cache_groups`` order).
+        Returns the logits (S, V), the ``step_counters`` as one int32
+        vector (over the slots whose ``cache_len`` is not 0: a free
         slot's is), and the caches with each slot's new row written
-        (``kv_cache.address``)."""
+        (``kv_cache.address``), a state group's with every layer's new
+        state in the old one's place (``kv_cache.advance``)."""
         group_of = self._group_of
         present = [g for g, c in enumerate(self._group_counts) if c]
 
         def fn(p, tok, lens, *cs):
             lens = lens.astype(jnp.int32)
             cs = iter(cs)
-            kv = {g: tuple(next(cs)
-                           for _ in range(kv_cache.tensors(_KINDS[g])))
+            kv = {g: [next(cs) for _ in range(kv_cache.tensors(_KINDS[g]))]
                   for g in present}
+            rowed = [g for g in present if g != _STATE]
             at = {g: kv_cache.address(lens, kv[g][0].shape[3], _KINDS[g])
-                  for g in present}
+                  for g in rowed}
             x = self._embed(p, tok)[..., None, :]          # (S, 1, C)
             live = lens > 0
-            new = {g: tuple([] for _ in kv[g]) for g in present}
+            new = {g: tuple([] for _ in kv[g]) for g in rowed}
             totals = dict.fromkeys(("routed_here", "experts_hit"), 0)
             load_max, sparse = 0, 0
             for i in range(len(self._kinds)):
@@ -534,6 +635,15 @@ class HybridDecoder(HybridBlock):
                 g, j = group_of[i]
 
                 def attention(u):
+                    if g == _STATE:
+                        # the layer reads its own state of the arrays the
+                        # layer before it handed on, and replaces it there
+                        with jax.named_scope("state_space"):
+                            a, *state = self._mamba(
+                                lp, u, state=[c[j] for c in kv[g]])
+                            kv[g] = [kv_cache.advance(c, j, s)
+                                     for c, s in zip(kv[g], state)]
+                        return a
                     if g == _LATENT:
                         with jax.named_scope("latent_attention"):
                             a, *rows = self._latent_absorbed(
@@ -571,8 +681,9 @@ class HybridDecoder(HybridBlock):
                 live.sum() * self._top_k * sparse,
                 totals["experts_hit"], load_max)])
             out = [logits, counters]
-            out += [kv_cache.write(cache, rows_new, at[g][0])
-                    for g in present
+            for g in present:
+                out += kv[g] if g == _STATE else [
+                    kv_cache.write(cache, rows_new, at[g][0])
                     for cache, rows_new in zip(kv[g], new[g])]
             return tuple(out)
 
@@ -627,6 +738,25 @@ _SPECS = {
                         factor=4, original_max_position_embeddings=16,
                         beta_fast=32, beta_slow=1, mscale_all_dim=1)),
         max_length=128),
+    # https://huggingface.co/ai21labs/AI21-Jamba2-3B/blob/main/config.json
+    # (``jamba``): 28 pre-norm layers, attention (20 query heads over ONE
+    # K/V head of 128, no positions, no per-head norm) where ``i % 14 ==
+    # 7``, a selective state-space mixer (5120 channels, state 16, dt rank
+    # 160, convolution over 4) everywhere else, every FFN dense 8192
+    # (``num_experts`` 1), the logits off the tied embedding table
+    "jamba2_3b": dict(
+        vocab_size=65536, units=2560, num_heads=20, num_kv_heads=1,
+        head_dim=128, hidden_size=8192, eps=1e-6, num_layers=28,
+        attn_layer_period=14, attn_layer_offset=7, dense_layers=28,
+        pre_norm=True, qk_norm=False, tie_embeddings=True,
+        mamba=dict(expand=2, d_state=16, dt_rank=160, d_conv=4)),
+    "jamba_tiny": dict(
+        vocab_size=97, units=64, num_heads=4, num_kv_heads=1, head_dim=16,
+        hidden_size=96, eps=1e-6, num_layers=4, attn_layer_period=4,
+        attn_layer_offset=1, dense_layers=4, pre_norm=True, qk_norm=False,
+        tie_embeddings=True,
+        mamba=dict(expand=2, d_state=8, dt_rank=4, d_conv=4),
+        max_length=128),
 }
 _PATTERN = {"G": "full_attention", "L": "sliding_attention",
             "M": "latent_attention"}
@@ -635,18 +765,28 @@ _PATTERN = {"G": "full_attention", "L": "sliding_attention",
 def get_decoder(model_name="exaone_moe", **kwargs):
     """Decoder factory (``get_gpt``'s analog for the data-built decoder).
     ``num_layers``, ``pattern`` (``L`` a window layer, ``G`` a full one,
-    ``M`` a latent one, repeated) and ``dense_layers`` (the leading layers
-    whose FFN is dense) expand to the per-layer kinds unless
-    ``layer_types`` / ``mlp_layer_types`` are given."""
+    ``M`` a latent one, repeated) or ``attn_layer_period`` /
+    ``attn_layer_offset`` (a full-attention layer where ``i % period ==
+    offset``, a ``mamba`` layer everywhere else: Jamba's rule), and
+    ``dense_layers`` (the leading layers whose FFN is dense) expand to the
+    per-layer kinds unless ``layer_types`` / ``mlp_layer_types`` are
+    given."""
     if model_name not in _SPECS:
         raise ValueError(f"unknown decoder spec {model_name!r}; "
                          f"known {sorted(_SPECS)}")
     spec = dict(_SPECS[model_name])
     spec.update(kwargs)
     n = int(spec.pop("num_layers"))
-    pattern, dense = spec.pop("pattern"), int(spec.pop("dense_layers"))
-    spec.setdefault("layer_types", [
-        _PATTERN[pattern[i % len(pattern)]] for i in range(n)])
+    dense = int(spec.pop("dense_layers"))
+    if "attn_layer_period" in spec:
+        period = int(spec.pop("attn_layer_period"))
+        offset = int(spec.pop("attn_layer_offset"))
+        kinds = ["full_attention" if i % period == offset else "mamba"
+                 for i in range(n)]
+    else:
+        pattern = spec.pop("pattern")
+        kinds = [_PATTERN[pattern[i % len(pattern)]] for i in range(n)]
+    spec.setdefault("layer_types", kinds)
     spec.setdefault("mlp_layer_types", [
         "dense" if i < dense else "sparse" for i in range(n)])
     return HybridDecoder(**spec)

@@ -22,6 +22,18 @@ one block of rows) and the step is lowered for the TPU, fetches only the
 blocks below each slot's ``cache_len`` and joins the new row in the
 kernel instead.
 
+A fourth kind, ``"state"``, has no rows: what a recurrent layer carries from
+one position to the next, the same size at every length (a selective
+state-space layer's, ``ops/state_space.py``). A layer keeps two tensors
+``[L, S, n, E]`` (``layout``): the scan's ``h`` (``n`` = ``state``, the
+group's own ``dtype``: it is summed into at every step) and the
+convolution's last inputs (``n`` = ``taps``, the cache's type), ``E``
+minor. A step REPLACES a slot's state where a row group appends a row:
+each layer reads its own and writes the new one in its place in the
+donated array (``advance``), and a prefill's state replaces a slot's whole
+(``join``, whatever the prompt's length: a freed slot leaves nothing
+behind). ``address`` / ``read`` / ``attend`` / ``write`` do not apply.
+
 The stored row: the TPU keeps a minor dimension of whole 128-lane tiles
 minor, so a new row is few tiles; a minor dimension of 64 it laid out
 ``T``-minor, a hundred tiles a new row (PERF.md PR 29). Heads narrower
@@ -46,9 +58,35 @@ BLOCK = pallas_decode.BLOCK
 
 
 def tensors(kind):
-    """Arrays a group of ``kind`` keeps a layer: K and V, or the one
-    tensor of a ``latent`` group that is read as both."""
+    """Arrays a group of ``kind`` keeps a layer: K and V, the one tensor
+    of a ``latent`` group that is read as both, or a ``state`` group's
+    two (the scan's state, the convolution's taps)."""
     return 1 if kind == "latent" else 2
+
+
+def layout(group, slots, dtype):
+    """``(shape, dtype)`` of each array a declared ``group`` keeps for
+    ``slots`` slots in a cache of type ``dtype``: a row group's
+    ``[L, S, H, rows, W]`` K and V (or one), a ``state`` group's
+    ``[L, S, state, E]`` in its own ``dtype`` and ``[L, S, taps, E]``."""
+    layers, dtype = int(group["layers"]), jnp.dtype(dtype)
+    if group["kind"] == "state":
+        width = int(group["width"])
+        return [((layers, slots, int(group["state"]), width),
+                 jnp.dtype(group["dtype"])),
+                ((layers, slots, int(group["taps"]), width), dtype)]
+    shape = (layers, slots, int(group["heads"]), int(group["rows"]),
+             int(group["head_dim"]))
+    return [(shape, dtype)] * tensors(group["kind"])
+
+
+def plane_shape(shape, kind, bucket):
+    """What a prefill of ``bucket`` positions hands the join for a cache
+    array of ``shape``: ``[L, H, bucket, W]``, or a ``state`` array's one
+    slot ``[L, n, E]`` (it has no position axis)."""
+    if kind == "state":
+        return (shape[0],) + tuple(shape[2:])
+    return (shape[0], shape[2], bucket, shape[4])
 
 
 def pack(head_dim):
@@ -218,12 +256,27 @@ def write(cache, new, row):
     return cache
 
 
+def advance(cache, layer, new):
+    """Layer ``layer``'s ``new`` state (S, n, E) in the place of the one
+    it read from the stacked ``cache`` (L, S, n, E): one static
+    ``dynamic_update_slice`` on the donated array. A layer reads
+    ``cache[layer]`` of the array the layer before it handed on and
+    nothing reads that slice again, so XLA updates the array where it
+    lies: a step reads the state once and writes it once."""
+    return jax.lax.dynamic_update_slice(cache, new[None].astype(cache.dtype),
+                                        (layer, 0, 0, 0))
+
+
 def join(cache, plane, slot, n, kind):
     """A prompt's prefilled ``plane`` (L, H, T, W) into slot ``slot``
     (traced) of ``cache``: a full or latent group from row 0; a ring the
     last ``rows`` positions below the TRUE length ``n`` (traced), each at
     ``position mod rows`` (rows no position below ``n`` maps to hold
-    garbage that ``see`` masks)."""
+    garbage that ``see`` masks). A ``state`` group's ``plane`` (L, n, E) is
+    the state after position ``n - 1`` already and replaces the slot's."""
+    if kind == "state":
+        return jax.lax.dynamic_update_slice(
+            cache, plane[:, None].astype(cache.dtype), (0, slot, 0, 0))
     if kind == "ring":
         rows = cache.shape[3]
         r = jnp.arange(rows, dtype=jnp.int32)

@@ -127,7 +127,13 @@ class KVCache:
     cache, in that same form. A group of no layer holds nothing.
     ``arrays`` is the flat list the executables take and return: group
     by group, k then v or the one; ``kinds`` and ``shapes`` name each
-    group, ``array_kinds`` and ``specs()`` each array.
+    group, ``array_kinds`` and ``specs()`` each array. A ``state`` group
+    (a recurrent layer's state, the same size at every length) declares
+    ``layers``, ``width``, ``state``, ``taps`` and its own ``dtype``
+    instead: two arrays ``[Lg, S, n, E]`` that a step replaces where it
+    lies (``kv_cache.layout``, ``advance``); it has no rows, so ``rows``,
+    ``live_rows``, ``read`` and ``max_len`` leave it out, ``nbytes``
+    counts it and ``state_bytes`` is its own.
 
     Owned by a :class:`DecodeSession`; rebound on every donated
     join/decode dispatch. Both executables only ever update the stacked
@@ -143,29 +149,37 @@ class KVCache:
     def __init__(self, groups, slots: int, dtype="float32"):
         self.groups = [dict(g) for g in groups if int(g["layers"])]
         self.dtype = jnp.dtype(dtype)
+        self._slots = int(slots)
         self.kinds = [g["kind"] for g in self.groups]
-        self.shapes = [(int(g["layers"]), int(slots), int(g["heads"]),
-                        int(g["rows"]), int(g["head_dim"]))
-                       for g in self.groups]
-        #: per array of ``arrays``: its group's kind and shape
-        self.array_kinds, self._array_shapes = [], []
-        for kind, shape in zip(self.kinds, self.shapes):
-            n = kv_cache.tensors(kind)
-            self.array_kinds += [kind] * n
-            self._array_shapes += [shape] * n
-        self.arrays = [jax.device_put(jnp.zeros(shape, self.dtype))
-                       for shape in self._array_shapes]
+        layouts = [kv_cache.layout(g, self._slots, self.dtype)
+                   for g in self.groups]
+        #: per group the shape of its arrays (a ``state`` group's first)
+        self.shapes = [arrays[0][0] for arrays in layouts]
+        #: per array of ``arrays``: its group's kind
+        self.array_kinds = [kind for kind, arrays in zip(self.kinds, layouts)
+                            for _ in arrays]
+        self._specs = [jax.ShapeDtypeStruct(shape, dt)
+                       for arrays in layouts for shape, dt in arrays]
+        self.arrays = [jax.device_put(jnp.zeros(s.shape, s.dtype))
+                       for s in self._specs]
         # which groups a step attends by blocks of live rows: the rule's
         # answer for the platform the arrays lie on, asked once
         on_tpu = {d.platform for a in self.arrays for d in a.devices()} \
             == {"tpu"}
-        self._by_blocks = [on_tpu and kv_cache.blocked(shape[3], kind)
-                           for kind, shape in zip(self.kinds, self.shapes)]
-        # bytes a position of a layer holds as stored, every tensor
-        self._row_bytes = [h * d * kv_cache.tensors(kind)
-                           * self.dtype.itemsize
-                           for kind, (_, _, h, _, d)
-                           in zip(self.kinds, self.shapes)]
+        # the groups of ROWS (every kind but ``state``): layers, rows,
+        # whether read by blocks, and the bytes a position of a layer
+        # holds as stored, every tensor
+        self._row_groups = [
+            (l, r, on_tpu and kv_cache.blocked(r, kind),
+             h * d * kv_cache.tensors(kind) * self.dtype.itemsize)
+            for kind, (l, _, h, r, d) in (
+                pair for pair in zip(self.kinds, self.shapes)
+                if pair[0] != "state")]
+        # bytes of recurrent state a slot holds, every layer and tensor
+        self._slot_state_bytes = sum(
+            int(np.prod(s.shape)) // self._slots * s.dtype.itemsize
+            for kind, s in zip(self.array_kinds, self._specs)
+            if kind == "state")
 
     # a cache of one K/V group (GPT-2's) reads as the one array pair it is
     @property
@@ -185,35 +199,36 @@ class KVCache:
 
     def specs(self) -> list:
         """``jax.ShapeDtypeStruct`` of every array, in ``arrays`` order."""
-        return [jax.ShapeDtypeStruct(shape, self.dtype)
-                for shape in self._array_shapes]
+        return list(self._specs)
 
     @property
     def slots(self) -> int:
-        return self.shapes[0][1]
+        return self._slots
 
     @property
     def max_len(self) -> int:
-        return max(shape[3] for shape in self.shapes)
+        """The most positions a slot's rows hold (a ``state`` group holds
+        a sequence of any length)."""
+        return max((r for _, r, _, _ in self._row_groups), default=0)
 
     @property
     def nbytes(self) -> int:
-        return self.dtype.itemsize * sum(
-            int(np.prod(shape)) for shape in self._array_shapes)
+        return sum(int(np.prod(s.shape)) * s.dtype.itemsize
+                   for s in self._specs)
 
     @property
     def rows(self) -> int:
         """Rows the cache holds in all: layers x slots x rows, summed
-        over the groups (a row is a position of a layer, whatever it
-        stores there)."""
-        return sum(l * s * r for l, s, _, r, _ in self.shapes)
+        over the groups of rows (a row is a position of a layer, whatever
+        it stores there)."""
+        return sum(l * self._slots * r for l, r, _, _ in self._row_groups)
 
     def live_rows(self, lens) -> int:
         """Of those, the rows that hold a position of a sequence whose
         cached length is in ``lens`` (one entry per active slot)."""
         lens = np.asarray(lens, np.int64)
         return int(sum(l * np.minimum(lens, r).sum()
-                       for l, _, _, r, _ in self.shapes))
+                       for l, r, _, _ in self._row_groups))
 
     def read(self, cache_len) -> Tuple[int, int]:
         """The rows a decode step's attention READS for slots whose
@@ -221,14 +236,20 @@ class KVCache:
         the bytes they hold as stored. Rows: whole blocks up to each
         length and the new row where a group goes by blocks, every row
         of the plane where it is read whole (``kv_cache.fetched_rows``),
-        summed over the groups; never under ``live_rows`` of ``cache_len
-        + 1``. Bytes: a row's ``heads x head_dim`` values in every tensor
-        of its group (K and V, or a latent group's one)."""
+        summed over the groups of rows; never under ``live_rows`` of
+        ``cache_len + 1``. Bytes: a row's ``heads x head_dim`` values in
+        every tensor of its group (K and V, or a latent group's one)."""
         cache_len = np.asarray(cache_len, np.int64)
         rows = [int(l * kv_cache.fetched_rows(cache_len, r, by_blocks).sum())
-                for by_blocks, (l, _, _, r, _)
-                in zip(self._by_blocks, self.shapes)]
-        return sum(rows), sum(n * b for n, b in zip(rows, self._row_bytes))
+                for l, r, by_blocks, _ in self._row_groups]
+        return sum(rows), sum(n * b for n, (_, _, _, b)
+                              in zip(rows, self._row_groups))
+
+    def state_bytes(self, active: int) -> int:
+        """Bytes of recurrent state a decode step reads AND writes for
+        ``active`` slots, as stored: each one's state of every ``state``
+        group once in, once out (0 without such a group)."""
+        return 2 * int(active) * self._slot_state_bytes
 
 
 _DONE = object()
@@ -489,7 +510,8 @@ class DecodeSession:
             fingerprint=params_fingerprint(self._params),
             version=str(model_version), donate=self._donate,
             program=_PROGRAM_REVISION, kv_shape=tuple(self._kv.shapes),
-            kv_dtype=self._kv.dtype.name)
+            kv_dtype="/".join(sorted({s.dtype.name
+                                      for s in self._kv.specs()})))
         self.engine_metrics = ServingMetrics(f"{self.name}.engine")
         # live weight hot-swap: publishers stage off the hot path; the
         # scheduler flips the staged version in BETWEEN steps
@@ -604,8 +626,10 @@ class DecodeSession:
         """The per-bucket cache-join executable: ``kv_cache.join`` of
         each group's prefilled ``[Lg, H, Lb, D]`` plane into slot
         ``slot``'s cache range (a TRACED slot index: one executable
-        serves every slot) for a prompt of TRUE length ``n``. ``at`` is
-        ``[slot, n]``. Cache operands are donated."""
+        serves every slot) for a prompt of TRUE length ``n`` (a ``state``
+        group's operand is one slot's state, with no bucket axis, and
+        replaces the slot's). ``at`` is ``[slot, n]``. Cache operands are
+        donated."""
         ex = self._joins.get(bucket)
         if ex is not None:
             return ex
@@ -625,8 +649,9 @@ class DecodeSession:
                         for cache, plane, kind in zip(caches, planes, kinds))
 
                 planes = [jax.ShapeDtypeStruct(
-                    (spec.shape[0], spec.shape[2], bucket, spec.shape[4]),
-                    spec.dtype) for spec in self._kv.specs()]
+                    kv_cache.plane_shape(spec.shape, kind, bucket),
+                    spec.dtype) for spec, kind in zip(self._kv.specs(),
+                                                      kinds)]
                 at = jax.ShapeDtypeStruct((2,), jnp.int32)
                 jitted = jax.jit(join, donate_argnums=tuple(range(n_arrays))
                                  if self._donate else ())
@@ -1231,7 +1256,9 @@ class DecodeSession:
             telemetry.trace.record(req.trace, "first_step", t0, t1,
                                    active=active)
         read_rows, read_bytes = self._kv.read(lens)
-        flight.turn.close(t0, t1 - t0, active=active,
+        state = {"state_bytes": self._kv.state_bytes(active)} \
+            if "state" in self._kv.kinds else {}
+        flight.turn.close(t0, t1 - t0, active=active, **state,
                           kv_live_rows=self._kv.live_rows(lens + 1),
                           kv_read_rows=read_rows, kv_read_bytes=read_bytes,
                           kv_rows=self._kv.rows,

@@ -309,3 +309,69 @@ def test_latent_decoder_step_aliases_its_one_cache_tensor_published_widths(
         text)) == 2 * layers
     assert len(re.findall(r"= \S+ custom-call\([^\n]*ragged-dot-none",
                           text)) == 3          # gate, up, down of one layer
+
+
+def test_state_space_decoder_step_replaces_its_state_in_place_published_widths(
+        one_chip):
+    """The data-built decoder's served decode step at AI21-Jamba2-3B's
+    widths (hidden 2560, a mixer of 5120 channels with 16 of state and 3
+    taps a channel, 20 query heads over one K/V head of 128; one period of
+    four layers: three state-space layers and an attention layer, the
+    vocabulary cut so that the weights are a test's), 64 slots of 4096
+    positions, donated as ``DecodeSession`` lowers it. All four cache
+    arrays alias their outputs, each in its own type; the stacked state
+    keeps ``E`` minor with the 16 second (``{3,2,1,0}``) and is touched by
+    nothing but one in-place update fusion a layer (and, at this depth,
+    the compiler's own move of the 63 MB through its fast memory,
+    ``copy-start`` / ``copy-done``; at 26 layers there is none): no copy
+    of it, and temp memory under one layer's state; the attention layer
+    goes through the block kernel with 20 queries a stored row."""
+    from incubator_mxnet_tpu import serving
+    from incubator_mxnet_tpu.gluon.model_zoo import get_decoder
+
+    layers, slots, t = 4, 64, 4096
+    net = get_decoder("jamba2_3b", num_layers=layers, dense_layers=layers,
+                      attn_layer_period=4, attn_layer_offset=1,
+                      vocab_size=4096, hidden_size=512, max_length=t)
+    net.cast("bfloat16")
+    net.initialize(init="zeros")
+    with serving.DecodeSession(net, max_slots=slots, max_len=t,
+                               prefill_buckets=(128,), name="ssm4",
+                               donate=True, artifact_dir="") as sess:
+        assert [(s.shape, s.dtype.name) for s in sess._kv.specs()] == [
+            ((1, slots, 1, t, 128), "bfloat16")] * 2 + [
+            ((3, slots, 16, 5120), "float32"),
+            ((3, slots, 3, 5120), "bfloat16")]
+        caches = [_spec(one_chip, s.shape, s.dtype)
+                  for s in sess._kv.specs()]
+        vec = _spec(one_chip, (slots,), jnp.int32)
+        params = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
+        compiled = jax.jit(
+            sess._decode_apply, donate_argnums=(1, 2, 3, 4)).lower(
+            params, *caches, vec, vec).compile()
+        kv_bytes, state_bytes = sess._kv.nbytes, 3 * slots * 16 * 5120 * 4
+    n = len(params)
+    text = compiled.as_text()
+    alias = re.search(r"input_output_alias=\{[^\n]*?\}, entry", text)
+    assert alias and all(f"{{{j + 1}}}: ({n + j}, {{}}" in alias.group(0)
+                         for j in range(4)), "a cache array is not aliased"
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= kv_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 4   # < a layer's state
+    whole = "f32[3,%d,16,5120]{" % slots
+    beside, layouts, fused = {}, [], False
+    for line in text.splitlines():
+        if line and not line.startswith((" ", "}")):
+            fused = not line.startswith("ENTRY")   # only the entry's ops
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if fused or not m or not m.group(1).startswith(whole):
+            continue
+        if m.group(2) == "parameter":
+            layouts.append(m.group(1)[len(whole):].split(":")[0].rstrip("}"))
+        elif m.group(2) not in ("bitcast", "get-tuple-element",
+                                "copy-start", "copy-done"):
+            beside[m.group(2)] = beside.get(m.group(2), 0) + 1
+    assert layouts == ["3,2,1,0"], layouts
+    assert beside == {"fusion": 3}, beside
+    assert _kernel_calls(text) == 1

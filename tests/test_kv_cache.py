@@ -218,3 +218,88 @@ def test_join_of_a_prompt_under_at_and_over_the_rows(kind, n):
     # and a step after it reads exactly those positions
     _, _, see = kv_cache.address(jnp.asarray([0, n, 0], jnp.int32), rows, kind)
     assert int(see[slot].sum()) == min(n + 1, rows)
+
+
+# -- the ``state`` kind: a recurrent layer's state, no rows --------------------
+
+_STATE = dict(layers=3, kind="state", width=256, state=4, taps=3,
+              dtype="float32")
+
+
+def test_a_state_group_keeps_two_tensors_with_a_dtype_each():
+    """The scan's state in the group's own type, the convolution's taps
+    in the cache's, ``E`` minor; a row group's arrays take the cache's
+    type and a prefill hands the join a slot's state with no position
+    axis."""
+    assert kv_cache.tensors("state") == 2
+    got = kv_cache.layout(_STATE, 5, "bfloat16")
+    assert got == [((3, 5, 4, 256), jnp.dtype("float32")),
+                   ((3, 5, 3, 256), jnp.dtype("bfloat16"))]
+    rows = dict(layers=2, heads=2, rows=8, head_dim=128, kind="full")
+    assert kv_cache.layout(rows, 5, "bfloat16") == [
+        ((2, 5, 2, 8, 128), jnp.dtype("bfloat16"))] * 2
+    assert kv_cache.layout(dict(rows, kind="latent"), 5, "float32") == [
+        ((2, 5, 2, 8, 128), jnp.dtype("float32"))]
+    assert kv_cache.plane_shape((3, 5, 4, 256), "state", 64) == (3, 4, 256)
+    assert kv_cache.plane_shape((2, 5, 2, 8, 128), "full", 64) \
+        == (2, 2, 64, 128)
+
+
+@pytest.mark.parametrize("n", [1, 7, 500])
+def test_a_state_join_replaces_the_slots_state_whatever_the_length(n):
+    rs = np.random.RandomState(n)
+    cache = rs.standard_normal((3, S, 4, 8)).astype(np.float32)
+    plane = rs.standard_normal((3, 4, 8)).astype(np.float32)
+    got = np.asarray(kv_cache.join(jnp.asarray(cache), jnp.asarray(plane),
+                                   jnp.int32(1), jnp.int32(n), "state"))
+    np.testing.assert_array_equal(got[:, 1], plane)
+    np.testing.assert_array_equal(got[:, [0, 2]], cache[:, [0, 2]])
+    # a float32 state into a bfloat16 array: cast, not refused
+    low = kv_cache.join(jnp.asarray(cache).astype(jnp.bfloat16),
+                        jnp.asarray(plane), jnp.int32(1), jnp.int32(n),
+                        "state")
+    assert low.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(low[:, 1]),
+        np.asarray(jnp.asarray(plane).astype(jnp.bfloat16)))
+
+
+def test_advance_replaces_one_layers_state_and_no_other():
+    rs = np.random.RandomState(0)
+    cache = rs.standard_normal((3, S, 4, 8)).astype(np.float32)
+    new = rs.standard_normal((S, 4, 8)).astype(np.float32)
+    got = np.asarray(kv_cache.advance(jnp.asarray(cache), 1,
+                                      jnp.asarray(new)))
+    np.testing.assert_array_equal(got[1], new)
+    np.testing.assert_array_equal(got[[0, 2]], cache[[0, 2]])
+
+
+def test_kv_cache_holds_a_state_group_beside_rows():
+    """Per-array dtypes; the state group is outside ``rows``,
+    ``live_rows``, ``read`` and ``max_len``, inside ``nbytes``, and
+    ``state_bytes`` is its own: each active slot's state once in and once
+    out, as stored."""
+    from incubator_mxnet_tpu import serving
+
+    full = dict(layers=2, heads=1, rows=16, head_dim=128, kind="full")
+    kv = serving.KVCache([full, _STATE], slots=5, dtype="bfloat16")
+    only = serving.KVCache([full], slots=5, dtype="bfloat16")
+    assert kv.kinds == ["full", "state"]
+    assert kv.array_kinds == ["full", "full", "state", "state"]
+    assert kv.shapes == [(2, 5, 1, 16, 128), (3, 5, 4, 256)]
+    assert [(a.shape, a.dtype) for a in kv.arrays] \
+        == [(s.shape, s.dtype) for s in kv.specs()] \
+        == [((2, 5, 1, 16, 128), jnp.bfloat16)] * 2 \
+        + [((3, 5, 4, 256), jnp.float32), ((3, 5, 3, 256), jnp.bfloat16)]
+    assert kv.slots == 5 and kv.max_len == only.max_len == 16
+    assert kv.rows == only.rows == 2 * 5 * 16
+    assert kv.live_rows([3, 9]) == only.live_rows([3, 9]) == 2 * 12
+    assert kv.read([3, 9]) == only.read([3, 9])
+    slot = 3 * 256 * (4 * 4 + 3 * 2)
+    assert kv.nbytes == only.nbytes + 5 * slot
+    assert kv.state_bytes(2) == 2 * 2 * slot and kv.state_bytes(0) == 0
+    assert only.state_bytes(5) == 0
+    # a cache of state alone has no rows at all
+    bare = serving.KVCache([_STATE], slots=2)
+    assert bare.rows == bare.max_len == 0 and bare.read([4])[0] == 0
+    assert [a.dtype for a in bare.arrays] == [jnp.float32, jnp.float32]
