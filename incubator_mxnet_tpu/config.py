@@ -142,14 +142,6 @@ config.register(
 
 
 config.register(
-    "MXTPU_FLASH_MIN_SEQ", 2048, int,
-    "Sequence-length crossover for flash_attention dispatch: below this "
-    "(max of Tq, Tk) the XLA dense-softmax path is used — the measured "
-    "Pallas-kernel crossover on v5e is ~2k (PROFILE.md: backward 0.47x "
-    "XLA at T=1024, 1.8x at 2048). Set 0 to always take the Pallas "
-    "kernels (the cuDNN algo-selection analog: reference "
-    "src/operator/nn/cudnn/ autotune registry).")
-config.register(
     "MXTPU_BENCH_FIT_K", 3, int,
     "Number of independent two-point fits per bench.py metric; the "
     "recorded value is the median and the spread rides the BENCH json "

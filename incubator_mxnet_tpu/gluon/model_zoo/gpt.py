@@ -7,8 +7,10 @@ but wired for BOTH halves of the decoder-LLM story:
 
 * **Training**: ``forward(tokens) -> logits`` is a plain causal
   full-sequence pass; attention routes through ``flash_attention``
-  (size-dispatched: XLA dense below the measured Pallas crossover, the
-  streaming Pallas kernels above it), so the same config trains under
+  (dispatched per direction and by size: XLA dense below the measured
+  Pallas crossover, the streaming Pallas kernels above it; a
+  differentiated call by the backward kernels' crossover, a served
+  prefill by the forward kernel's own), so the same config trains under
   ``SPMDTrainer`` + SuperStep + the ZeRO ladder like every other
   workload.
 * **Serving** (docs/SERVING.md "What a block declares"): ``prefill``

@@ -28,7 +28,8 @@ On non-TPU backends the same kernel runs through the Pallas interpreter
 Measured on v5e-1 (bf16, causal, D=64; see PROFILE.md). Forward: 1.7x
 over the XLA chain at T=2048, ~60x at T=8192 (XLA spills), 2.6x at
 T=16384 where the XLA path OOMs without remat. Backward: 1.8x at T=2048,
-4.7x at T=4096 over the XLA backward.
+4.7x at T=4096 over the XLA backward. Which of the kernels and the dense
+chain a call takes: ``_kernel_pays``.
 """
 
 from __future__ import annotations
@@ -474,25 +475,91 @@ def _flash_core_bwd(scale, causal, interpret, cache_offset, res, g):
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-def _use_pallas_path(b, h, tq, tk, interpret):
-    """Size-aware algo selection (the cuDNN-autotune-registry analog).
+def _kernel_pays(direction, bh, tq, tk):
+    """Whether the Pallas kernels beat the dense chain for this call: per
+    direction, from the call's shapes (v5e, bf16; PERF.md section 6
+    "PR 37" holds the tables, chiprun_out/flash_sweep_pr37_a.json,
+    flash_sweep_pr37_b.json and prefill_bench_pr37.json the runs).
 
-    An explicit ``interpret=`` pins the Pallas path (tests exercise the
-    kernels at tiny shapes that way). Otherwise sequences below the
-    measured crossover (``MXTPU_FLASH_MIN_SEQ``, default 2048 — PROFILE.md:
-    Pallas backward is 0.47x XLA at T=1024 but 1.8x/4.7x at 2048/4096)
-    take the XLA dense path in both directions — UNLESS the dense f32
-    score tensor it materialises would exceed 1 GiB, where the flash
-    kernel's O(T) memory wins regardless of speed (a huge-B*H job at
-    T<2048 must never OOM because of a speed heuristic)."""
+    A call that is being differentiated runs the forward AND the backward
+    kernels, and the backward pair decides: 0.47x the XLA backward at
+    T = 1024, 1.8x at 2048, 4.7x at 4096 (PROFILE.md, round 4).
+
+    A call that is not runs the forward kernel alone: ~0.65 us a program
+    (B*H x Tq / 256 of them) and 3-5 us a million scores (B*H x Tq x Tk),
+    so 5.2 us a million at T = 1024, 8.2 at 512, 19 at 256, 43 at 128.
+    The dense chain takes 4.2-5.4 us a million while the compiler keeps
+    its score tensor on the chip and 7.3-24 once it goes through HBM,
+    which it does between 17.8M scores ((1, 17, 1024, 1024): dense 81 us,
+    kernel 95) and 21.0M ((1, 20, ..): 154 against 108; (1, 25, ..): 198
+    against 139, and inside a served GPT-2 XL prefill, where other
+    buffers want the same memory, 0.39 ms a layer against 0.16). Over
+    that size the kernel wins from 512 positions ((16, 16, 512, 512):
+    1259 us against 587) and not under them ((64, 16, 256, 256): 1385
+    against 1264; (256, 12, 128, 128): 1181 against 2193).
+
+    Whatever the speed, a dense fp32 score tensor over 1 GiB takes the
+    kernels: their memory is O(T), and a huge-B*H job must never run out
+    of memory because of a speed heuristic."""
+    scores = bh * tq * tk
+    if scores * 4 > (1 << 30):
+        return True
+    if direction == "differentiated":
+        return max(tq, tk) >= 2048
+    return scores >= 19 << 20 and min(tq, tk) >= 512
+
+
+def _implementation(direction, q, k, lens, scale, causal, interpret,
+                    cache_offset):
+    """The function of (q, k, v) this call runs, counted where it is
+    chosen: while the program is traced. An explicit ``interpret=`` pins
+    the kernels (tests exercise them at tiny shapes that way)."""
+    from .. import telemetry
+
+    b, h, tq, _ = q.shape
+    kernel = interpret is not None or _kernel_pays(
+        direction, b * h, tq, k.shape[2])
+    telemetry.counter(
+        "mxtpu_flash_dispatch_total",
+        "flash_attention calls traced, by the implementation chosen",
+        path="kernel" if kernel else "dense", direction=direction).inc()
+    if not kernel:
+        return lambda q, k, v: _xla_reference(
+            q, k, v, lens, scale, causal, cache_offset=cache_offset)
+
+    def kernels(interpret):
+        return lambda q, k, v: _flash_core(
+            q, k, v, lens, scale, causal, interpret, cache_offset)
+
     if interpret is not None:
-        return True
-    from ..config import config
+        return kernels(bool(interpret))
+    # compiled where the program is lowered for a TPU (a compile for a
+    # described chip included), the Pallas interpreter elsewhere
+    return lambda q, k, v: jax.lax.platform_dependent(
+        q, k, v, tpu=kernels(False), default=kernels(True))
 
-    min_seq = int(config.get("MXTPU_FLASH_MIN_SEQ"))
-    if min_seq <= 0 or max(tq, tk) >= min_seq:
-        return True
-    return b * h * tq * tk * 4 > (1 << 30)
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _attention(q, k, v, lens, *static):
+    """What JAX runs when nothing differentiates the call."""
+    return _implementation("forward", q, k, lens, *static)(q, k, v)
+
+
+def _attention_fwd(q, k, v, lens, *static):
+    """What JAX runs in the call's place when it is differentiated."""
+    out, pull = jax.vjp(
+        _implementation("differentiated", q, k, lens, *static), q, k, v)
+    return out, (pull, lens)
+
+
+def _attention_bwd(*static_res_g):
+    (pull, lens), g = static_res_g[-2:]
+    lens_ct = None if lens is None else \
+        np.zeros(lens.shape, dtype=jax.dtypes.float0)
+    return (*pull(g), lens_ct)
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
 
 
 @register("flash_attention")
@@ -509,9 +576,10 @@ def flash_attention(q, k, v, lengths=None, scale=None, causal=False,
     ``[0, lengths_b - tq + i]`` exactly (decode step t attends [0, t]).
     Requires ``lengths`` with every entry >= Tq; implies ``causal``.
 
-    Dispatch: below the measured Pallas crossover (``MXTPU_FLASH_MIN_SEQ``)
-    the mathematically identical XLA dense path runs instead — same
-    contract, chosen by size the way the reference's cuDNN autotune
+    Dispatch: the Pallas kernels where they beat the mathematically
+    identical XLA dense path, which runs elsewhere — same contract, chosen
+    per direction (is JAX differentiating this call?) and from the call's
+    shapes (``_kernel_pays``), the way the reference's cuDNN autotune
     registry picks an algo per shape."""
     d = q.shape[-1]
     s = float(scale) if scale is not None else 1.0 / (d ** 0.5)
@@ -520,11 +588,5 @@ def flash_attention(q, k, v, lengths=None, scale=None, causal=False,
             raise ValueError("cache_offset=True requires per-sample "
                              "lengths (the cache fill per slot)")
         causal = True
-    if not _use_pallas_path(q.shape[0], q.shape[1], q.shape[2],
-                            k.shape[2], interpret):
-        return _xla_reference(q, k, v, lengths, s, bool(causal),
-                              cache_offset=bool(cache_offset))
-    if interpret is None:
-        interpret = not pallas_available()
-    return _flash_core(q, k, v, lengths, s, bool(causal), bool(interpret),
-                       bool(cache_offset))
+    return _attention(q, k, v, lengths, s, bool(causal), interpret,
+                      bool(cache_offset))

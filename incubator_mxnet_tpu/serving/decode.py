@@ -90,8 +90,10 @@ _PREFILL_PHASES = ("dispatch", "join", "fence")
 #: both blocks and the join through ``ops/kv_cache.py``; 6: a full
 #: group's attention by blocks of live rows on the TPU; 7: a group of
 #: one tensor, the block kernel's operands by planes; 8: the step returns
-#: its tokens once more, as the vector a step launched ahead takes).
-_PROGRAM_REVISION = 8
+#: its tokens once more, as the vector a step launched ahead takes; 9: a
+#: GPT prefill's whole-prompt attention by the forward flash kernel
+#: where ``ops/pallas_attention.py``'s rule sends it).
+_PROGRAM_REVISION = 9
 
 
 def default_prefill_buckets(max_len: int) -> Tuple[int, ...]:

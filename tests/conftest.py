@@ -45,6 +45,9 @@ import pytest
 
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running training tests")
+    config.addinivalue_line(
+        "markers", "the_rule: flash_attention picks its own implementation "
+        "(tests/test_pallas_attention.py pins the kernels everywhere else)")
 
 
 def pytest_collection_modifyitems(config, items):

@@ -196,6 +196,71 @@ def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
     assert _kernel_calls(text) == layers
 
 
+@pytest.mark.parametrize("bucket,kernels", [(1024, 2), (512, 0)])
+def test_prefill_attends_through_the_forward_kernel_gpt2_xl_widths(
+        one_chip, bucket, kernels):
+    """The served prefill at GPT-2 XL's widths (two layers of the 48), as
+    ``DecodeSession`` lowers it: nothing differentiates it, so in the
+    bucket the rule sends there (1024: 26M scores a layer, over 19 x 2**20)
+    its whole-prompt attention is one forward flash kernel a layer and
+    the optimised program holds no score-shaped tensor (the parent wrote
+    and re-read ``bf16[25,1024,1024]`` at least twice a layer); the 512
+    bucket, whose scores the compiler keeps on the chip, is the
+    parent's dense chain."""
+    from incubator_mxnet_tpu import serving
+    from incubator_mxnet_tpu.gluon.model_zoo import get_gpt
+
+    layers, heads, d = 2, 25, 64
+    net = get_gpt("gpt_decoder_345m", num_layers=layers, units=heads * d,
+                  num_heads=heads, vocab_size=50257, max_length=1024,
+                  dropout=0.0)
+    net.cast("bfloat16")
+    net.initialize()
+    with serving.DecodeSession(net, max_slots=16, max_len=1024,
+                               prefill_buckets=(bucket,), name="xl2",
+                               donate=True, artifact_dir="") as sess:
+        params = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
+        compiled = jax.jit(sess._prefill_apply).lower(
+            params, _spec(one_chip, (bucket,), jnp.int32),
+            _spec(one_chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    scores = re.findall(r"\[(?:1,)?%d,%d,%d\]" % (heads, bucket, bucket),
+                        text)
+    assert bool(scores) == (kernels == 0), scores[:3]
+
+
+def test_training_step_attends_through_the_dense_chain_gpt2_medium_widths(
+        one_chip):
+    """The same block under ``jax.grad`` at GPT-2 medium's training shape
+    ``(4, 16, 1024, 64)``: the backward kernels lose to XLA's backward
+    under 2,048 positions, so a differentiated call's program is the
+    parent's, the dense chain with its saved probabilities and no
+    attention custom call."""
+    from incubator_mxnet_tpu.gluon.model_zoo import get_gpt
+    from incubator_mxnet_tpu.parallel.spmd import (collect_params,
+                                                   functional_apply)
+
+    net = get_gpt("gpt_decoder_345m", num_layers=2, units=1024,
+                  num_heads=16, vocab_size=50257, max_length=1024,
+                  dropout=0.0)
+    net.cast("bfloat16")
+    net.initialize()
+    objs = collect_params(net)
+
+    def loss(pvals, tokens):
+        out, _ = functional_apply(net, objs, pvals, tokens)
+        return jnp.mean(out.astype(jnp.float32) ** 2)
+
+    pvals = {n: _spec(one_chip, p.shape, p.dtype) for n, p in objs.items()}
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.value_and_grad(loss)).lower(
+            pvals, _spec(one_chip, (4, 1024), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "f32[4,16,1024,1024]" in text
+
+
 def test_sparse_decoder_step_aliases_both_cache_groups_published_widths(
         one_chip):
     """The data-built decoder's served decode step at K-EXAONE's widths

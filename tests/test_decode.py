@@ -611,6 +611,47 @@ def test_stored_rows_serve_the_plain_forward(heads, head_dim, pack):
             k, v = k2, v2
 
 
+def test_prefill_through_the_forward_kernel_serves_the_plain_forward():
+    """At a bucket the rule of ``ops/pallas_attention.py`` sends to the
+    forward flash kernel (1024 positions x 20 heads of 64: 21.0M scores,
+    over 19 x 2**20; here through the Pallas interpreter, one launch a layer,
+    counted where the program is traced) a real session's greedy streams
+    are the oracle's, whose plain forward at the prompt's own length
+    stays under the crossover and takes the dense chain, and
+    ``serve_prefill``'s logits are the plain forward's."""
+    layers, heads, bucket = 2, 20, 1024
+    np.random.seed(5)
+    mx.random.seed(5)
+    net = get_gpt("gpt_decoder_tiny", vocab_size=VOCAB, num_layers=layers,
+                  units=heads * 64, num_heads=heads, hidden_size=64,
+                  max_length=bucket, dropout=0.0)
+    net.initialize(init="xavier")
+
+    def traced(path):
+        return telemetry.counter("mxtpu_flash_dispatch_total", path=path,
+                                 direction="forward").value
+
+    prompts = _prompts([700, 960], seed=11)
+    with serving.DecodeSession(net, max_slots=2, max_len=bucket,
+                               prefill_buckets=(bucket,),
+                               name="flashfill") as sess:
+        kernel, dense = traced("kernel"), traced("dense")
+        sess.warmup()
+        assert (traced("kernel"), traced("dense")) == (kernel + layers, dense)
+        handles = [sess.submit(p, max_new_tokens=3) for p in prompts]
+        want = [_oracle(net, p, 3) for p in prompts]
+        assert traced("kernel") == kernel + layers      # the oracle: dense
+        for h, w in zip(handles, want):
+            assert h.result(300.0) == w
+        padded = np.zeros(bucket, np.int32)
+        padded[:700] = prompts[0]
+        last, _, _ = jax.jit(lambda pv, tok, n: sess._run(
+            net.serve_prefill, pv, tok, n))(sess._params, padded,
+                                            np.int32(700))
+    plain = net(mx.nd.array(prompts[0][None], dtype="int32")).asnumpy()
+    np.testing.assert_allclose(last, plain[0, -1], rtol=1e-4, atol=1e-4)
+
+
 # ---------------------------------------------------------------------------
 # front-door semantics: backpressure, shedding, drain/healthz
 # ---------------------------------------------------------------------------

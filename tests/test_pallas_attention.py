@@ -12,60 +12,164 @@ from incubator_mxnet_tpu.models import MultiHeadAttention
 
 
 @pytest.fixture(autouse=True)
-def _pin_pallas_path():
-    """These tests exercise the KERNELS at tiny shapes; disable the
-    size-aware dispatch (which would route sub-crossover shapes to the
-    XLA path) for every test except the dispatch test itself."""
-    from incubator_mxnet_tpu.config import config
+def _pin_pallas_path(request, monkeypatch):
+    """These tests exercise the KERNELS at tiny shapes, where the rule
+    (``_kernel_pays``) takes the XLA path: every call through the
+    registered op is given ``interpret=``, which pins the kernels, except
+    in the tests of the rule itself."""
+    import functools
 
-    config.set("MXTPU_FLASH_MIN_SEQ", 0)
-    yield
-    config.unset("MXTPU_FLASH_MIN_SEQ")
-
-
-def test_flash_dispatch_size_aware(monkeypatch):
-    """Below MXTPU_FLASH_MIN_SEQ flash_attention takes the XLA dense path;
-    at/above it, the Pallas kernels — the cuDNN algo-selection analog
-    (VERDICT r4 item 3: no silent sub-crossover Pallas regression)."""
-    from incubator_mxnet_tpu.config import config
     from incubator_mxnet_tpu.ops import pallas_attention as pa
+    from incubator_mxnet_tpu.ops import registry
 
-    calls = []
-    real_core, real_xla = pa._flash_core, pa._xla_reference
-    monkeypatch.setattr(
-        pa, "_flash_core",
-        lambda *a, **k: (calls.append("pallas"), real_core(*a, **k))[1])
-    monkeypatch.setattr(
-        pa, "_xla_reference",
-        lambda *a, **k: (calls.append("xla"), real_xla(*a, **k))[1])
+    if request.node.get_closest_marker("the_rule"):
+        return
+    opdef = registry.get("flash_attention")
+    monkeypatch.setattr(opdef, "fn", functools.partial(
+        opdef.fn, interpret=not pa.pallas_available()))
 
+
+def _kernel_calls(fn, *shapes, dtype="bfloat16"):
+    """Kernel launches in ``fn``'s jaxpr over operands of ``shapes``: a
+    call the rule leaves to the platform holds the compiled kernels and
+    the interpreted ones as the two branches of one switch, so the count
+    is of the first branch where there is one."""
+    import jax
+
+    text = str(jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(s, dtype) for s in shapes)))
+    return text.count("pallas_call") // (2 if "platform_index" in text else 1)
+
+
+# (B, H, Tq, Tk, D) per corner of the rule, whether the call is
+# differentiated, keywords, and how many Pallas launches the call's program
+# holds (a forward: 1; a differentiated call: the forward that saves the
+# log-sum-exp, dQ, dK/dV)
+_RULE = [
+    # not differentiated: the forward kernel's crossover, 19 x 2**20
+    # scores a call and 512 positions
+    pytest.param((1, 25, 512, 512, 64), False, {}, 0, id="forward-under-512"),
+    pytest.param((1, 17, 1024, 1024, 64), False, {}, 0, id="forward-under"),
+    pytest.param((1, 25, 1024, 1024, 64), False, {}, 1, id="forward-over"),
+    pytest.param((16, 16, 512, 512, 64), False, {}, 1,
+                 id="forward-over-batch-512"),
+    pytest.param((256, 12, 128, 128, 64), False, {}, 0,
+                 id="forward-short-rows"),
+    pytest.param((16, 25, 1, 1024, 64), False, {}, 0,
+                 id="forward-decode-row"),
+    # differentiated: the backward pair's crossover, the parent's
+    pytest.param((4, 16, 1024, 1024, 64), True, {}, 0,
+                 id="differentiated-under"),
+    pytest.param((1, 2, 2048, 2048, 64), True, {}, 3,
+                 id="differentiated-over"),
+    # a dense fp32 score tensor over 1 GiB takes the kernels whatever
+    # the speed, in both directions
+    pytest.param((128, 128, 128, 128, 8), False, {}, 0, id="forward-cap"),
+    pytest.param((128, 129, 128, 128, 8), False, {}, 1,
+                 id="forward-over-cap"),
+    pytest.param((16, 16, 1024, 1024, 8), True, {}, 0,
+                 id="differentiated-cap"),
+    pytest.param((16, 17, 1024, 1024, 8), True, {}, 3,
+                 id="differentiated-over-cap"),
+    # an explicit interpret= pins the kernels at any shape
+    pytest.param((1, 1, 16, 16, 16), False, {"interpret": True}, 1,
+                 id="forward-pinned"),
+    pytest.param((1, 1, 16, 16, 16), True, {"interpret": True}, 3,
+                 id="differentiated-pinned"),
+]
+
+
+@pytest.mark.the_rule
+@pytest.mark.parametrize("shape,differentiated,kw,kernels", _RULE)
+def test_flash_dispatch_size_aware(shape, differentiated, kw, kernels):
+    """Which implementation ``flash_attention`` takes is decided per
+    direction and from the call's shapes (the cuDNN algo-selection
+    analog; VERDICT r4 item 3: no silent sub-crossover Pallas
+    regression): read from the jaxpr, a ``pallas_call`` or none."""
+    import jax
     import jax.numpy as jnp
 
+    from incubator_mxnet_tpu import telemetry
+    from incubator_mxnet_tpu.ops import pallas_attention as pa
+
+    b, h, tq, tk, d = shape
+
+    def fwd(q, k, v):
+        return pa.flash_attention(q, k, v, causal=True, **kw)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if differentiated else fwd
+    labels = dict(path="kernel" if kernels else "dense",
+                  direction="differentiated" if differentiated
+                  else "forward")
+    counter = telemetry.counter("mxtpu_flash_dispatch_total", **labels)
+    before = counter.value
+    assert _kernel_calls(fn, (b, h, tq, d), (b, h, tk, d),
+                         (b, h, tk, d)) == kernels
+    assert counter.value == before + 1
+
+
+@pytest.mark.the_rule
+def test_trainer_step_counts_its_attention_as_differentiated():
+    """A training step differentiates every layer's call, so each is
+    traced through the rule's ``differentiated`` side (dense under 2,048
+    positions: the parent's program) and none through ``forward``, which
+    is what a plain forward of the same block counts."""
+    from incubator_mxnet_tpu import gluon, parallel, telemetry
+    from incubator_mxnet_tpu.gluon.model_zoo import get_gpt
+
+    layers, vocab = 2, 61
+    net = get_gpt("gpt_decoder_tiny", vocab_size=vocab, units=32,
+                  num_layers=layers, max_length=16, dropout=0.0)
+    net.initialize(init="xavier")
+
+    def traced():
+        return {(p, d): telemetry.counter(
+            "mxtpu_flash_dispatch_total", path=p, direction=d).value
+            for p in ("kernel", "dense")
+            for d in ("forward", "differentiated")}
+
     rng = np.random.RandomState(0)
+    tokens = rng.randint(0, vocab, (8, 16)).astype(np.int32)
+    start = traced()
+    net(nd.array(tokens[:1], dtype="int32"))
+    before = traced()
+    assert before[("dense", "forward")] == start[("dense", "forward")] \
+        + layers
+    trainer = parallel.SPMDTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+        {"learning_rate": 0.1}, mesh=parallel.make_mesh({"data": -1}))
+    trainer.step(tokens, rng.randint(0, vocab, (8, 16)).astype(np.float32))
+    after = traced()
+    moved = {k: after[k] - before[k] for k in after if after[k] != before[k]}
+    assert moved == {("dense", "differentiated"): layers}, moved
 
-    def run(t):
-        x = jnp.asarray(rng.randn(1, 2, t, 16).astype(np.float32))
-        return pa.flash_attention(x, x, x, causal=True)
 
-    config.set("MXTPU_FLASH_MIN_SEQ", 64)
-    try:
-        run(32)
-        assert calls == ["xla"], calls          # below crossover -> XLA
-        calls.clear()
-        run(64)
-        assert calls == ["pallas"], calls       # at crossover -> kernels
-        calls.clear()
-        # explicit interpret= pins the Pallas path regardless of size
-        x = jnp.asarray(rng.randn(1, 1, 16, 16).astype(np.float32))
-        pa.flash_attention(x, x, x, interpret=True)
-        assert calls == ["pallas"], calls
-        calls.clear()
-        # knob 0 disables dispatch entirely
-        config.set("MXTPU_FLASH_MIN_SEQ", 0)
-        run(8)
-        assert calls == ["pallas"], calls
-    finally:
-        config.unset("MXTPU_FLASH_MIN_SEQ")
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("t", [512, 1024])
+@pytest.mark.parametrize("heads", [25, 16])
+def test_forward_kernel_matches_dense_at_served_widths(heads, t, dtype):
+    """The forward kernel against the dense chain at a served GPT's
+    prefill shapes (GPT-2 XL's 25 heads of 64 and GPT-2 medium's 16, the
+    512 and 1024 buckets, causal), in the interpreter, with the block
+    sizes the kernel runs with (``bq`` 256, ``bk`` 512)."""
+    import jax.numpy as jnp
+
+    from incubator_mxnet_tpu.ops import pallas_attention as pa
+
+    d = 64
+    rng = np.random.RandomState(heads + t)
+    q, k, v = (jnp.asarray(rng.randn(1, heads, t, d), dtype)
+               for _ in range(3))
+    got = pa.flash_attention(q, k, v, causal=True, interpret=True)
+    want = pa._xla_reference(q, k, v, None, d ** -0.5, True)
+    assert got.shape == want.shape == (1, heads, t, d)
+    tol = 2e-2 if dtype == "bfloat16" else 2e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
 
 
 def _grad_tols():

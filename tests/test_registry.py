@@ -266,12 +266,13 @@ def test_decode_artifact_guard_covers_cache_shape(tmp_path):
         s2.close()
 
 
-@pytest.mark.parametrize("stored", ["absent", 1, 3])
+@pytest.mark.parametrize("stored", ["absent", 1, 3, 8])
 def test_decode_artifact_of_older_program_refused(tmp_path, monkeypatch,
                                                   stored):
     """A decode artifact persisted by an older PROGRAM (the parent
     of PR 26 wrote no ``program`` field; later ones write a lower
-    revision, 3 the one whose GPT cache kept a head a row) agrees with the guard on compiler, device and shapes. It
+    revision, 3 the one whose GPT cache kept a head a row, 8 the one
+    whose GPT prefill attended through a dense score tensor) agrees with the guard on compiler, device and shapes. It
     is refused by the field's name and recompiled, never deserialized
     into the session."""
     from jax.experimental import serialize_executable
