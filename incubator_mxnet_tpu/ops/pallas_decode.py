@@ -8,9 +8,15 @@ whole; the kernel indexes the layer itself (a ``cache[layer]`` ahead of a
 custom call would be a copy of the plane). Rows go by blocks of
 ``BLOCK``: the caller's ``cache_len`` (S,) becomes a flat list
 of the (slot, block) pairs that hold a cached position, in slot order,
-and one loop walks it with the next pair's K and V blocks (H, block, W)
-in flight while this pair's are computed, across slot boundaries, so the
-memory never waits for a grid step. Blocks at or above
+and one loop walks it with the K and V blocks (H, block, W) of the next
+``depth`` pairs in flight while this pair's are computed, across slot
+boundaries, so the memory never waits for a grid step. A ring of
+``depth + 1`` buffers a plane holds them; ``depth`` comes from the
+operands' shapes (``_in_flight``): as many pairs as keep ``IN_FLIGHT``
+bytes on the way, which is one pair of GPT-2 XL's 13 K/V rows of 128
+lanes (the rate the kernel reaches there) and more pairs where a pair is
+smaller, 2 for 8 K/V heads, 6 for one latent plane of 640 lanes, 13 for
+one K/V head of 128. Blocks at or above
 ``ceil(cache_len / block)`` are neither fetched nor computed; rows at or
 above ``cache_len`` inside a slot's last block are masked.
 
@@ -43,9 +49,15 @@ import jax.numpy as jnp
 from . import pallas_attention
 
 #: rows of a block, of which a slot fetches half dead at the mean: the
-#: smallest tried, as fast a byte as 512 at both served shapes, 8 and 13
-#: stored rows a slot (PERF.md PR 31)
+#: smallest tried, a block as fast a byte as one of 512 at both shapes
+#: then served, 8 and 13 stored rows a slot (PERF.md PR 31); what a small
+#: row loses to latency is made up by pairs in flight, not by longer blocks
 BLOCK = 128
+#: bytes the ring keeps in flight: one (slot, block) pair of GPT-2 XL's
+#: cache, 2 planes x 13 rows x 128 x 128 lanes of bf16 (~740 GB/s there)
+IN_FLIGHT = 2 * 13 * BLOCK * 128 * 2
+#: the deepest ring, whatever the pair
+_MAX_DEPTH = 16
 #: queries a K/V row are padded to whole sublane tiles of this many
 _SUBLANES = 8
 
@@ -55,6 +67,14 @@ def cached_rows(cache_len, rows):
     and ``rows - 1`` for a stale or full slot, whose new row stands in
     the last one (``kv_cache.address``). Arrays of numpy or jax alike."""
     return cache_len.clip(0, rows - 1)
+
+
+def _in_flight(h, w, planes, itemsize):
+    """Pairs the kernel keeps in flight beside the one it computes, for
+    ``planes`` blocks (h, BLOCK, w) of ``itemsize`` bytes a pair: enough
+    to hold ``IN_FLIGHT`` bytes on the way, 1 to ``_MAX_DEPTH``."""
+    pair = planes * h * BLOCK * w * itemsize
+    return max(1, min(_MAX_DEPTH, -(-IN_FLIGHT // pair)))
 
 
 def _plan(cache_len, rows):
@@ -71,7 +91,7 @@ def _plan(cache_len, rows):
 
 
 def _kernel(layer_ref, total_ref, n_ref, slot_ref, block_ref,
-            q_ref, kn_ref, vn_ref, *refs, scale, precision, v_width):
+            q_ref, kn_ref, vn_ref, *refs, scale, precision, v_width, depth):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -93,19 +113,22 @@ def _kernel(layer_ref, total_ref, n_ref, slot_ref, block_ref,
     # a slot of no cached row attends its new row alone
     o_ref[...] = jnp.broadcast_to(vn_ref[...], o_ref.shape)
 
-    @pl.when(total > 0)
-    def _():
-        for c in copies(0, 0):
-            c.start()
+    # pair t lies in buffer t % (depth + 1); the first depth start here
+    for t in range(depth):
+        @pl.when(t < total)
+        def _():
+            for c in copies(t, t):
+                c.start()
 
     def body(t, carry):
-        buf = t % 2
+        buf = t % (depth + 1)
         slot, j = slot_ref[t], block_ref[t]
         n = n_ref[slot]
 
-        @pl.when(t + 1 < total)
+        # into the buffer pair t - 1 was computed from
+        @pl.when(t + depth < total)
         def _():
-            for c in copies(t + 1, 1 - buf):
+            for c in copies(t + depth, (t + depth) % (depth + 1)):
                 c.start()
 
         q = q_ref[slot]                                      # (H, G, W)
@@ -147,16 +170,19 @@ def _kernel(layer_ref, total_ref, n_ref, slot_ref, block_ref,
 
 
 def attend(q, k_cache, v_cache, layer, k_new, v_new, cache_len, head_dim,
-           scale=None, v_width=None, interpret=None):
+           scale=None, v_width=None, interpret=None, _depth=None):
     """Layer ``layer``'s one-token attention: ``q`` (S, H, G, W), ``G``
     queries a stored K/V row; ``k_cache``/``v_cache`` the stacked
     (L, S, H, rows, W); ``k_new``/``v_new`` (S, H, 1, W) the new token's
     rows; ``cache_len`` (S,). Returns (S, H, G, W) in the values' type:
     softmax over the slot's cached rows and its new row. With no
     ``v_cache`` (and no ``v_new``) the values are the first ``v_width``
-    lanes of the keys, and the result is that wide."""
+    lanes of the keys, and the result is that wide. ``_depth`` replaces
+    the pairs in flight that the shapes give (tests only)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    from .. import telemetry
 
     s, h, g, w = q.shape
     rows = k_cache.shape[3]
@@ -171,25 +197,31 @@ def attend(q, k_cache, v_cache, layer, k_new, v_new, cache_len, head_dim,
     q = jnp.pad(q, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
     n, total, slot_of, block_of = _plan(cache_len, rows)
     # the package's ambient ``highest`` is refused for 16-bit products
-    precision = (jax.lax.Precision.DEFAULT if k_cache.dtype.itemsize < 4
+    itemsize = k_cache.dtype.itemsize
+    precision = (jax.lax.Precision.DEFAULT if itemsize < 4
                  else jax.lax.Precision.HIGHEST)
+    depth = _depth or _in_flight(h, w, len(caches), itemsize)
+    telemetry.counter(
+        "mxtpu_decode_attention_depth_total",
+        "kv_decode_attention calls traced, by the pairs kept in flight",
+        depth=depth).inc()
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(
             _kernel, scale=head_dim ** -0.5 if scale is None else scale,
-            precision=precision, v_width=v_width),
+            precision=precision, v_width=v_width, depth=depth),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5, grid=(1,),
             in_specs=[whole, whole, whole]
             + [pl.BlockSpec(memory_space=pl.ANY) for _ in caches],
             out_specs=whole,
-            scratch_shapes=[pltpu.VMEM((2, h, BLOCK, w), c.dtype)
+            scratch_shapes=[pltpu.VMEM((depth + 1, h, BLOCK, w), c.dtype)
                             for c in caches]
-            + [pltpu.SemaphoreType.DMA((len(caches), 2))]),
+            + [pltpu.SemaphoreType.DMA((len(caches), depth + 1))]),
         out_shape=jax.ShapeDtypeStruct((s, h, gp, w_out), k_cache.dtype),
-        # the blocks in flight (two a plane), and room for the rest
+        # the ring (depth + 1 blocks a plane), and room for the rest
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=(16 << 20)
-            + 4 * h * BLOCK * w * k_cache.dtype.itemsize),
+            + (depth + 1) * len(caches) * h * BLOCK * w * itemsize),
         interpret=interpret, name="kv_decode_attention",
     )(jnp.asarray(layer, jnp.int32).reshape(1), total, n, slot_of,
       block_of, q, k_new, v_new, *caches)

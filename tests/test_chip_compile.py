@@ -111,25 +111,35 @@ def test_fused_conv_bn_forward_and_backward_resnet50_shape(one_chip, hw, c):
     _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), x, w, ab, ab)
 
 
-@pytest.mark.parametrize("shape,queries,head_dim", [
-    ((48, 16, 13, 1024, 128), 2, 64),     # GPT-2 XL: two heads a stored row
-    ((2, 32, 8, 4096, 128), 8, 128),      # K-EXAONE: 8 queries a K/V head
+@pytest.mark.parametrize("shape,queries,head_dim,v_width,depth", [
+    # GPT-2 XL: two heads a stored row
+    ((48, 16, 13, 1024, 128), 2, 64, None, 1),
+    # K-EXAONE: 8 queries a K/V head
+    ((2, 32, 8, 4096, 128), 8, 128, None, 2),
+    # Xing4: one latent plane, its first 512 lanes the values
+    ((40, 32, 1, 2048, 640), 32, 640, 512, 6),
+    # Jamba2: 20 queries over one K/V head
+    ((2, 64, 1, 4096, 128), 20, 128, None, 13),
 ])
 def test_decode_attention_kernel_benchmark_shapes(one_chip, shape, queries,
-                                                  head_dim):
+                                                  head_dim, v_width, depth):
     """The block kernel of the decode step's attention alone, at the
-    served caches' whole shapes: the stacked cache goes in as it lies
-    (no slice or copy of a plane ahead of the call)."""
+    served caches' whole shapes and the ring's depth there (it lowers and
+    fits the compiler's fast memory): the stacked cache goes in as it
+    lies (no slice or copy of a plane ahead of the call)."""
     from incubator_mxnet_tpu.ops import pallas_decode
 
     _, slots, heads, _, w = shape
+    one = v_width is not None
+    assert pallas_decode._in_flight(heads, w, 1 if one else 2, 2) == depth
     row = _spec(one_chip, (slots, heads, 1, w))
     compiled = _compile(
         lambda q, k, v, kn, vn, n, layer: pallas_decode.attend(
-            q, k, v, layer, kn, vn, n, head_dim, interpret=False),
+            q, k, v, layer, kn, vn, n, head_dim, v_width=v_width,
+            interpret=False),
         _spec(one_chip, (slots, heads, queries, w)), _spec(one_chip, shape),
-        _spec(one_chip, shape), row, row, _spec(one_chip, (slots,), jnp.int32),
-        _spec(one_chip, (), jnp.int32))
+        None if one else _spec(one_chip, shape), row, None if one else row,
+        _spec(one_chip, (slots,), jnp.int32), _spec(one_chip, (), jnp.int32))
     assert not re.search(r"= bf16\[%d,%d,%d,%d\]\S* (copy|slice|dynamic-slice)"
                          % shape[1:], compiled.as_text())
 

@@ -1,11 +1,14 @@
 """``ops/kv_cache.py`` against a by-hand numpy cache: write the new row,
 then dense attention over the rows the slot may read."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
-from incubator_mxnet_tpu.ops import kv_cache
+from incubator_mxnet_tpu import telemetry
+from incubator_mxnet_tpu.ops import kv_cache, pallas_decode
 
 L, S, ROWS, D = 2, 3, 6, 16
 STALE = 1            # a freed slot whose cache_len stays at ``ROWS``
@@ -98,20 +101,27 @@ _LENS = {"0": lambda rows: 0, "1": lambda rows: 1,
          "rows(stale)": lambda rows: rows}
 #: g heads a stored row, G queries a K/V head, the head's width
 _FORMS = {"g1-3q": (1, 3, 128), "g1-8q": (1, 8, 128), "g2": (2, 1, 64)}
+#: (rows, dtype, pairs in flight): None is what the shapes give (4 in
+#: float32, 7 in bfloat16 here); the others are the served depths, over
+#: calls of 0 to 13 pairs
+_RINGS = [(r, t, None) for r in (2 * BLOCK, 4 * BLOCK)
+          for t in ("float32", "bfloat16")] \
+    + [(4 * BLOCK, "bfloat16", d) for d in (1, 2, 6, 13)]
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("rows", [2 * BLOCK, 4 * BLOCK])
+@pytest.mark.parametrize("rows,dtype,depth", _RINGS)
 @pytest.mark.parametrize("form", sorted(_FORMS))
 @pytest.mark.parametrize("lens", sorted(_LENS) + ["unlike"])
-def test_block_kernel_is_the_dense_attention(lens, form, rows, dtype):
+def test_block_kernel_is_the_dense_attention(lens, form, rows, dtype, depth):
     """``attend_blocks`` (the kernel, in the interpreter) against
     ``read`` + ``attend`` on the same step: every slot at one length, or
     all seven lengths side by side in one call. Blocks above a slot's
     last live one are NaN in the kernel's cache, so a dead block fetched
     and let through a mask fails; the rows of the last live block above
     ``cache_len``, and the row the new token will take, hold finite
-    garbage, which the mask and ``k_new``/``v_new`` keep out."""
+    garbage, which the mask and ``k_new``/``v_new`` keep out. A ring of
+    any depth reads the same rows: a call of fewer pairs than the depth
+    (every slot at length 0 has none) starts only the pairs it has."""
     g, queries, d = _FORMS[form]
     layers, heads, w, layer = 2, 2, g * d, 1
     ns = [f(rows) for f in _LENS.values()] if lens == "unlike" \
@@ -134,12 +144,49 @@ def test_block_kernel_is_the_dense_attention(lens, form, rows, dtype):
         [-(-min(n, rows - 1) // BLOCK) * BLOCK for n in ns])[:, None]
     k, v = (jnp.where(dead[None, :, None, :, None], jnp.nan, a)
             for a in (k, v))
-    got = kv_cache.attend_blocks(q, k, v, layer, k_new, v_new, n, d)
+    # copies run as they start, and one past the plane's end raises
+    eager = pltpu.InterpretParams(dma_execution_mode="eager")
+    got = kv_cache.attend_blocks(q, k, v, layer, k_new, v_new, n, d,
+                                 interpret=eager) \
+        if depth is None else kv_cache._heads_of_a_row(
+            lambda q: pallas_decode.attend(q, k, v, layer, k_new, v_new, n,
+                                           d, interpret=eager, _depth=depth),
+            q, w, d)
     assert got.shape == q.shape and got.dtype == want.dtype
     tol = 2e-5 if dtype == "float32" else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("h,w,planes,itemsize,depth", [
+    (13, 128, 2, 2, 1),      # GPT-2 XL: 13 stored rows of two heads
+    (8, 128, 2, 2, 2),       # K-EXAONE: 8 K/V heads
+    (1, 640, 1, 2, 6),       # Xing4: one latent plane of 640 lanes
+    (1, 128, 2, 2, 13),      # Jamba2: one K/V head
+    (1, 128, 1, 2, 16),      # a pair of 32 KiB: the ring's deepest
+    (13, 128, 2, 4, 1),      # more than the target a pair
+])
+def test_pairs_in_flight_come_from_the_shapes(h, w, planes, itemsize,
+                                              depth):
+    """``_in_flight`` keeps GPT-2 XL's one pair of bytes on the way at
+    every served shape, clipped to 1-16 pairs, and the counter of traced
+    calls goes up by one at that depth for a call at those shapes."""
+    assert pallas_decode._in_flight(h, w, planes, itemsize) == depth
+    counter = telemetry.counter("mxtpu_decode_attention_depth_total",
+                                depth=depth)
+    before = counter.value
+    dtype = {2: jnp.bfloat16, 4: jnp.float32}[itemsize]
+    slots, rows, queries = 2, 2 * BLOCK, 8
+    cache = jax.ShapeDtypeStruct((1, slots, h, rows, w), dtype)
+    row = jax.ShapeDtypeStruct((slots, h, 1, w), dtype)
+    jax.eval_shape(
+        lambda q, k, v, kn, vn, n: pallas_decode.attend(
+            q, k, v, 0, kn, vn, n, w, v_width=w // 2 if v is None else None),
+        jax.ShapeDtypeStruct((slots, h, queries, w), dtype), cache,
+        None if planes == 1 else cache, row, None if planes == 1 else row,
+        jax.ShapeDtypeStruct((slots,), jnp.int32))
+    assert counter.value == before + 1
 
 
 def test_rings_small_and_ragged_planes_are_read_dense():
