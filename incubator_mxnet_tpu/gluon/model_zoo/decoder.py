@@ -7,8 +7,8 @@ The layers are those today's large open decoders are built from:
 
 * grouped-query attention (``num_heads`` query heads over
   ``num_kv_heads`` K/V heads of ``head_dim``), RMSNorm over each query and
-  key head (``qk_norm``), rotary positions on the window layers and none
-  on the full ones;
+  key head (``qk_norm``), rotary positions on the window layers and, only
+  where ``rope_full`` says so, on the full ones;
 * a selective state-space mixer (Jamba's, arXiv:2403.19887; ``mamba`` gives
   ``expand``, ``d_state``, ``dt_rank``, ``d_conv``): ``[x, z] = W_in u``, a
   causal depthwise convolution over the last ``d_conv`` inputs, ``[d, B,
@@ -37,14 +37,24 @@ The layers are those today's large open decoders are built from:
   ``streams`` of them under manifold-constrained hyper-connections
   (``ops/hyper_connection.py``: per token and sub-layer a read, a write
   and a doubly stochastic mix of the streams); and where the norm stands:
-  on each sub-layer's OUTPUT (``g = norm . f``) or, ``pre_norm``, on its
-  input (``g = f . norm``).
+  on each sub-layer's OUTPUT (``g = norm . f``), ``pre_norm`` on its
+  input (``g = f . norm``), or ``sandwich`` on both, each with a gain of
+  its own (``g = norm_out . f . norm_in``);
+* a loop (Ouro's LoopLM, arXiv:2510.25741): ``passes`` over 1 runs the
+  whole stack that many times a token over the same weights, the final
+  norm at the end of every pass and its output the next pass's input; the
+  head reads the last pass's normed state. Every (pass, layer) attends
+  over keys of its own, so a layer's cache rows are kept per pass. The
+  passes are a loop in the program (``jax.lax.scan``, scope
+  ``loop_pass``), not ``passes`` copies of the stack.
 
 What the block declares to be served by ``serving.DecodeSession``
 (docs/SERVING.md "What a block declares"):
 
 * ``cache_groups(max_len)``: the cache as groups of layers with their own
-  row count and kind: the full layers keep ``max_len`` rows of K and V,
+  row count and kind (of a looped block, ``passes`` x its layers: pass
+  ``r`` of the group's layer ``j`` at index ``r * Lg + j``): the full
+  layers keep ``max_len`` rows of K and V,
   the window layers a ring of ``window`` rows, the latent layers
   ``max_len`` rows of one tensor, the state-space layers a ``state``
   group (no rows: two tensors a layer that a step replaces);
@@ -56,7 +66,9 @@ What the block declares to be served by ``serving.DecodeSession``
   the caches updated where they lie. Where a row lies, what a slot may
   read, the one-token attention, the writes and how a state advances are
   ``ops/kv_cache.py``'s;
-* ``step_counters``: the integers a step returns beside the logits.
+* ``step_counters``: the integers a step returns beside the logits;
+* ``passes`` and ``step_weight_bytes(slots)``: how many times a step runs
+  the stack, and the parameter bytes one step reads.
 
 The arithmetic is plain ``jax.numpy`` over the parameter arrays (one
 ``invoke`` per entry point); the matrix products take their operands'
@@ -158,7 +170,12 @@ class HybridDecoder(HybridBlock):
     row ``[c | k_r]`` is stored at ``kv_cache.whole_tiles`` of ``kv_rank +
     rope_dim`` lanes, the rest zero. ``streams`` over 1 puts the hyper-connections'
     ``streams`` streams in the one's place, with ``hc`` their ``iters``,
-    ``eps`` and ``clamp``."""
+    ``eps`` and ``clamp``. ``sandwich`` norms each sub-layer's input and
+    its output (``attn_out_norm``, ``ffn_out_norm``), ``rope_full`` rotates
+    the full-attention layers' queries and keys too, ``passes`` runs the
+    stack that many times a token; ``exit_threshold`` is the early-exit
+    gate's, and only 1 (every token runs every pass: the gate selects
+    nothing and is not computed) is served."""
 
     step_counters = ("routed_here", "routed_all", "experts_hit",
                      "expert_load_max")
@@ -169,11 +186,23 @@ class HybridDecoder(HybridBlock):
                  expert_share=0, experts_per_token=0, expert_hidden=0,
                  routed_scale=1.0, max_length=4096, latent=None, streams=1,
                  hc=None, pre_norm=False, mamba=None, qk_norm=True,
-                 tie_embeddings=False, prefix=None, params=None):
+                 tie_embeddings=False, sandwich=False, rope_full=False,
+                 passes=1, exit_threshold=1.0, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         if len(layer_types) != len(mlp_layer_types):
             raise ValueError("layer_types and mlp_layer_types differ in "
                              "length")
+        if sandwich and pre_norm:
+            raise ValueError("a sub-layer's norm is sandwich or pre_norm, "
+                             "not both")
+        if exit_threshold < 1:
+            raise ValueError(
+                f"exit_threshold {exit_threshold}: an early exit before the "
+                f"last pass is not served (every token runs every pass)")
+        if int(passes) > 1 and (streams > 1 or "sparse" in mlp_layer_types
+                                or "mamba" in layer_types):
+            raise ValueError("passes over 1 run a stack of attention layers "
+                             "with dense FFNs on one stream only")
         if num_heads % num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
         sparse = "sparse" in mlp_layer_types
@@ -206,6 +235,8 @@ class HybridDecoder(HybridBlock):
         self._hc = dict(hc or {})
         self._ssm = dict(mamba or {})
         self._qk_norm, self._tied = bool(qk_norm), bool(tie_embeddings)
+        self._sandwich, self._rope_full = bool(sandwich), bool(rope_full)
+        self.passes = int(passes)
         # per layer (cache group, index in the group); layers per group
         self._group_counts, self._group_of = [0] * len(_KINDS), []
         for attn, _ in self._kinds:
@@ -224,6 +255,8 @@ class HybridDecoder(HybridBlock):
                 self.head = get("head", shape=(self._vocab, c))
             for i, (attn, ffn) in enumerate(self._kinds):
                 shapes = {"attn_norm": (c,), "ffn_norm": (c,)}
+                if self._sandwich:
+                    shapes.update(attn_out_norm=(c,), ffn_out_norm=(c,))
                 if attn == "latent_attention":
                     la, h = self._latent, self._heads
                     shapes.update(
@@ -286,12 +319,14 @@ class HybridDecoder(HybridBlock):
         ``taps`` a channel and the state's ``dtype`` instead: float32
         whatever the model is served in, because the scan sums into it
         at every step. Groups a model has no layer of are left out but
-        keep their place in the order."""
+        keep their place in the order. A looped block's group holds
+        ``passes`` x its layers, pass by pass."""
         rows = (int(max_len), min(self._window, int(max_len)), int(max_len))
         heads = (self._kv_heads, self._kv_heads, 1)
         widths = (self._head_dim, self._head_dim, self._latent.get("row"))
         # the three kinds of rows (``zip`` stops before the state group)
-        groups = [dict(layers=n, heads=h, rows=r, head_dim=w, kind=kind)
+        groups = [dict(layers=n * self.passes, heads=h, rows=r, head_dim=w,
+                       kind=kind)
                   for n, h, r, w, kind in zip(self._group_counts, heads,
                                               rows, widths, _KINDS)]
         ma = self._ssm
@@ -301,6 +336,21 @@ class HybridDecoder(HybridBlock):
                 width=ma["expand"] * self._units, state=ma["d_state"],
                 taps=ma["d_conv"] - 1, dtype="float32"))
         return [g for g in groups if g["layers"]]
+
+    def step_weight_bytes(self, slots):
+        """Parameter bytes a decode step of ``slots`` slots reads, as they
+        are held: every layer's and the final norm's once a pass, the head
+        once and one table row a slot. None where a step reads a share of
+        them that routing decides (a ``sparse`` layer)."""
+        if any(ffn == "sparse" for _, ffn in self._kinds):
+            return None
+        nbytes = {n: int(math.prod(p.shape)) * jnp.dtype(p.dtype).itemsize
+                  for n, p in self._reg_params.items()}
+        stack = sum(b for n, b in nbytes.items()
+                    if n.startswith("layer") or n == "final_norm")
+        table = nbytes["embed"]
+        return self.passes * stack + nbytes.get("head", table) \
+            + int(slots) * table // self._vocab
 
     # -- the arithmetic, over plain arrays -------------------------------------
     def _arrays(self):
@@ -317,8 +367,12 @@ class HybridDecoder(HybridBlock):
         residual rule: ``x`` is the stream, or the ``streams`` streams
         (n, ..., C)."""
         norm = lambda a: rms_norm(a, lp[name + "_norm"], self._eps)
-        g = (lambda u: f(norm(u))) if self._pre_norm \
-            else (lambda u: norm(f(u)))
+        if self._sandwich:
+            g = lambda u: rms_norm(f(norm(u)), lp[name + "_out_norm"],
+                                   self._eps)
+        else:
+            g = (lambda u: f(norm(u))) if self._pre_norm \
+                else (lambda u: norm(f(u)))
         if self._streams == 1:
             return x + g(x)
         with jax.named_scope("hyper_connection"):
@@ -339,7 +393,7 @@ class HybridDecoder(HybridBlock):
     def _qkv(self, lp, x, positions, window):
         """``x`` (B, T, C) -> q (B, Hkv, G, T, D), k and v (B, Hkv, T, D),
         normed per head where the block norms them; rotated on window
-        layers."""
+        layers, and on full ones where ``rope_full`` says so."""
         b, t, _ = x.shape
         d, hkv = self._head_dim, self._kv_heads
         heads = lambda a, n: a.reshape(b, t, n, d).transpose(0, 2, 1, 3)
@@ -349,7 +403,7 @@ class HybridDecoder(HybridBlock):
             q = rms_norm(q, lp["q_norm"], self._eps)
             k = rms_norm(k, lp["k_norm"], self._eps)
         v = heads(_mm(x, lp["v"]), hkv)
-        if window:
+        if window or self._rope_full:
             q = rope(q, positions[:, None, :], self._theta)
             k = rope(k, positions[:, None, :], self._theta)
         return q.reshape(b, hkv, self._heads // hkv, t, d), k, v
@@ -520,42 +574,77 @@ class HybridDecoder(HybridBlock):
                               lp["shared_down"]).astype(jnp.float32)
         return y.astype(x.dtype), counts
 
-    def _sequence(self, p, tokens, n=None):
+    def _sequence(self, p, tokens, n=None, entry="forward"):
         """``tokens`` (B, T) -> the last layer's output (B, T, C) and
         per layer its cache planes: K and V (B, Hkv, T, D), the one
         (B, 1, T, row) of a latent layer, or a state-space layer's state
         (B, N, E) and taps (B, K - 1, E) after position ``n - 1`` (the
-        true length of padded sequences, traced; ``T`` where None)."""
+        true length of padded sequences, traced; ``T`` where None). Of a
+        looped block (``_passes``) the output is the last pass's, normed,
+        and a layer's planes are stacked over the passes, (R, B, ...)."""
         b, t = tokens.shape
         x = self._embed(p, tokens)
         positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-        planes = []
-        for attn, _ in self._kinds:
-            lp = self._layer(p, len(planes))
 
-            def attention(u):
-                if attn == "latent_attention":
-                    with jax.named_scope("latent_attention"):
-                        a, row = self._latent_expanded(lp, u, positions)
-                    planes.append((row,))
-                    return a
-                if attn == "mamba":
-                    with jax.named_scope("state_space"):
-                        a, *state = self._mamba(lp, u, n=n)
-                    planes.append(tuple(state))
-                    return a
-                window = self._window if attn == "sliding_attention" else 0
-                with jax.named_scope("attention"):
-                    q, k, v = self._qkv(lp, u, positions, window)
-                    a = self._merge(lp, self._attend_sequence(
-                        q, k, v, window, 1.0 / math.sqrt(self._head_dim)))
-                planes.append((k, v))
-                return a
+        def stack(x, _):
+            planes = []
+            for attn, _ in self._kinds:
+                lp = self._layer(p, len(planes))
 
-            x = self._sub(lp, x, "attn", attention)
-            x = self._sub(lp, x, "ffn", lambda u: self._ffn(
-                lp, u.reshape(b * t, -1))[0].reshape(b, t, -1))
+                def attention(u):
+                    if attn == "latent_attention":
+                        with jax.named_scope("latent_attention"):
+                            a, row = self._latent_expanded(lp, u, positions)
+                        planes.append((row,))
+                        return a
+                    if attn == "mamba":
+                        with jax.named_scope("state_space"):
+                            a, *state = self._mamba(lp, u, n=n)
+                        planes.append(tuple(state))
+                        return a
+                    window = self._window if attn == "sliding_attention" \
+                        else 0
+                    with jax.named_scope("attention"):
+                        q, k, v = self._qkv(lp, u, positions, window)
+                        a = self._merge(lp, self._attend_sequence(
+                            q, k, v, window, 1.0 / math.sqrt(self._head_dim)))
+                    planes.append((k, v))
+                    return a
+
+                x = self._sub(lp, x, "attn", attention)
+                x = self._sub(lp, x, "ffn", lambda u: self._ffn(
+                    lp, u.reshape(b * t, -1))[0].reshape(b, t, -1))
+            return x, planes
+
+        x, planes = self._passes(p, stack, x, entry)
         return self._out(x), planes
+
+    def _passes(self, p, stack, x, entry):
+        """``stack(x, r)`` -> ``(x, out)``, the layers once with ``r`` the
+        pass: once (``r`` 0), or of a looped block ``passes`` times in a
+        program loop over the same weights, ``r`` traced, the final norm
+        at the end of every pass. Returns the last ``x`` and ``out``, of a
+        loop each of its arrays stacked over the passes."""
+        if self.passes == 1:
+            return stack(x, 0)
+        from ... import telemetry
+
+        telemetry.counter("mxtpu_loop_passes_total",
+                          "pass loops traced, by the entry point that "
+                          "traced them", entry=entry).inc()
+
+        def one(x, r):
+            with jax.named_scope("loop_pass"):
+                x, out = stack(x, r)
+                return rms_norm(x, p["final_norm"], self._eps), out
+
+        return jax.lax.scan(one, x, jnp.arange(self.passes, dtype=jnp.int32))
+
+    def _by_instance(self, arrays):
+        """One group's per-layer arrays, each stacked over the passes
+        (R, ...), as the cache holds them: (R x Lg, ...), pass by pass."""
+        a = jnp.stack(arrays, axis=1)
+        return a.reshape((-1,) + a.shape[2:])
 
     def _out(self, x):
         """The streams summed into the one the head reads."""
@@ -563,8 +652,11 @@ class HybridDecoder(HybridBlock):
             else x.astype(jnp.float32).sum(axis=0).astype(x.dtype)
 
     def _logits(self, p, x):
-        return _mm(rms_norm(x, p["final_norm"], self._eps),
-                   p["embed" if self._tied else "head"])
+        """The head over the last layer's output; a looped block's output
+        is normed at the end of its last pass already."""
+        if self.passes == 1:
+            x = rms_norm(x, p["final_norm"], self._eps)
+        return _mm(x, p["embed" if self._tied else "head"])
 
     def _run(self, fn, inputs, name):
         names, arrays = self._arrays()
@@ -585,12 +677,13 @@ class HybridDecoder(HybridBlock):
         is applied there and nowhere else) and, per cache group, its
         planes ``[Lg, H, T, W]`` of the whole bucket: K then V, or a
         latent group's one (positions from ``n`` on hold garbage that no
-        true position attended); a state group's ``[Lg, N, E]`` and
-        ``[Lg, K - 1, E]``, the state after position ``n - 1``."""
+        true position attended), of a looped block every pass's planes in
+        pass order; a state group's ``[Lg, N, E]`` and ``[Lg, K - 1, E]``,
+        the state after position ``n - 1``."""
         group_of, counts = self._group_of, self._group_counts
 
         def fn(p, tok, n_true):
-            x, planes = self._sequence(p, tok[None], n_true)
+            x, planes = self._sequence(p, tok[None], n_true, "prefill")
             last = jax.lax.dynamic_index_in_dim(x[0], n_true - 1, axis=0,
                                                 keepdims=False)
             out = [self._logits(p, last)]
@@ -599,6 +692,8 @@ class HybridDecoder(HybridBlock):
                     mine = [planes[i] for i, (gi, _) in enumerate(group_of)
                             if gi == g]
                     out += [jnp.stack([a[0] for a in tensor])
+                            if self.passes == 1 else
+                            self._by_instance([a[:, 0] for a in tensor])
                             for tensor in zip(*mine)]
             return tuple(out)
 
@@ -613,7 +708,10 @@ class HybridDecoder(HybridBlock):
         vector (over the slots whose ``cache_len`` is not 0: a free
         slot's is), and the caches with each slot's new row written
         (``kv_cache.address``), a state group's with every layer's new
-        state in the old one's place (``kv_cache.advance``)."""
+        state in the old one's place (``kv_cache.advance``). A looped
+        block's passes read the caches at their own indices inside the
+        loop and write nothing there: every pass's rows go in after it,
+        in one ``kv_cache.write`` a tensor."""
         group_of = self._group_of
         present = [g for g, c in enumerate(self._group_counts) if c]
 
@@ -627,63 +725,77 @@ class HybridDecoder(HybridBlock):
                   for g in rowed}
             x = self._embed(p, tok)[..., None, :]          # (S, 1, C)
             live = lens > 0
-            new = {g: tuple([] for _ in kv[g]) for g in rowed}
-            totals = dict.fromkeys(("routed_here", "experts_hit"), 0)
-            load_max, sparse = 0, 0
-            for i in range(len(self._kinds)):
-                lp = self._layer(p, i)
-                g, j = group_of[i]
+            # the routing's counts over the sparse layers (a loop has none)
+            totals = dict.fromkeys(("routed_here", "experts_hit",
+                                    "load_max", "sparse"), 0)
 
-                def attention(u):
-                    if g == _STATE:
-                        # the layer reads its own state of the arrays the
-                        # layer before it handed on, and replaces it there
-                        with jax.named_scope("state_space"):
-                            a, *state = self._mamba(
-                                lp, u, state=[c[j] for c in kv[g]])
-                            kv[g] = [kv_cache.advance(c, j, s)
-                                     for c, s in zip(kv[g], state)]
+            def stack(x, r):
+                """The layers once, pass ``r``: each reads the cache at its
+                own index of its group in that pass and hands out its new
+                rows, which nothing writes before the last pass."""
+                new = {g: tuple([] for _ in kv[g]) for g in rowed}
+                for i in range(len(self._kinds)):
+                    lp = self._layer(p, i)
+                    g, j = group_of[i]
+                    at_layer = j + r * self._group_counts[g]
+
+                    def attention(u):
+                        if g == _STATE:
+                            # the layer reads its own state of the arrays
+                            # the layer before it handed on, and replaces
+                            # it there
+                            with jax.named_scope("state_space"):
+                                a, *state = self._mamba(
+                                    lp, u, state=[c[j] for c in kv[g]])
+                                kv[g] = [kv_cache.advance(c, j, s)
+                                         for c, s in zip(kv[g], state)]
+                            return a
+                        if g == _LATENT:
+                            with jax.named_scope("latent_attention"):
+                                a, *rows = self._latent_absorbed(
+                                    lp, u, lens, kv[g][0], at_layer, at[g])
+                        else:
+                            with jax.named_scope("attention"):
+                                q, *rows = self._qkv(
+                                    lp, u, lens[:, None],
+                                    self._window * (g == _RING))
+                                a = kv_cache.attend_row(
+                                    q[:, :, :, 0], *kv[g], at_layer, *rows,
+                                    lens, at[g], _KINDS[g], self._head_dim)
+                                a = self._merge(lp, a[:, :, :, None])
+                        for rows_new, row in zip(new[g], rows):
+                            rows_new.append(row)
                         return a
-                    if g == _LATENT:
-                        with jax.named_scope("latent_attention"):
-                            a, *rows = self._latent_absorbed(
-                                lp, u, lens, kv[g][0], j, at[g])
-                    else:
-                        with jax.named_scope("attention"):
-                            q, *rows = self._qkv(
-                                lp, u, lens[:, None],
-                                self._window * (g == _RING))
-                            a = kv_cache.attend_row(
-                                q[:, :, :, 0], *kv[g], j, *rows, lens,
-                                at[g], _KINDS[g], self._head_dim)
-                            a = self._merge(lp, a[:, :, :, None])
-                    for rows_new, row in zip(new[g], rows):
-                        rows_new.append(row)
-                    return a
 
-                counts = []
+                    counts = []
 
-                def ffn(u):
-                    y, c = self._ffn(lp, u[:, 0], live=live)
-                    counts.append(c)
-                    return y[:, None]
+                    def ffn(u):
+                        y, c = self._ffn(lp, u[:, 0], live=live)
+                        counts.append(c)
+                        return y[:, None]
 
-                x = self._sub(lp, x, "attn", attention)
-                x = self._sub(lp, x, "ffn", ffn)
-                if counts[0] is not None:
-                    sparse += 1
-                    for name in totals:
-                        totals[name] = totals[name] + counts[0][name]
-                    load_max = jnp.maximum(load_max, counts[0]["load_max"])
+                    x = self._sub(lp, x, "attn", attention)
+                    x = self._sub(lp, x, "ffn", ffn)
+                    if counts[0] is not None:
+                        totals["sparse"] += 1
+                        for name in ("routed_here", "experts_hit"):
+                            totals[name] = totals[name] + counts[0][name]
+                        totals["load_max"] = jnp.maximum(
+                            totals["load_max"], counts[0]["load_max"])
+                return x, new
+
+            x, new = self._passes(p, stack, x, "step")
             logits = self._logits(p, self._out(x)[:, 0])
             counters = jnp.stack([jnp.asarray(v, jnp.int32) for v in (
                 totals["routed_here"],
-                live.sum() * self._top_k * sparse,
-                totals["experts_hit"], load_max)])
+                live.sum() * self._top_k * totals["sparse"],
+                totals["experts_hit"], totals["load_max"])])
             out = [logits, counters]
             for g in present:
                 out += kv[g] if g == _STATE else [
-                    kv_cache.write(cache, rows_new, at[g][0])
+                    kv_cache.write(cache, rows_new if self.passes == 1
+                                   else self._by_instance(rows_new),
+                                   at[g][0])
                     for cache, rows_new in zip(kv[g], new[g])]
             return tuple(out)
 
@@ -757,6 +869,21 @@ _SPECS = {
         tie_embeddings=True,
         mamba=dict(expand=2, d_state=8, dt_rank=4, d_conv=4),
         max_length=128),
+    # https://huggingface.co/ByteDance/Ouro-2.6B/blob/main/config.json
+    # (``ouro``): 48 full-attention layers of 16 heads of 128 (as many K/V
+    # heads), rotary positions at theta 1e6 on every layer, no per-head
+    # norm, sandwich norms, every FFN dense 5632, an untied head; the
+    # stack run ``total_ut_steps`` 4 times a token
+    "ouro_2p6b": dict(
+        vocab_size=49152, units=2048, num_heads=16, num_kv_heads=16,
+        head_dim=128, hidden_size=5632, rope_theta=1e6, eps=1e-6,
+        num_layers=48, pattern="G", dense_layers=48, qk_norm=False,
+        sandwich=True, rope_full=True, passes=4, max_length=65536),
+    "ouro_tiny": dict(
+        vocab_size=97, units=64, num_heads=4, num_kv_heads=4, head_dim=16,
+        hidden_size=96, rope_theta=1e6, eps=1e-6, num_layers=3,
+        pattern="G", dense_layers=3, qk_norm=False, sandwich=True,
+        rope_full=True, passes=4, max_length=128),
 }
 _PATTERN = {"G": "full_attention", "L": "sliding_attention",
             "M": "latent_attention"}

@@ -209,10 +209,11 @@ def attend_blocks(q, k_cache, v_cache, layer, k_new, v_new, cache_len,
 def attend_row(q, k_cache, v_cache, layer, k_new, v_new, cache_len, at,
                kind, head_dim, scale=None, v_width=None):
     """Layer ``layer``'s one-token attention over the stacked caches
-    (L, S, H, rows, W) with the new token's rows ``k_new``/``v_new``
-    (S, H, 1, W) where ``at`` (the group's ``address`` of ``cache_len``)
-    puts them: what writing the rows and then ``attend`` over the plane
-    gives, to float tolerance, without the write. ``q`` as ``attend``
+    (L, S, H, rows, W) (``layer`` a Python int, or a traced scalar inside
+    a looped block's pass loop) with the new token's rows
+    ``k_new``/``v_new`` (S, H, 1, W) where ``at`` (the group's ``address``
+    of ``cache_len``) puts them: what writing the rows and then ``attend``
+    over the plane gives, to float tolerance, without the write. ``q`` as ``attend``
     takes it. A ``latent`` group has no ``v_cache``/``v_new`` (None): the
     values are the first ``v_width`` lanes of the keys, and ``head_dim``
     is the row's whole width (a row is one head). ``read`` + ``attend``
@@ -242,14 +243,16 @@ def attend_row(q, k_cache, v_cache, layer, k_new, v_new, cache_len, at,
 
 
 def write(cache, new, row):
-    """All layers' ``new`` rows (``L`` of (S, H, 1, W)) into ``cache``
-    (L, S, H, rows, W) at ``row`` (S,): one ``dynamic_update_slice`` a
+    """All layers' ``new`` rows (``L`` of (S, H, 1, W), or one array
+    (L, S, H, 1, W)) into ``cache`` (L, S, H, rows, W) at ``row`` (S,):
+    one ``dynamic_update_slice`` a
     slot, slot static, chained on the whole cache. They are the cache's
     only writers in a step, so nothing of its or a plane's shape is built
     beside it. (On the v5e an update's time goes by tiles touched, not by
     calls; a scatter, which a vmapped update also lowers to, makes the TPU
     compiler relayout its whole operand: PERF.md PR 26.)"""
-    u = jnp.stack(new, axis=0)                          # (L, S, H, 1, W)
+    u = jnp.stack(new, axis=0) if isinstance(new, (list, tuple)) \
+        else new                                        # (L, S, H, 1, W)
     for s in range(cache.shape[1]):
         cache = jax.lax.dynamic_update_slice(cache, u[:, s:s + 1],
                                              (0, s, 0, row[s], 0))

@@ -491,6 +491,15 @@ class DecodeSession:
         self._kv = KVCache(block.cache_groups(self.max_len), max_slots,
                            dtype=dtype)
         self._counters = tuple(block.step_counters)
+        # what else a block may declare of its step, for the ledger: the
+        # times it runs its stack a token (written where more than one)
+        # and the parameter bytes a step reads
+        passes = int(getattr(block, "passes", 1))
+        self._prefill_fields = {"passes": passes} if passes > 1 else {}
+        weight = getattr(block, "step_weight_bytes", None)
+        weight = weight(self.max_slots) if weight else None
+        self._step_fields = dict(self._prefill_fields, **(
+            {} if weight is None else {"weight_bytes": int(weight)}))
         self.metrics = DecodeMetrics(self.name)
         self.metrics.set_capacity(max_slots, self._kv.nbytes)
         self._site = f"decode.{self.name}"
@@ -1085,7 +1094,7 @@ class DecodeSession:
         queue_wait = st.t_admitted - req.t_submit
         self.metrics.observe_admit(queue_wait, dt)
         turn.close(t0, dt, kind="prefill", bucket=bucket, prompt_len=n,
-                   queue_wait_s=queue_wait)
+                   queue_wait_s=queue_wait, **self._prefill_fields)
         self.metrics.observe_first_token(now - req.t_submit)
         if root is not None:
             # the measured TTFT on the SAME perf clock the segments use
@@ -1261,6 +1270,7 @@ class DecodeSession:
         state = {"state_bytes": self._kv.state_bytes(active)} \
             if "state" in self._kv.kinds else {}
         flight.turn.close(t0, t1 - t0, active=active, **state,
+                          **self._step_fields,
                           kv_live_rows=self._kv.live_rows(lens + 1),
                           kv_read_rows=read_rows, kv_read_bytes=read_bytes,
                           kv_rows=self._kv.rows,

@@ -450,3 +450,67 @@ def test_state_space_decoder_step_replaces_its_state_in_place_published_widths(
     assert layouts == ["3,2,1,0"], layouts
     assert beside == {"fusion": 3}, beside
     assert _kernel_calls(text) == 1
+
+
+def test_looped_decoder_step_attends_inside_its_pass_loop_published_widths(
+        one_chip):
+    """The data-built decoder's served decode step at Ouro-2.6B's widths
+    (hidden 2048, 16 heads of 128 over as many K/V heads, sandwich norms,
+    rotary on every layer; two layers of the 48, the vocabulary cut so
+    that the weights are a test's) run 4 passes a token, 8 slots of 512
+    positions, donated as ``DecodeSession`` lowers it. The passes are one
+    program loop (a ``while``) whose body holds the block kernel once a
+    layer, handed the whole stacked caches of the 4 x 2 (pass, layer)
+    instances and a traced layer index; both caches alias their outputs,
+    and outside fusions the only ops of the cache's shape are the
+    in-place ``dynamic-update-slice`` of every pass's rows after the loop:
+    no copy of the cache beside the one write, and temp memory under a
+    tenth of it."""
+    from incubator_mxnet_tpu import serving
+    from incubator_mxnet_tpu.gluon.model_zoo import get_decoder
+
+    layers, passes, slots, t = 2, 4, 8, 512
+    net = get_decoder("ouro_2p6b", num_layers=layers, dense_layers=layers,
+                      vocab_size=4096, max_length=t)
+    net.cast("bfloat16")
+    net.initialize(init="zeros")
+    with serving.DecodeSession(net, max_slots=slots, max_len=t,
+                               prefill_buckets=(128,), name="ouro2",
+                               donate=True, artifact_dir="") as sess:
+        shape = (passes * layers, slots, 16, t, 128)
+        assert sess._kv.shapes == [shape]
+        caches = [_spec(one_chip, shape)] * 2
+        vec = _spec(one_chip, (slots,), jnp.int32)
+        params = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
+        lowered = jax.jit(sess._decode_apply, donate_argnums=(1, 2)).lower(
+            params, *caches, vec, vec)
+        compiled = lowered.compile()
+        kv_bytes = sess._kv.nbytes
+    # one loop, in the program and in what the compiler made of it, whose
+    # body calls the kernel once a layer
+    program = lowered.as_text()
+    assert program.count("stablehlo.while") == 1
+    assert len(re.findall(r"custom_call @tpu_custom_call[^\n]*"
+                          r"kv_decode_attention", program)) == layers
+    n = len(params)
+    text = compiled.as_text()
+    assert len(re.findall(r"\) while\(", text)) == 1
+    assert _kernel_calls(text) == layers
+    alias = re.search(r"input_output_alias=\{[^\n]*?\}, entry", text)
+    assert alias and all(f"{{{j + 1}}}: ({n + j}, {{}}" in alias.group(0)
+                         for j in range(2)), "a cache array is not aliased"
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= kv_bytes
+    assert mem.temp_size_in_bytes < kv_bytes // 10
+    whole = "bf16[%d,%d,16,%d,128]{" % (passes * layers, slots, t)
+    beside, fused = {}, False
+    for line in text.splitlines():
+        if line and not line.startswith((" ", "}")):
+            fused = not line.startswith("ENTRY")   # only the entry's ops
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if fused or not m or not m.group(1).startswith(whole):
+            continue
+        if m.group(2) not in ("parameter", "bitcast", "get-tuple-element"):
+            beside[m.group(2)] = beside.get(m.group(2), 0) + 1
+    assert beside == {"dynamic-update-slice": 2 * slots}, beside
