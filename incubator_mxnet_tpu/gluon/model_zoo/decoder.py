@@ -67,6 +67,7 @@ What the block declares to be served by ``serving.DecodeSession``
   read, the one-token attention, the writes and how a state advances are
   ``ops/kv_cache.py``'s;
 * ``step_counters``: the integers a step returns beside the logits;
+* ``row_tables``: the parameters read by rows, the embedding table;
 * ``passes`` and ``step_weight_bytes(slots)``: how many times a step runs
   the stack, and the parameter bytes one step reads.
 
@@ -179,6 +180,7 @@ class HybridDecoder(HybridBlock):
 
     step_counters = ("routed_here", "routed_all", "experts_hit",
                      "expert_load_max")
+    row_tables = ("embed",)
 
     def __init__(self, vocab_size, units, num_heads, num_kv_heads, head_dim,
                  layer_types, mlp_layer_types, hidden_size, window=128,

@@ -231,6 +231,11 @@ class GPTDecoder(HybridBlock):
     #: integers a decode step returns beside the logits: none here
     step_counters = ()
 
+    #: parameters read by rows (a gather): a served session holds them
+    #: row-major where the device's default layout is not (GPT-2 XL's
+    #: 1600-wide tables on a TPU)
+    row_tables = ("word_embed.weight", "position_embed.weight")
+
     def cache_groups(self, max_len):
         """The K/V cache this block is served with (docs/SERVING.md "What
         a block declares"): one group, every layer ``max_len`` rows, in

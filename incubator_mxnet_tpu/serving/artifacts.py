@@ -75,14 +75,19 @@ def environment_fingerprint() -> Dict[str, Any]:
 
 def params_fingerprint(params) -> str:
     """Structural fingerprint of a parameter list: ordered shapes +
-    dtypes. Identifies the *program signature*, not the weight values —
-    a hot weight swap keeps the fingerprint (and the executables); an
-    architecture change breaks it. Callers whose architectures can
-    collide on param specs disambiguate with a ``model_version`` tag."""
+    dtypes + the order of each device array's layout (an executable
+    lowered for a row-major table is no executable for the default
+    column-major one). Identifies the *program signature*, not the
+    weight values — a hot weight swap keeps the fingerprint (and the
+    executables); an architecture change breaks it. Callers whose
+    architectures can collide on param specs disambiguate with a
+    ``model_version`` tag."""
     h = hashlib.sha256()
     for p in params:
         h.update(repr(tuple(int(d) for d in p.shape)).encode())
         h.update(str(getattr(p.dtype, "name", p.dtype)).encode())
+        layout = getattr(getattr(p, "format", None), "layout", None)
+        h.update(repr(getattr(layout, "major_to_minor", None)).encode())
     return h.hexdigest()[:16]
 
 

@@ -64,7 +64,7 @@ from .artifacts import (ArtifactStore, environment_fingerprint,
 from .batcher import (DeadlineExceededError, QueueFullError,
                       ServerClosedError)
 from .executor_cache import (BucketedExecutorCache,
-                             pure_method_runner)
+                             pure_method_runner, row_table_formats)
 from .metrics import DecodeMetrics, ServingMetrics
 
 __all__ = ["DecodeHandle", "DecodeSession", "KVCache"]
@@ -414,7 +414,8 @@ class DecodeSession:
     """Continuous-batching autoregressive serving over one decoder block.
 
     ``block`` is a gluon block that declares what it is served with
-    (``cache_groups``/``serve_prefill``/``serve_step``/``step_counters``:
+    (``cache_groups``/``serve_prefill``/``serve_step``/``step_counters``,
+    and ``row_tables`` where it reads a parameter by rows:
     docs/SERVING.md "What a block declares";
     :class:`~..gluon.model_zoo.gpt.GPTDecoder` and
     :class:`~..gluon.model_zoo.decoder.HybridDecoder` do), parameters
@@ -467,7 +468,7 @@ class DecodeSession:
             donate = jax.default_backend() != "cpu"
         self._donate = bool(donate)
 
-        self._run, self._params = pure_method_runner(block)
+        self._run, params = pure_method_runner(block)
         self._block = block
         buckets = tuple(prefill_buckets) if prefill_buckets is not None \
             else default_prefill_buckets(self.max_len)
@@ -475,17 +476,28 @@ class DecodeSession:
         if bad:
             raise ValueError(f"prefill buckets {bad} exceed max_len="
                              f"{self.max_len}")
-        self._prefill = BucketedExecutorCache(
-            self._prefill_apply, self._params, buckets=buckets,
-            donate=donate, name=f"{self.name}.prefill",
-            metrics=ServingMetrics(f"{self.name}.prefill"),
-            pass_count=True, depad=False, artifact_dir=artifact_dir,
-            model_version=model_version)
         # same collect_params walk the param values were zipped from
         # (pure_method_runner exports it) — the hot-swap name→position
         # mapping must never come from a second traversal
         self._param_names = list(self._run.param_names)
+        # the tables the block reads by rows are held row-major where the
+        # device's default layout is not; the prefill cache places every
+        # parameter once, and its list is the one every executable takes
+        formats = row_table_formats(params, self._param_names,
+                                    getattr(block, "row_tables", ()))
+        self._prefill = BucketedExecutorCache(
+            self._prefill_apply, params, buckets=buckets,
+            donate=donate, name=f"{self.name}.prefill",
+            metrics=ServingMetrics(f"{self.name}.prefill"),
+            pass_count=True, depad=False, artifact_dir=artifact_dir,
+            model_version=model_version, formats=formats)
         self._prefill.param_names = self._param_names
+        self._params = self._prefill._params
+        telemetry.counter(
+            "mxtpu_serving_row_tables_relaid_total",
+            "parameters a session holds in a layout other than the "
+            "device's default", session=self.name).inc(
+            sum(f is not None for f in formats))
 
         dtype = self._params[0].dtype
         self._kv = KVCache(block.cache_groups(self.max_len), max_slots,
@@ -678,15 +690,14 @@ class DecodeSession:
     def _lower_decode(self):
         """The decode step lowered for this session's shapes: every
         cache array donated (where the session donates), so the compiled
-        program's output caches alias its inputs."""
+        program's output caches alias its inputs; every parameter in the
+        format it is held in, as the prefill buckets take it."""
         caches = self._kv.specs()
         vec = jax.ShapeDtypeStruct((self.max_slots,), jnp.int32)
         jitted = jax.jit(self._decode_apply,
                          donate_argnums=tuple(range(1, 1 + len(caches)))
                          if self._donate else ())
-        p_specs = [jax.ShapeDtypeStruct(p.shape, p.dtype)
-                   for p in self._params]
-        return jitted.lower(p_specs, *caches, vec, vec)
+        return jitted.lower(self._prefill.param_specs(), *caches, vec, vec)
 
     def _decode_exec(self):
         """THE decode executable — built once (deserialized where a
@@ -809,7 +820,8 @@ class DecodeSession:
             t0 = time.perf_counter()
             staged = _stage_publish(self._params, self._param_digests,
                                     self._param_names, source,
-                                    allow_partial, self.name)
+                                    allow_partial, self.name,
+                                    formats=self._prefill.formats)
             version = _resolve_version(self._weights_version, version)
             applied = threading.Event()
             swap = {"staged": staged, "version": version,

@@ -12,9 +12,12 @@ re-traced on the hot path.
 Parameters are placed on device once at construction and stay resident;
 every call moves only the request bytes (the Python twin of the C++
 ``Predictor`` residency fix, and TF-Serving's loaded-servable design,
-arXiv:1605.08695). On non-CPU backends the padded input buffer is
-donated to the executable so steady-state serving does not hold two
-copies of the batch in HBM.
+arXiv:1605.08695). Each is held in a FORMAT (``formats``: the device's
+default layout, or the one :func:`row_table_formats` gives a table read
+by rows), and every executable over them is lowered with the same, so no
+call relayouts a parameter on entry. On non-CPU backends the padded
+input buffer is donated to the executable so steady-state serving does
+not hold two copies of the batch in HBM.
 """
 
 from __future__ import annotations
@@ -28,6 +31,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from .. import profiler
 from .. import telemetry
@@ -59,6 +63,72 @@ def warmup_thread_count(threads: Optional[int], n_tasks: int) -> int:
     return max(1, min(int(threads), int(n_tasks)))
 
 
+def _default_order(p) -> Tuple[int, ...]:
+    """``major_to_minor`` of the layout ``p``'s device gives its shape and
+    type by default."""
+    device = next(iter(p.sharding.device_set))
+    layout = device.client.get_default_layout(
+        jnp.dtype(p.dtype), p.sharding.shard_shape(tuple(p.shape)), device)
+    return Layout.from_pjrt_layout(layout).major_to_minor
+
+
+def row_table_formats(params: Sequence[Any], names: Sequence[str],
+                      tables: Sequence[str]) -> List[Optional[Format]]:
+    """The format each of ``params`` is held and lowered in: ``None``, the
+    device's default, but for a parameter ``tables`` names (a block's
+    ``row_tables``: read by rows, a gather) whose default layout is not
+    row-major, which is held row-major. A gather needs a table's rows
+    contiguous: over a TPU's default layout for ``bf16[50257, 1600]``
+    (1600 no multiple of 128: the row index minor) every call would copy
+    the whole table first. ``params`` are arrays, or
+    ``jax.ShapeDtypeStruct`` with a sharding (a described device's, for a
+    compile without the chip)."""
+    index = {n: i for i, n in enumerate(names)}
+    unknown = sorted(set(tables) - set(index))
+    if unknown:
+        raise ValueError(f"row tables {unknown} are not parameters of the "
+                         f"block")
+    formats: List[Optional[Format]] = [None] * len(params)
+    for name in tables:
+        p = params[index[name]]
+        rows = tuple(range(len(p.shape)))
+        if _default_order(p) != rows:
+            formats[index[name]] = Format(Layout(rows), p.sharding)
+    return formats
+
+
+def _nothing() -> None:
+    pass
+
+
+def _hold(value):
+    # The host callback keeps this computation out of JAX's persistent
+    # compilation cache (such an executable is never written there, nor
+    # read): one restored from that cache labels its outputs with the
+    # device's default layout whatever order it writes them in, and a
+    # table placed through it would reach the executables mislabelled.
+    jax.debug.callback(_nothing)
+    return value
+
+
+def place(value, fmt: Optional[Format] = None):
+    """``value`` on the device in ``fmt``, or in the device's default
+    layout where ``fmt`` is None: how a served parameter is placed, at
+    construction and at every weight swap."""
+    if fmt is None:
+        return jax.device_put(jnp.asarray(value))
+    return jax.jit(_hold, out_shardings=fmt)(jnp.asarray(value))
+
+
+def param_specs(params: Sequence[Any],
+                formats: Sequence[Optional[Format]]) -> list:
+    """``jax.ShapeDtypeStruct`` of each parameter as an executable over
+    them is lowered: its format where it has one, else no sharding (the
+    device's default layout)."""
+    return [jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=f)
+            for p, f in zip(params, formats)]
+
+
 def _digest(arr: np.ndarray) -> str:
     """Content digest of one parameter value (the zero-copy aliasing
     test for weight hot-swap: equal digest => reuse the resident device
@@ -86,7 +156,9 @@ class _StagedSwap:
 def stage_weight_swap(params: List[Any], digests: Optional[List[str]],
                       param_names: Optional[List[str]], new,
                       allow_partial: bool = True,
-                      model: str = "model") -> _StagedSwap:
+                      model: str = "model",
+                      formats: Optional[Sequence[Optional[Format]]] = None
+                      ) -> _StagedSwap:
     """Stage a new weight version against a live parameter list — the
     aliasing core shared by :class:`BucketedExecutorCache` and the
     decode session. ``new`` is a ``{structural_name: array}`` dict
@@ -94,7 +166,8 @@ def stage_weight_swap(params: List[Any], digests: Optional[List[str]],
     dtypes must match (the AOT executables are signature-frozen).
     Unchanged values (by content digest) alias the RESIDENT device
     buffer — zero-copy across versions; changed ones are device_put
-    here, off the hot path, so the commit is a pure pointer flip."""
+    here, off the hot path, in the live one's format (``formats``), so
+    the commit is a pure pointer flip."""
     if isinstance(new, dict):
         if param_names is None:
             raise ValueError(
@@ -141,7 +214,7 @@ def stage_weight_swap(params: List[Any], digests: Optional[List[str]],
         if d == digests[i]:
             aliased += 1              # zero-copy: keep the device buffer
             continue
-        cur[i] = jax.device_put(jnp.asarray(arr))
+        cur[i] = place(arr, formats[i] if formats else None)
         digests[i] = d
         updated += 1
     stats = {"params": len(cur), "aliased": aliased, "updated": updated,
@@ -239,6 +312,11 @@ class BucketedExecutorCache:
       produced them (bucket-padded); callers that consume whole padded
       planes (a KV-cache block write) or non-batch-leading outputs
       slice for themselves.
+
+    ``formats`` (one per parameter, ``None`` for the device's default) is
+    the format each parameter is held in, at construction and through
+    every weight swap, and every executable is lowered with
+    (:meth:`param_specs`); a decode session shares the same list.
     """
 
     def __init__(self, apply_fn: Callable, params: Sequence[Any],
@@ -248,15 +326,18 @@ class BucketedExecutorCache:
                  name: str = "model", pass_count: bool = False,
                  depad: bool = True,
                  artifact_dir: Optional[str] = None,
-                 model_version: str = ""):
+                 model_version: str = "",
+                 formats: Optional[Sequence[Optional[Format]]] = None):
         self.name = name
         self.buckets = tuple(sorted({int(b) for b in buckets}))
         if not self.buckets or self.buckets[0] < 1:
             raise ValueError(f"buckets must be positive ints, got {buckets}")
         self._apply = apply_fn
-        # residency: one device_put at construction; executions reference
+        self.formats = list(formats) if formats is not None \
+            else [None] * len(params)
+        # residency: one placement at construction; executions reference
         # these arrays, no per-call host-to-device parameter traffic
-        self._params = [jax.device_put(jnp.asarray(p)) for p in params]
+        self._params = [place(p, f) for p, f in zip(params, self.formats)]
         if donate is None:
             # XLA ignores donation on CPU (and warns); only donate where
             # the runtime can actually alias the buffer
@@ -388,8 +469,7 @@ class BucketedExecutorCache:
             jitted = jax.jit(
                 self._apply,
                 donate_argnums=(1,) if self._donate else ())
-            p_specs = [jax.ShapeDtypeStruct(p.shape, p.dtype)
-                       for p in self._params]
+            p_specs = self.param_specs()
             x_spec = jax.ShapeDtypeStruct((bucket,) + key[1],
                                           jnp.dtype(dtype_name))
             if self._pass_count:
@@ -509,7 +589,7 @@ class BucketedExecutorCache:
         return stage_weight_swap(self._params, self._digests,
                                  self.param_names, new,
                                  allow_partial=allow_partial,
-                                 model=self.name)
+                                 model=self.name, formats=self.formats)
 
     def commit_params(self, staged: _StagedSwap) -> Dict[str, int]:
         """Flip the staged version live: one atomic assignment. A batch
@@ -525,6 +605,11 @@ class BucketedExecutorCache:
     def swap_params(self, new, allow_partial: bool = True) -> Dict[str, int]:
         """``commit_params(stage_params(new))`` — the one-call form."""
         return self.commit_params(self.stage_params(new, allow_partial))
+
+    def param_specs(self) -> list:
+        """The parameters as every executable over them is lowered, each
+        in the format it is held in (:func:`param_specs`)."""
+        return param_specs(self._params, self.formats)
 
     def param_bytes(self) -> int:
         """Device bytes held by the resident parameters (the registry's

@@ -140,11 +140,12 @@ def load_weight_arrays(source, names=None) -> dict:
 
 
 def _stage_publish(params, digests, param_names, source,
-                   allow_partial: bool, model: str):
+                   allow_partial: bool, model: str, formats=None):
     """The shared first half of every weight publish: resolve the
     source to arrays, drop checkpoint tensors the serving graph does
     not consume (an explicit dict publish keeps unknown keys so staging
-    rejects typos loudly), and stage the swap — all off the hot path."""
+    rejects typos loudly), and stage the swap — all off the hot path,
+    each changed parameter placed in its live one's format."""
     from .executor_cache import stage_weight_swap
 
     names = set(param_names or []) or None
@@ -162,7 +163,8 @@ def _stage_publish(params, digests, param_names, source,
                 f"{model}'s served parameter names "
                 f"(e.g. {sorted(names)[:3]}); wrong checkpoint?")
     return stage_weight_swap(params, digests, param_names, arrays,
-                             allow_partial=allow_partial, model=model)
+                             allow_partial=allow_partial, model=model,
+                             formats=formats)
 
 
 def _resolve_version(base, version):

@@ -20,6 +20,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.layout import Layout
 from jax.sharding import SingleDeviceSharding
 
 from incubator_mxnet_tpu.ops.pallas_attention import _flash_core
@@ -149,6 +150,80 @@ def _kernel_calls(text):
                           text))
 
 
+def _served_params(one_chip, sess, block):
+    """The session's parameters as it lowers its executables on the
+    described chip: each in the format it would hold it in there (a
+    table the block reads by rows row-major where the chip's default
+    layout for its shape is not)."""
+    from incubator_mxnet_tpu.serving.executor_cache import (
+        param_specs, row_table_formats)
+
+    on_chip = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
+    formats = row_table_formats(on_chip, sess._param_names,
+                                block.row_tables)
+    return param_specs(on_chip, formats), formats
+
+
+def _entry_copies_of_parameters(text):
+    """Ops of the entry computation that copy (relayout) a parameter that
+    is an array (a scalar's copy into the chip's scalar memory is none)."""
+    params, copies, entry = set(), [], False
+    for line in text.splitlines():
+        if line and not line.startswith((" ", "}")):
+            entry = line.startswith("ENTRY")
+            continue
+        if not entry:
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = \w+\[(\d[^\]]*)\]\S* "
+                     r"(parameter|copy)\(%?([\w.\-]*)", line)
+        if m and m.group(3) == "parameter":
+            params.add(m.group(1))
+        elif m and m.group(4) in params:
+            copies.append(line.strip()[:120])
+    return copies
+
+
+def _orders(one_chip, compiled, params):
+    """Per parameter: the order the executable takes it in, and the one
+    the described chip's default layout gives its shape and type."""
+    (device,) = one_chip.device_set
+    return [(f.layout.major_to_minor, Layout.from_pjrt_layout(
+        device.client.get_default_layout(s.dtype, s.shape, device))
+        .major_to_minor)
+        for f, s in zip(compiled.input_formats[0][0], params)]
+
+
+def _xl_net(layers=2, t=1024):
+    from incubator_mxnet_tpu.gluon.model_zoo import get_gpt
+
+    net = get_gpt("gpt_decoder_345m", num_layers=layers, units=1600,
+                  num_heads=25, vocab_size=50257, max_length=t, dropout=0.0)
+    net.cast("bfloat16")
+    net.initialize()
+    return net
+
+
+def _xl_decode_step(one_chip, layers, slots, t):
+    """GPT-2 XL's served decode step (``layers`` of the 48 at its widths,
+    ``slots`` of ``t`` positions) compiled for the described chip as
+    ``DecodeSession`` lowers it: the caches donated, the parameters in
+    the formats the session holds them in there. Returns the executable,
+    the parameter specs, their formats and the cache's bytes."""
+    from incubator_mxnet_tpu import serving
+
+    net = _xl_net(layers, t)
+    with serving.DecodeSession(net, max_slots=slots, max_len=t,
+                               prefill_buckets=(256,), name="xl2",
+                               donate=True, artifact_dir="") as sess:
+        assert sess._kv.shape == (layers, slots, 13, t, 128)
+        cache = _spec(one_chip, sess._kv.shape, sess._kv.dtype)
+        vec = _spec(one_chip, (slots,), jnp.int32)
+        params, formats = _served_params(one_chip, sess, net)
+        compiled = jax.jit(sess._decode_apply, donate_argnums=(1, 2)).lower(
+            params, cache, cache, vec, vec).compile()
+        return compiled, params, formats, sess._kv.nbytes
+
+
 def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
     """The served decode step at GPT-2 XL's widths (two layers of the
     48), 16 slots of 1024 positions, donated as ``DecodeSession`` lowers
@@ -163,30 +238,15 @@ def test_decode_step_updates_the_cache_in_place_gpt2_xl_widths(one_chip):
     ``dynamic-update-slice`` of the new rows — no copy, no relayout, no
     concatenate (a scatter in their place makes this compiler relayout
     the whole cache around it)."""
-    from incubator_mxnet_tpu import serving
-    from incubator_mxnet_tpu.gluon.model_zoo import get_gpt
-
-    layers, slots, heads, t, d = 2, 16, 25, 1024, 64
-    net = get_gpt("gpt_decoder_345m", num_layers=layers, units=heads * d,
-                  num_heads=heads, vocab_size=50257, max_length=t,
-                  dropout=0.0)
-    net.cast("bfloat16")
-    net.initialize()
-    with serving.DecodeSession(net, max_slots=slots, max_len=t,
-                               prefill_buckets=(256,), name="xl2",
-                               donate=True, artifact_dir="") as sess:
-        assert sess._kv.shape == (layers, slots, 13, t, 128)
-        cache = _spec(one_chip, sess._kv.shape, sess._kv.dtype)
-        vec = _spec(one_chip, (slots,), jnp.int32)
-        params = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
-        compiled = jax.jit(sess._decode_apply, donate_argnums=(1, 2)).lower(
-            params, cache, cache, vec, vec).compile()
+    layers, slots, t = 2, 16, 1024
+    compiled, params, _, kv_nbytes = _xl_decode_step(one_chip, layers,
+                                                     slots, t)
     n = len(params)
     text = compiled.as_text()
     alias = re.search(r"input_output_alias=\{[^\n]*?\}, entry", text)
     assert alias and f"{{1}}: ({n}, {{}}" in alias.group(0) \
         and f"{{2}}: ({n + 1}, {{}}" in alias.group(0), "caches not aliased"
-    assert compiled.memory_analysis().alias_size_in_bytes >= sess._kv.nbytes
+    assert compiled.memory_analysis().alias_size_in_bytes >= kv_nbytes
     big = re.compile(r"bf16\[(%d,)?(1,)?%d,13,%d,128\]" % (layers, slots, t))
     whole = "bf16[%d,%d,13,%d,128]{" % (layers, slots, t)
     beside, layouts, fused = {}, [], False
@@ -216,20 +276,18 @@ def test_prefill_attends_through_the_forward_kernel_gpt2_xl_widths(
     the optimised program holds no score-shaped tensor (the parent wrote
     and re-read ``bf16[25,1024,1024]`` at least twice a layer); the 512
     bucket, whose scores the compiler keeps on the chip, is the
-    parent's dense chain."""
+    parent's dense chain. Neither copies a parameter on entry: the two
+    tables it gathers rows of come in row-major (taken in the chip's
+    default layout, both are relaid every call, ``copy(pvals_0)`` of
+    ``bf16[50257,1600]``)."""
     from incubator_mxnet_tpu import serving
-    from incubator_mxnet_tpu.gluon.model_zoo import get_gpt
 
-    layers, heads, d = 2, 25, 64
-    net = get_gpt("gpt_decoder_345m", num_layers=layers, units=heads * d,
-                  num_heads=heads, vocab_size=50257, max_length=1024,
-                  dropout=0.0)
-    net.cast("bfloat16")
-    net.initialize()
+    heads = 25
+    net = _xl_net()
     with serving.DecodeSession(net, max_slots=16, max_len=1024,
                                prefill_buckets=(bucket,), name="xl2",
                                donate=True, artifact_dir="") as sess:
-        params = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
+        params, formats = _served_params(one_chip, sess, net)
         compiled = jax.jit(sess._prefill_apply).lower(
             params, _spec(one_chip, (bucket,), jnp.int32),
             _spec(one_chip, (), jnp.int32)).compile()
@@ -238,6 +296,58 @@ def test_prefill_attends_through_the_forward_kernel_gpt2_xl_widths(
     scores = re.findall(r"\[(?:1,)?%d,%d,%d\]" % (heads, bucket, bucket),
                         text)
     assert bool(scores) == (kernels == 0), scores[:3]
+    assert _entry_copies_of_parameters(text) == []
+    assert [order for (order, _), f in zip(
+        _orders(one_chip, compiled, params), formats) if f] == [(0, 1)] * 2
+
+
+def test_decode_step_reads_its_tables_where_they_lie_gpt2_xl_widths(
+        one_chip):
+    """GPT-2 XL's served decode step (two layers of the 48, 16 slots of
+    1024, as ``DecodeSession`` lowers it for the chip): the word and
+    position tables, 1600 wide, which the chip's default layout holds
+    column-major (the row index minor), are held and taken row-major, so
+    the step gathers its rows where they lie: no op of the entry copies a
+    parameter (taken in the default layout, ``bf16[50257,1600]`` and
+    ``bf16[1024,1600]`` are relaid every step, 169 MB of temp memory);
+    every other parameter keeps the chip's default layout."""
+    compiled, params, formats, _ = _xl_decode_step(one_chip, 2, 16, 1024)
+    text = compiled.as_text()
+    assert not re.findall(r"= bf16\[(?:50257|1024),1600\]\S* copy\(", text)
+    assert _entry_copies_of_parameters(text) == []
+    assert sum(f is not None for f in formats) == 2
+    for (order, default), f in zip(_orders(one_chip, compiled, params),
+                                   formats):
+        assert order == ((0, 1) if f is not None else default)
+    assert {default for (_, default), f in zip(
+        _orders(one_chip, compiled, params), formats) if f} == {(1, 0)}
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("config,shape,relaid", [
+    ("gpt2_xl word", (50257, 1600), True),
+    ("gpt2_xl position", (1024, 1600), True),
+    ("gpt2_medium word", (50257, 1024), False),
+    ("k_exaone_236b_ep8", (19200, 6144), False),
+    ("xing4_29b_ep8", (16384, 3584), False),
+    ("jamba2_3b", (65536, 2560), False),
+    ("ouro_2p6b", (49152, 2048), False),
+])
+def test_a_table_is_relaid_only_where_the_chip_lays_it_column_major(
+        one_chip, config, shape, relaid):
+    """The rule by shape, on the described chip, for the served tables of
+    the benchmark's configurations (bfloat16): only GPT-2 XL's 1600-wide
+    tables, whose default layout puts the row index minor, are given a
+    row-major format; every table of a width that is a multiple of 128
+    keeps its default, which is row-major already."""
+    from incubator_mxnet_tpu.serving.executor_cache import row_table_formats
+
+    spec = _spec(one_chip, shape)
+    (fmt,) = row_table_formats([spec], ["table"], ["table"])
+    assert (fmt is not None) == relaid
+    if relaid:
+        assert fmt.layout.major_to_minor == (0, 1)
+    assert row_table_formats([spec], ["table"], []) == [None]
 
 
 def test_training_step_attends_through_the_dense_chain_gpt2_medium_widths(
@@ -465,8 +575,10 @@ def test_looped_decoder_step_attends_inside_its_pass_loop_published_widths(
     and outside fusions the only ops of the cache's shape are the
     in-place ``dynamic-update-slice`` of every pass's rows after the loop:
     no copy of the cache beside the one write, and temp memory under a
-    tenth of it."""
-    from incubator_mxnet_tpu import serving
+    tenth of it. Its table, 2048 wide, is row-major by the chip's default:
+    the session relays no parameter (the counter reads 0) and the step
+    takes every one in the chip's default layout, copying none."""
+    from incubator_mxnet_tpu import serving, telemetry
     from incubator_mxnet_tpu.gluon.model_zoo import get_decoder
 
     layers, passes, slots, t = 2, 4, 8, 512
@@ -481,11 +593,17 @@ def test_looped_decoder_step_attends_inside_its_pass_loop_published_widths(
         assert sess._kv.shapes == [shape]
         caches = [_spec(one_chip, shape)] * 2
         vec = _spec(one_chip, (slots,), jnp.int32)
-        params = [_spec(one_chip, p.shape, p.dtype) for p in sess._params]
+        params, formats = _served_params(one_chip, sess, net)
         lowered = jax.jit(sess._decode_apply, donate_argnums=(1, 2)).lower(
             params, *caches, vec, vec)
         compiled = lowered.compile()
         kv_bytes = sess._kv.nbytes
+    assert formats == [None] * len(params)
+    assert telemetry.counter("mxtpu_serving_row_tables_relaid_total",
+                             session="ouro2").value == 0
+    assert all(order == default
+               for order, default in _orders(one_chip, compiled, params))
+    assert _entry_copies_of_parameters(compiled.as_text()) == []
     # one loop, in the program and in what the compiler made of it, whose
     # body calls the kernel once a layer
     program = lowered.as_text()

@@ -21,6 +21,7 @@ import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import gluon, parallel, serving, telemetry
 from incubator_mxnet_tpu.config import config
 from incubator_mxnet_tpu.gluon.model_zoo import get_gpt
+from incubator_mxnet_tpu.serving import decode as decode_module
 
 VOCAB = 61
 
@@ -800,6 +801,190 @@ def test_prefill_bucket_policy_compiles_once_per_bucket():
         post = sess.stats()["prefill_cache"]
         assert post["compiles"] == 2           # warmup covered them all
         assert post["hits"] == 4
+
+
+# ---------------------------------------------------------------------------
+# how a session holds its parameters: the tables it reads by rows
+# ---------------------------------------------------------------------------
+def _column_major_tables(params, names, tables):
+    """Stands for ``row_table_formats`` on a device whose default layout
+    differs from the one a session holds its tables in: each declared
+    table column-major, a layout the CPU takes but never chooses (the rule
+    itself relays nothing here: the CPU's default is row-major for every
+    shape), so that every executable has to be lowered with it."""
+    from jax.experimental.layout import Format, Layout
+
+    return [Format(Layout((1, 0)), p.sharding) if n in tables else None
+            for p, n in zip(params, names)]
+
+
+def _relaid(name):
+    return telemetry.counter("mxtpu_serving_row_tables_relaid_total",
+                             session=name).value
+
+
+@pytest.mark.parametrize("held", ["default", "relaid"])
+def test_a_session_holds_its_parameters_as_its_executables_take_them(
+        held, monkeypatch):
+    """Every parameter is held in the format the decode step and every
+    prefill bucket take it in, so no call relayouts one on entry; the
+    counter reads how many the session holds in another layout than the
+    device's default (0 on the CPU), and the greedy streams stay
+    bit-exact either way."""
+    if held == "relaid":
+        monkeypatch.setattr(decode_module, "row_table_formats",
+                            _column_major_tables)
+    net = _tiny_net(dropout=0.0)
+    name = f"held_{held}"
+    with serving.DecodeSession(net, max_slots=2, max_len=48,
+                               prefill_buckets=(8, 16), name=name) as sess:
+        sess.warmup()
+        layouts = [p.format.layout for p in sess._params]
+        executables = [sess._dec_ex] + [
+            sess._prefill.executable(b, (), "int32")
+            for b in sess.prefill_buckets]
+        for ex in executables:
+            assert [f.layout for f in ex.input_formats[0][0]] == layouts
+        tables = {sess._param_names.index(n) for n in net.row_tables}
+        assert len(tables) == 2
+        assert {layouts[i].major_to_minor for i in tables} == {
+            (1, 0) if held == "relaid" else (0, 1)}
+        assert all(layouts[i].major_to_minor == (0, 1)
+                   for i in range(len(layouts)) if i not in tables
+                   and len(layouts[i].major_to_minor) == 2)
+        assert _relaid(name) == (2 if held == "relaid" else 0)
+        prompts = _prompts([5, 11, 3])
+        handles = [sess.submit(p, max_new_tokens=6) for p in prompts]
+        for p, h in zip(prompts, handles):
+            assert h.result(60) == _oracle(net, p, 6)
+
+
+def test_a_weight_swap_keeps_every_parameters_format(monkeypatch):
+    """A published version's changed parameters are placed in the live
+    ones' formats, in the session's list and the prefill cache's alike:
+    the executables are neither recompiled nor fed a relayout, and the
+    streams are the new weights'."""
+    monkeypatch.setattr(decode_module, "row_table_formats",
+                        _column_major_tables)
+    net_a, net_b = (_tiny_net(seed=s, dropout=0.0) for s in (0, 1))
+    weights = {k: p.data().asnumpy()
+               for k, p in parallel.spmd.collect_params(net_b).items()}
+    with serving.DecodeSession(net_a, max_slots=2, max_len=48,
+                               prefill_buckets=(8,), name="swap_held") as sess:
+        sess.warmup()
+        before = [p.format for p in sess._params]
+        tables = [sess._params[sess._param_names.index(n)]
+                  for n in net_a.row_tables]
+        compiles = sess.stats()["engine_cache"]["compiles"]
+        stats = sess.publish_weights(weights, version=2)
+        # (norms and biases start alike in both nets: those are aliased)
+        assert stats["updated"] + stats["aliased"] == len(before)
+        assert not any(any(p is t for t in tables) for p in sess._params)
+        assert [p.format for p in sess._params] == before
+        assert [p.format for p in sess._prefill._params] == before
+        prompt = _prompts([7])[0]
+        assert sess.generate(prompt, max_new_tokens=5) \
+            == _oracle(net_b, prompt, 5)
+        assert sess.stats()["engine_cache"]["compiles"] == compiles
+
+
+def test_params_fingerprint_tells_layouts_apart(tmp_path, monkeypatch):
+    """The artifact guard's fingerprint reads each parameter's layout: the
+    same shapes and types in another layout fingerprint differently, so a
+    session holding its tables relaid refuses the executables a session
+    holding them in the default layout persisted, and recompiles; the
+    next session holding them relaid loads what that one persisted, and
+    its streams are the oracle's."""
+    import jax.numpy as jnp
+    from jax.experimental.layout import Format, Layout
+
+    from incubator_mxnet_tpu.serving.artifacts import params_fingerprint
+    from incubator_mxnet_tpu.serving.executor_cache import place
+
+    x = jnp.arange(60, dtype=jnp.bfloat16).reshape(6, 10)
+    row, col = (place(x, Format(Layout(order), x.sharding))
+                for order in ((0, 1), (1, 0)))
+    assert params_fingerprint([x]) == params_fingerprint([row])
+    assert params_fingerprint([col]) != params_fingerprint([row])
+
+    net = _tiny_net(dropout=0.0)
+    store = str(tmp_path / "artifacts")
+    with serving.DecodeSession(net, max_slots=2, max_len=48,
+                               prefill_buckets=(8,), name="fp",
+                               artifact_dir=store) as sess:
+        sess.warmup()
+        assert sess.save_artifacts() == 3      # a bucket, a join, decode
+    monkeypatch.setattr(decode_module, "row_table_formats",
+                        _column_major_tables)
+    with serving.DecodeSession(net, max_slots=2, max_len=48,
+                               prefill_buckets=(8,), name="fp",
+                               artifact_dir=store) as sess:
+        sess.warmup()
+        engine = sess.stats()["engine_cache"]
+        prefill = sess.stats()["prefill_cache"]
+        assert engine["artifact_hits"] == prefill["artifact_hits"] == 0
+        assert engine["artifact_refused"] == 2          # the join, decode
+        assert prefill["artifact_refused"] == 1
+        prompt = _prompts([6])[0]
+        assert sess.generate(prompt, max_new_tokens=4) \
+            == _oracle(net, prompt, 4)
+    with serving.DecodeSession(net, max_slots=2, max_len=48,
+                               prefill_buckets=(8,), name="fp",
+                               artifact_dir=store) as sess:
+        sess.warmup()
+        stats = sess.stats()
+        assert stats["engine_cache"]["artifact_hits"] == 2
+        assert stats["prefill_cache"]["artifact_hits"] == 1
+        assert stats["engine_cache"]["compiles"] \
+            == stats["prefill_cache"]["compiles"] == 0
+        assert sess.generate(prompt, max_new_tokens=4) \
+            == _oracle(net, prompt, 4)
+
+
+_PLACE_SCRIPT = """
+import json, sys
+import jax, numpy as np
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
+from incubator_mxnet_tpu.serving.executor_cache import place
+table = np.arange(60, dtype=np.float32).reshape(6, 10)
+fmt = Format(Layout((1, 0)), SingleDeviceSharding(jax.devices()[0]))
+held = place(table, fmt)
+rows = jax.jit(lambda t: t[np.array([1, 3])]).lower(
+    jax.ShapeDtypeStruct(table.shape, table.dtype, sharding=fmt)).compile()
+print(json.dumps({"order": held.format.layout.major_to_minor,
+                  "rows": np.asarray(rows(held)).tolist()}))
+"""
+
+
+def test_a_table_is_placed_in_its_format_with_a_warm_compile_cache(tmp_path):
+    """A table placed in another layout than the default keeps it in a
+    process whose persistent compile cache already holds every executable
+    it runs (a served run after the first): an executable restored from
+    that cache labels its outputs with the default layout whatever it
+    writes, so the placement is compiled in the process. The gather that
+    takes the table in that layout, restored from the cache from the
+    second run on, reads the rows it asks for."""
+    import json
+    import subprocess
+    import sys
+
+    script = tmp_path / "place.py"
+    script.write_text(_PLACE_SCRIPT)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.dirname(os.path.dirname(
+                   os.path.abspath(__file__))))
+    for _ in range(3):
+        out = subprocess.run(
+            [sys.executable, str(script), str(tmp_path / "jax_cache")],
+            env=env, capture_output=True, text=True, timeout=240)
+        assert out.returncode == 0, out.stderr[-2000:]
+        got = json.loads(out.stdout.strip().splitlines()[-1])
+        assert got["order"] == [1, 0]
+        assert got["rows"] == [list(range(10, 20)), list(range(30, 40))]
 
 
 # ---------------------------------------------------------------------------
