@@ -1,7 +1,8 @@
 """A decoder-only language model built from data: per layer a sequence
 mixer (attention that is ``full``, a sliding ``window`` or ``latent``, or
 a selective state-space layer, ``mamba``) and an FFN kind (``dense`` or
-``sparse``), over one set of widths, under one of two residual rules.
+``sparse``), or one of the two alone, over one set of widths, under one
+of two residual rules.
 
 The layers are those today's large open decoders are built from:
 
@@ -16,7 +17,13 @@ The layers are those today's large open decoders are built from:
   selective scan over a state of ``d_state x E`` a sequence
   (``ops/state_space.py``), the output gated by ``silu(z)``. It keeps no
   row a position: what a sequence carries is that state and the
-  convolution's last inputs, the same size at every length;
+  convolution's last inputs, the same size at every length. ``mamba``
+  ``version`` 2 is Mamba-2's mixer (arXiv:2405.21060, as Nemotron-H has
+  it; ``heads``, ``head_dim``, ``n_groups``, ``d_state``, ``d_conv``,
+  ``chunk``): ``[z, xBC, dt] = W_in u``, the convolution over all of
+  ``xBC``, ONE ``dt`` and ``A`` a head, ``B`` and ``C`` a group of heads,
+  ``y * silu(z)`` RMS-normed a group at a time; the scan by the
+  state-space dual form in chunks over whole sequences;
 * latent attention (DeepSeek-V2's, arXiv:2405.04434; ``latent`` gives its
   ranks and head parts): queries through a normed ``q_rank`` bottleneck,
   keys and values through ONE normed ``kv_rank`` latent a position beside
@@ -26,13 +33,17 @@ The layers are those today's large open decoders are built from:
   key and value (``_latent_expanded``); the decode step ABSORBS the
   expansion into the query and the output and attends over the cached
   rows themselves (``_latent_absorbed``);
-* a SiLU-gated FFN, and on ``sparse`` layers a router over
+* a SiLU-gated FFN (or, ``ffn_act`` ``relu2``, ``down(relu(up x) ** 2)``
+  with no gate), and on ``sparse`` layers a router over
   ``num_experts`` experts of which the layer HOLDS ``experts_held`` (share
   ``expert_share`` of ``num_experts // experts_held``: what one chip of an
   expert-parallel deployment holds) plus a shared expert. Routing drops
   nothing (``ops.moe.moe_route`` / ``moe_held_ffn``); what the experts
   held elsewhere would add is left out, as it is on that chip before the
-  exchange;
+  exchange. With ``moe_latent`` the routed experts work in a latent of
+  that width (Nemotron-H's LatentMoE): the router reads ``x``, the
+  experts ``W_lat_down x``, their sum returns through ``W_lat_up``; the
+  shared expert (``shared_hidden`` wide) reads ``x``;
 * the residual rule (``_sub``): one stream, ``h = h + g(h)``, or
   ``streams`` of them under manifold-constrained hyper-connections
   (``ops/hyper_connection.py``: per token and sub-layer a read, a write
@@ -66,7 +77,8 @@ What the block declares to be served by ``serving.DecodeSession``
   the caches updated where they lie. Where a row lies, what a slot may
   read, the one-token attention, the writes and how a state advances are
   ``ops/kv_cache.py``'s;
-* ``step_counters``: the integers a step returns beside the logits;
+* ``step_counters``: the integers a step returns beside the logits, and
+  ``held_expert_bytes``: what a held expert's weights hold;
 * ``row_tables``: the parameters read by rows, the embedding table;
 * ``passes`` and ``step_weight_bytes(slots)``: how many times a step runs
   the stack, and the parameter bytes one step reads.
@@ -156,16 +168,41 @@ def gated_ffn(x, w_gate, w_up, w_down):
     return _mm(jax.nn.silu(_mm(x, w_gate)) * _mm(x, w_up), w_down)
 
 
+def relu2(x):
+    """The squared ReLU of Nemotron-H's FFNs and experts."""
+    return jnp.square(jax.nn.relu(x))
+
+
+#: an FFN's activation: ``silu`` gates (``down(silu(gate x) * up x)``),
+#: ``relu2`` has no gate (``down(relu(up x) ** 2)``)
+_ACTS = {"silu": jax.nn.silu, "relu2": relu2}
+
+
+def group_rms_norm(x, g, groups, eps):
+    """RMSNorm over each of ``groups`` equal parts of the last axis (the
+    gated norm of a Mamba-2 mixer), statistics in float32."""
+    shape = x.shape
+    xf = x.astype(jnp.float32).reshape(shape[:-1] + (groups, -1))
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y.reshape(shape) * g.astype(jnp.float32)).astype(x.dtype)
+
+
 class HybridDecoder(HybridBlock):
     """tokens (B, T) int32 -> logits (B, T, V); see the module docstring.
 
     ``layer_types[i]`` is ``"full_attention"``, ``"sliding_attention"``,
     ``"latent_attention"`` or ``"mamba"``, ``mlp_layer_types[i]``
-    ``"dense"`` or ``"sparse"`` (the published configs' own words).
-    ``mamba``, where a layer is one, is a dict: ``expand``, ``d_state``,
-    ``dt_rank``, ``d_conv``. ``qk_norm`` off leaves the
-    attention heads un-normed, ``tie_embeddings`` reads the logits off the
-    embedding table (no ``head``). ``latent``, where a
+    ``"dense"`` or ``"sparse"`` (the published configs' own words); either
+    may be None where the layer is the other alone (each sub-layer that
+    is there has its own norm). ``mamba``, where a layer is one, is a
+    dict: ``expand``, ``d_state``, ``dt_rank``, ``d_conv``, or with
+    ``version`` 2 ``heads``, ``head_dim``, ``n_groups``, ``d_state``,
+    ``d_conv`` and ``chunk``. ``ffn_act`` is ``silu`` (gated) or ``relu2``
+    (no gate), for the dense FFNs and every expert; ``moe_latent`` the
+    width the routed experts work in (0: the stream's), ``shared_hidden``
+    the shared expert's (``expert_hidden`` where None). ``qk_norm`` off
+    leaves the attention heads un-normed, ``tie_embeddings`` reads the
+    logits off the embedding table (no ``head``). ``latent``, where a
     layer is latent, is a dict: ``q_rank``, ``kv_rank``, ``nope_dim``,
     ``rope_dim``, ``v_dim`` and ``rope_scaling`` (YaRN's keys); a cached
     row ``[c | k_r]`` is stored at ``kv_cache.whole_tiles`` of ``kv_rank +
@@ -189,7 +226,9 @@ class HybridDecoder(HybridBlock):
                  routed_scale=1.0, max_length=4096, latent=None, streams=1,
                  hc=None, pre_norm=False, mamba=None, qk_norm=True,
                  tie_embeddings=False, sandwich=False, rope_full=False,
-                 passes=1, exit_threshold=1.0, prefix=None, params=None):
+                 passes=1, exit_threshold=1.0, ffn_act="silu",
+                 shared_hidden=None, moe_latent=0, prefix=None,
+                 params=None):
         super().__init__(prefix=prefix, params=params)
         if len(layer_types) != len(mlp_layer_types):
             raise ValueError("layer_types and mlp_layer_types differ in "
@@ -207,6 +246,11 @@ class HybridDecoder(HybridBlock):
                              "with dense FFNs on one stream only")
         if num_heads % num_kv_heads:
             raise ValueError("num_heads must be a multiple of num_kv_heads")
+        if any(a is None and f is None
+               for a, f in zip(layer_types, mlp_layer_types)):
+            raise ValueError("a layer has a mixer, an FFN or both")
+        if ffn_act not in _ACTS:
+            raise ValueError(f"ffn_act {ffn_act!r}: one of {sorted(_ACTS)}")
         sparse = "sparse" in mlp_layer_types
         if sparse and not (0 < experts_held <= num_experts
                            and (expert_share + 1) * experts_held
@@ -235,19 +279,30 @@ class HybridDecoder(HybridBlock):
                 self._latent["kv_rank"] + self._latent["rope_dim"])
         self._streams, self._pre_norm = int(streams), bool(pre_norm)
         self._hc = dict(hc or {})
-        self._ssm = dict(mamba or {})
+        self._ssm = dict(mamba, version=mamba.get("version", 1)) \
+            if mamba else {}
+        self._gated, self._act = ffn_act == "silu", _ACTS[ffn_act]
+        self._moe_latent = int(moe_latent)
         self._qk_norm, self._tied = bool(qk_norm), bool(tie_embeddings)
         self._sandwich, self._rope_full = bool(sandwich), bool(rope_full)
         self.passes = int(passes)
         # per layer (cache group, index in the group); layers per group
         self._group_counts, self._group_of = [0] * len(_KINDS), []
         for attn, _ in self._kinds:
+            if attn is None:
+                self._group_of.append((None, None))
+                continue
             g = _GROUP_OF[attn]
             self._group_of.append((g, self._group_counts[g]))
             self._group_counts[g] += 1
         c, d = self._units, self._head_dim
         hq, hkv = self._heads * d, self._kv_heads * d
         f, fe, e = int(hidden_size), int(expert_hidden), self._held
+        fs = fe if shared_hidden is None else int(shared_hidden)
+        # the experts' own width: the stream's, or the latent's they work in
+        ce = self._moe_latent or c
+        # an FFN's projections into its hidden width: gate and up, or up
+        ins = ("gate", "up") if self._gated else ("up",)
         n = self._streams
         get = self.params.get
         with self.name_scope():
@@ -256,7 +311,9 @@ class HybridDecoder(HybridBlock):
             if not self._tied:
                 self.head = get("head", shape=(self._vocab, c))
             for i, (attn, ffn) in enumerate(self._kinds):
-                shapes = {"attn_norm": (c,), "ffn_norm": (c,)}
+                subs = [sub for sub, kind in (("attn", attn), ("ffn", ffn))
+                        if kind is not None]
+                shapes = {f"{sub}_norm": (c,) for sub in subs}
                 if self._sandwich:
                     shapes.update(attn_out_norm=(c,), ffn_out_norm=(c,))
                 if attn == "latent_attention":
@@ -270,6 +327,15 @@ class HybridDecoder(HybridBlock):
                         kv_b=(h * (la["nope_dim"] + la["v_dim"]),
                               la["kv_rank"]),
                         o=(c, h * la["v_dim"]))
+                elif attn == "mamba" and self._ssm["version"] == 2:
+                    ma = self._ssm
+                    ex, conv = self._mamba2_widths()
+                    shapes.update(
+                        in_proj=(ex + conv + ma["heads"], c),
+                        conv_w=(ma["d_conv"], conv), conv_bias=(conv,),
+                        dt_bias=(ma["heads"],), a_log=(ma["heads"],),
+                        d_skip=(ma["heads"],), mamba_norm=(ex,),
+                        out_proj=(c, ex))
                 elif attn == "mamba":
                     ma = self._ssm
                     ex, ns = ma["expand"] * c, ma["d_state"]
@@ -279,26 +345,29 @@ class HybridDecoder(HybridBlock):
                         dt_norm=(ma["dt_rank"],), b_norm=(ns,), c_norm=(ns,),
                         dt_proj=(ex, ma["dt_rank"]), dt_bias=(ex,),
                         a_log=(ns, ex), d_skip=(ex,), out_proj=(c, ex))
-                else:
+                elif attn is not None:
                     shapes.update(q=(hq, c), k=(hkv, c), v=(hkv, c),
                                   o=(c, hq))
                     if self._qk_norm:
                         shapes.update(q_norm=(d,), k_norm=(d,))
                 if n > 1:
-                    for sub in ("attn", "ffn"):
+                    for sub in subs:
                         shapes.update({
                             f"hc_{sub}_w": (2 * n + n * n, n * c),
                             f"hc_{sub}_scale": (3,),
                             f"hc_{sub}_bias": (2 * n + n * n,)})
                 if ffn == "dense":
-                    shapes.update(gate=(f, c), up=(f, c), down=(c, f))
-                else:
+                    shapes.update({k: (f, c) for k in ins}, down=(c, f))
+                elif ffn == "sparse":
                     shapes.update(
                         router=(self._experts, c),
                         router_bias=(self._experts,),
-                        experts_gate=(e, c, fe), experts_up=(e, c, fe),
-                        experts_down=(e, fe, c), shared_gate=(fe, c),
-                        shared_up=(fe, c), shared_down=(c, fe))
+                        **{f"experts_{k}": (e, ce, fe) for k in ins},
+                        experts_down=(e, fe, ce),
+                        **{f"shared_{k}": (fs, c) for k in ins},
+                        shared_down=(c, fs))
+                    if self._moe_latent:
+                        shapes.update(latent_down=(ce, c), latent_up=(c, ce))
                 for name, shape in shapes.items():
                     init = "ones" if name.endswith(("norm", "_scale",
                                                      "d_skip")) \
@@ -317,10 +386,13 @@ class HybridDecoder(HybridBlock):
         layers: ``layers``, ``heads`` (K/V heads), ``rows``, ``head_dim``
         and ``kind`` (``ops/kv_cache.py``; a ``latent`` group is one
         tensor of one ``row``-wide head). A ``state`` group declares the
-        ``width`` of a state-space layer's channels, its ``state`` and
-        ``taps`` a channel and the state's ``dtype`` instead: float32
-        whatever the model is served in, because the scan sums into it
-        at every step. Groups a model has no layer of are left out but
+        scan's state a layer as ``state`` rows of ``width`` (Mamba's
+        ``d_state`` rows of its channels, Mamba-2's ``d_state x n_groups``
+        rows of a group's channels), the convolution's ``taps`` of
+        ``taps_width`` (the channels', or Mamba-2's ``x``, ``B`` and ``C``
+        together) and the state's ``dtype``: float32 whatever the model is
+        served in, because the scan sums into it at every step. Groups a
+        model has no layer of are left out but
         keep their place in the order. A looped block's group holds
         ``passes`` x its layers, pass by pass."""
         rows = (int(max_len), min(self._window, int(max_len)), int(max_len))
@@ -333,10 +405,12 @@ class HybridDecoder(HybridBlock):
                                               rows, widths, _KINDS)]
         ma = self._ssm
         if ma:
+            state, width = self._state_layout()
+            taps = self._mamba2_widths()[1] if ma["version"] == 2 else width
             groups.append(dict(
                 layers=self._group_counts[_STATE], kind=_KINDS[_STATE],
-                width=ma["expand"] * self._units, state=ma["d_state"],
-                taps=ma["d_conv"] - 1, dtype="float32"))
+                width=width, state=state, taps=ma["d_conv"] - 1,
+                taps_width=taps, dtype="float32"))
         return [g for g in groups if g["layers"]]
 
     def step_weight_bytes(self, slots):
@@ -353,6 +427,20 @@ class HybridDecoder(HybridBlock):
         table = nbytes["embed"]
         return self.passes * stack + nbytes.get("head", table) \
             + int(slots) * table // self._vocab
+
+    @property
+    def held_expert_bytes(self):
+        """Bytes of one held expert's weights as they are held (every
+        sparse layer's are alike), or None where no layer is sparse: what
+        a step reads of a layer for each held expert it hits."""
+        names = ("experts_gate", "experts_up", "experts_down")
+        for i, (_, ffn) in enumerate(self._kinds):
+            if ffn == "sparse":
+                ps = [self._reg_params[f"layer{i}_{n}"] for n in names
+                      if f"layer{i}_{n}" in self._reg_params]
+                return sum(int(math.prod(p.shape[1:]))
+                           * jnp.dtype(p.dtype).itemsize for p in ps)
+        return None
 
     # -- the arithmetic, over plain arrays -------------------------------------
     def _arrays(self):
@@ -539,6 +627,8 @@ class HybridDecoder(HybridBlock):
         or with ``state`` = (``h`` (S, N, E), ``taps`` (S, K - 1, E)) every
         slot's one token ``u`` (S, 1, C). Returns the mixer's output and
         the state after it: ``h``, ``taps``."""
+        if self._ssm["version"] == 2:
+            return self._mamba2(lp, u, state, n)
         xz = _mm(u, lp["in_proj"])
         x, z = jnp.split(xz, 2, axis=-1)
         a = -jnp.exp(lp["a_log"].astype(jnp.float32))
@@ -556,24 +646,97 @@ class HybridDecoder(HybridBlock):
             y = y[:, None]
         return _mm(y * jax.nn.silu(z), lp["out_proj"]), h, taps
 
+    def _mamba2_widths(self):
+        """A Mamba-2 mixer's channels ``E = heads x head_dim`` and what its
+        convolution runs over, ``x``, ``B`` and ``C``: ``E + 2 G N``."""
+        ma = self._ssm
+        ex = ma["heads"] * ma["head_dim"]
+        return ex, ex + 2 * ma["n_groups"] * ma["d_state"]
+
+    def _state_layout(self):
+        """The scan's state as a ``state`` group keeps it a layer and slot,
+        ``(state, width)``: Mamba's ``(N, E)``; Mamba-2's ``(N G, E / G)``,
+        ``ops/state_space.py``'s ``(N, G, Q)`` with its two outer axes
+        merged (a reshape that moves no byte)."""
+        ma = self._ssm
+        if ma["version"] == 2:
+            ex, _ = self._mamba2_widths()
+            return ma["n_groups"] * ma["d_state"], ex // ma["n_groups"]
+        return ma["d_state"], ma["expand"] * self._units
+
+    def _mamba2(self, lp, u, state=None, n=None):
+        """``_mamba`` of a Mamba-2 mixer (``ops/state_space.py``'s ``ssd_*``
+        forms): ``[z, xBC, dt] = W_in u``, the causal convolution over all
+        of ``xBC``, one ``dt`` and ``A`` a head, ``B`` and ``C`` a group of
+        heads, the output gated by ``silu(z)`` and RMS-normed a group at
+        a time."""
+        ma = self._ssm
+        ex, conv = self._mamba2_widths()
+        g, ns = ma["n_groups"], ma["d_state"]
+        zxd = _mm(u, lp["in_proj"])
+        z, xbc, dt = jnp.split(zxd, [ex, ex + conv], axis=-1)
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + lp["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(lp["a_log"].astype(jnp.float32))
+        groups = lambda v: v.reshape(v.shape[:-1] + (g, ns))
+        if state is None:
+            xbc, taps = state_space.conv_sequence(xbc, lp["conv_w"],
+                                                  lp["conv_bias"], n)
+            x, b, c = jnp.split(xbc, [ex, ex + g * ns], axis=-1)
+            y, h = state_space.ssd_sequence(x, dt, a, groups(b), groups(c),
+                                            lp["d_skip"], n,
+                                            chunk=ma["chunk"])
+        else:
+            h, taps = state
+            xbc, taps = state_space.conv_step(xbc[:, 0], taps, lp["conv_w"],
+                                              lp["conv_bias"])
+            x, b, c = jnp.split(xbc, [ex, ex + g * ns], axis=-1)
+            y, h = state_space.ssd_step(
+                h.reshape(h.shape[0], ns, g, -1), x, dt[:, 0], a, groups(b),
+                groups(c), lp["d_skip"])
+            y = y[:, None]
+        h = h.reshape(h.shape[0], ns * g, -1)
+        y = group_rms_norm(y.astype(jnp.float32) * jax.nn.silu(
+            z.astype(jnp.float32)), lp["mamba_norm"], g, self._eps)
+        return _mm(y.astype(u.dtype), lp["out_proj"]), h, taps
+
+    def _dense(self, lp, x, name=""):
+        """The FFN ``name`` (``""`` a dense layer's, ``"shared_"`` the
+        shared expert) over ``x`` (N, C): gated or not, as ``ffn_act``
+        says."""
+        if self._gated:
+            return gated_ffn(x, lp[name + "gate"], lp[name + "up"],
+                             lp[name + "down"])
+        return _mm(self._act(_mm(x, lp[name + "up"])), lp[name + "down"])
+
     def _ffn(self, lp, x, live=None):
         """``x`` (N, C) -> the FFN's output and the routing's counts
-        (None on a dense layer)."""
-        if "gate" in lp:
-            return gated_ffn(x, lp["gate"], lp["up"], lp["down"]), None
+        (None on a dense layer). Of a ``moe_latent`` block the experts
+        work in the latent: the router reads ``x``, the chosen experts
+        ``W_lat_down x``, and their sum goes back through ``W_lat_up``;
+        the shared expert works at full width."""
+        if "router" not in lp:
+            return self._dense(lp, x), None
         with jax.named_scope("router"):
             logits = jnp.einsum("ni,ei->ne", x, lp["router"],
                                 preferred_element_type=jnp.float32)
             idx, w = moe_route(logits, self._top_k, bias=lp["router_bias"],
                                scale=self._scale)
+        u = x
+        if self._moe_latent:
+            with jax.named_scope("latent_down"):
+                u = _mm(x, lp["latent_down"])
         with jax.named_scope("grouped_product"):
             y, counts = moe_held_ffn(
-                x, idx, w, lp["experts_gate"], lp["experts_up"],
+                u, idx, w, lp.get("experts_gate"), lp["experts_up"],
                 lp["experts_down"], first_expert=self._first_expert,
-                live=live)
+                live=live, act=self._act)
+        if self._moe_latent:
+            with jax.named_scope("latent_up"):
+                y = _mm(y.astype(x.dtype), lp["latent_up"]).astype(
+                    jnp.float32)
         with jax.named_scope("shared_expert"):
-            y = y + gated_ffn(x, lp["shared_gate"], lp["shared_up"],
-                              lp["shared_down"]).astype(jnp.float32)
+            y = y + self._dense(lp, x, "shared_").astype(jnp.float32)
         return y.astype(x.dtype), counts
 
     def _sequence(self, p, tokens, n=None, entry="forward"):
@@ -590,8 +753,8 @@ class HybridDecoder(HybridBlock):
 
         def stack(x, _):
             planes = []
-            for attn, _ in self._kinds:
-                lp = self._layer(p, len(planes))
+            for i, (attn, ffn) in enumerate(self._kinds):
+                lp = self._layer(p, i)
 
                 def attention(u):
                     if attn == "latent_attention":
@@ -613,9 +776,13 @@ class HybridDecoder(HybridBlock):
                     planes.append((k, v))
                     return a
 
-                x = self._sub(lp, x, "attn", attention)
-                x = self._sub(lp, x, "ffn", lambda u: self._ffn(
-                    lp, u.reshape(b * t, -1))[0].reshape(b, t, -1))
+                if attn is None:
+                    planes.append(())
+                else:
+                    x = self._sub(lp, x, "attn", attention)
+                if ffn is not None:
+                    x = self._sub(lp, x, "ffn", lambda u: self._ffn(
+                        lp, u.reshape(b * t, -1))[0].reshape(b, t, -1))
             return x, planes
 
         x, planes = self._passes(p, stack, x, entry)
@@ -736,10 +903,11 @@ class HybridDecoder(HybridBlock):
                 own index of its group in that pass and hands out its new
                 rows, which nothing writes before the last pass."""
                 new = {g: tuple([] for _ in kv[g]) for g in rowed}
-                for i in range(len(self._kinds)):
+                for i, (attn, ffn) in enumerate(self._kinds):
                     lp = self._layer(p, i)
                     g, j = group_of[i]
-                    at_layer = j + r * self._group_counts[g]
+                    at_layer = None if attn is None \
+                        else j + r * self._group_counts[g]
 
                     def attention(u):
                         if g == _STATE:
@@ -771,14 +939,16 @@ class HybridDecoder(HybridBlock):
 
                     counts = []
 
-                    def ffn(u):
+                    def ffn_step(u):
                         y, c = self._ffn(lp, u[:, 0], live=live)
                         counts.append(c)
                         return y[:, None]
 
-                    x = self._sub(lp, x, "attn", attention)
-                    x = self._sub(lp, x, "ffn", ffn)
-                    if counts[0] is not None:
+                    if attn is not None:
+                        x = self._sub(lp, x, "attn", attention)
+                    if ffn is not None:
+                        x = self._sub(lp, x, "ffn", ffn_step)
+                    if counts and counts[0] is not None:
                         totals["sparse"] += 1
                         for name in ("routed_here", "experts_hit"):
                             totals[name] = totals[name] + counts[0][name]
@@ -886,9 +1056,42 @@ _SPECS = {
         hidden_size=96, rope_theta=1e6, eps=1e-6, num_layers=3,
         pattern="G", dense_layers=3, qk_norm=False, sandwich=True,
         rope_full=True, passes=4, max_length=128),
+    # https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16/
+    # blob/main/config.json (``nemotron_h``): 88 pre-norm layers that are
+    # each ONE of a Mamba-2 mixer (``M``: 128 heads of 64, 8 groups of
+    # state 128, convolution over 4), a latent expert layer (``E``: 512
+    # experts top-22 of relu2 2688 in a 1024 latent, a relu2 shared expert
+    # 5376 at full width, scaling 5) or attention (``*``: 32 query heads
+    # over 2 K/V heads of 128, no positions, no per-head norm); an untied
+    # head
+    "nemotron3_super": dict(
+        vocab_size=131072, units=4096, num_heads=32, num_kv_heads=2,
+        head_dim=128, hidden_size=2688, eps=1e-5, num_experts=512,
+        experts_held=512, experts_per_token=22, expert_hidden=2688,
+        shared_hidden=5376, moe_latent=1024, routed_scale=5.0,
+        hybrid_override_pattern=(
+            "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+            "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+        pre_norm=True, qk_norm=False, ffn_act="relu2",
+        mamba=dict(version=2, heads=128, head_dim=64, n_groups=8,
+                   d_state=128, d_conv=4, chunk=128),
+        max_length=262144),
+    "nemotron_h_tiny": dict(
+        vocab_size=97, units=64, num_heads=4, num_kv_heads=2, head_dim=16,
+        hidden_size=96, eps=1e-5, num_experts=16, experts_held=8,
+        experts_per_token=4, expert_hidden=32, shared_hidden=48,
+        moe_latent=24, routed_scale=5.0, hybrid_override_pattern="MEM*EME",
+        pre_norm=True, qk_norm=False, ffn_act="relu2",
+        mamba=dict(version=2, heads=8, head_dim=8, n_groups=2, d_state=16,
+                   d_conv=4, chunk=8),
+        max_length=128),
 }
 _PATTERN = {"G": "full_attention", "L": "sliding_attention",
             "M": "latent_attention"}
+#: Nemotron-H's ``hybrid_override_pattern``: a layer is ONE of a Mamba
+#: mixer, a sparse FFN or attention
+_HYBRID = {"M": ("mamba", None), "E": (None, "sparse"),
+           "*": ("full_attention", None)}
 
 
 def get_decoder(model_name="exaone_moe", **kwargs):
@@ -899,12 +1102,19 @@ def get_decoder(model_name="exaone_moe", **kwargs):
     offset``, a ``mamba`` layer everywhere else: Jamba's rule), and
     ``dense_layers`` (the leading layers whose FFN is dense) expand to the
     per-layer kinds unless ``layer_types`` / ``mlp_layer_types`` are
-    given."""
+    given. A ``hybrid_override_pattern`` (Nemotron-H's: a character a
+    layer, ``M`` a mixer alone, ``E`` a sparse FFN alone, ``*`` attention
+    alone) is the whole stack, its depth too."""
     if model_name not in _SPECS:
         raise ValueError(f"unknown decoder spec {model_name!r}; "
                          f"known {sorted(_SPECS)}")
     spec = dict(_SPECS[model_name])
     spec.update(kwargs)
+    if "hybrid_override_pattern" in spec:
+        kinds = [_HYBRID[k] for k in spec.pop("hybrid_override_pattern")]
+        spec.setdefault("layer_types", [a for a, _ in kinds])
+        spec.setdefault("mlp_layer_types", [f for _, f in kinds])
+        return HybridDecoder(**spec)
     n = int(spec.pop("num_layers"))
     dense = int(spec.pop("dense_layers"))
     if "attn_layer_period" in spec:

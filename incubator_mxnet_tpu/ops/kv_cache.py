@@ -25,14 +25,17 @@ kernel instead.
 A fourth kind, ``"state"``, has no rows: what a recurrent layer carries from
 one position to the next, the same size at every length (a selective
 state-space layer's, ``ops/state_space.py``). A layer keeps two tensors
-``[L, S, n, E]`` (``layout``): the scan's ``h`` (``n`` = ``state``, the
-group's own ``dtype``: it is summed into at every step) and the
-convolution's last inputs (``n`` = ``taps``, the cache's type), ``E``
-minor. A step REPLACES a slot's state where a row group appends a row:
-each layer reads its own and writes the new one in its place in the
-donated array (``advance``), and a prefill's state replaces a slot's whole
-(``join``, whatever the prompt's length: a freed slot leaves nothing
-behind). ``address`` / ``read`` / ``attend`` / ``write`` do not apply.
+(``layout``): the scan's ``h`` ``[L, S, state, width]`` (the group's own
+``dtype``: it is summed into at every step) and the convolution's last
+inputs ``[L, S, taps, taps_width]`` (the cache's type). The two widths
+differ where the convolution runs over more than the scan's channels
+(Mamba-2's over ``x``, ``B`` and ``C``); the block declares each as whole
+128-lane tiles, the minor axis of its tensor. A step REPLACES a slot's
+state where a row group appends a row: each layer reads its own and
+writes the new one in its place in the donated array (``advance``), and
+a prefill's state replaces a slot's whole (``join``, whatever the
+prompt's length: a freed slot leaves nothing behind). ``address`` /
+``read`` / ``attend`` / ``write`` do not apply.
 
 The stored row: the TPU keeps a minor dimension of whole 128-lane tiles
 minor, so a new row is few tiles; a minor dimension of 64 it laid out
@@ -68,13 +71,14 @@ def layout(group, slots, dtype):
     """``(shape, dtype)`` of each array a declared ``group`` keeps for
     ``slots`` slots in a cache of type ``dtype``: a row group's
     ``[L, S, H, rows, W]`` K and V (or one), a ``state`` group's
-    ``[L, S, state, E]`` in its own ``dtype`` and ``[L, S, taps, E]``."""
+    ``[L, S, state, width]`` in its own ``dtype`` and ``[L, S, taps,
+    taps_width]``."""
     layers, dtype = int(group["layers"]), jnp.dtype(dtype)
     if group["kind"] == "state":
-        width = int(group["width"])
-        return [((layers, slots, int(group["state"]), width),
+        return [((layers, slots, int(group["state"]), int(group["width"])),
                  jnp.dtype(group["dtype"])),
-                ((layers, slots, int(group["taps"]), width), dtype)]
+                ((layers, slots, int(group["taps"]),
+                  int(group["taps_width"])), dtype)]
     shape = (layers, slots, int(group["heads"]), int(group["rows"]),
              int(group["head_dim"]))
     return [(shape, dtype)] * tensors(group["kind"])

@@ -153,13 +153,15 @@ def moe_route(logits, k, bias=None, scale=1.0):
 
 
 def moe_held_ffn(x, idx, weights, w_gate, w_up, w_down, first_expert=0,
-                 live=None):
-    """The held experts' part of a sparse SiLU-gated FFN, dropless.
+                 live=None, act=jax.nn.silu):
+    """The held experts' part of a sparse FFN, dropless: each expert
+    ``W_down (act(W_gate x) * W_up x)``, or where ``w_gate`` is None
+    ``W_down act(W_up x)``.
 
     ``x`` (N, C); ``idx``/``weights`` (N, k) from :func:`moe_route` (ids
-    over all the layer's experts); ``w_gate``/``w_up`` (E, C, F) and
-    ``w_down`` (E, F, C) the ``E`` experts held here, which are experts
-    ``first_expert .. first_expert + E - 1`` of the layer. The N x k
+    over all the layer's experts); ``w_gate`` (or None) / ``w_up`` (E, C,
+    F) and ``w_down`` (E, F, C) the ``E`` experts held here, which are
+    experts ``first_expert .. first_expert + E - 1`` of the layer. The N x k
     token-choices are sorted by held expert (those that fell on experts
     held elsewhere go last and belong to no group) and each projection is
     one grouped product over the sorted rows; every token is served by
@@ -170,7 +172,7 @@ def moe_held_ffn(x, idx, weights, w_gate, w_up, w_down, first_expert=0,
     over the tokens where ``live`` (N,) is true (all, by default).
     """
     n, k = idx.shape
-    e = w_gate.shape[0]
+    e = w_up.shape[0]
     local = idx - int(first_expert)
     held = (local >= 0) & (local < e)
     key = jnp.where(held, local, e).reshape(-1)            # (N*k,)
@@ -185,7 +187,8 @@ def moe_held_ffn(x, idx, weights, w_gate, w_up, w_down, first_expert=0,
     grouped = functools.partial(
         jax.lax.ragged_dot, group_sizes=sizes,
         precision=jax.lax.Precision.DEFAULT if low else None)
-    h = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+    h = act(grouped(rows, w_up)) if w_gate is None \
+        else act(grouped(rows, w_gate)) * grouped(rows, w_up)
     out = grouped(h, w_down, preferred_element_type=jnp.float32)
     # back to (token, choice) order. Rows of no group are NOT computed:
     # the TPU's kernel leaves whatever lay there (NaN included), so they

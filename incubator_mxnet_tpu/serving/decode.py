@@ -512,6 +512,10 @@ class DecodeSession:
         weight = weight(self.max_slots) if weight else None
         self._step_fields = dict(self._prefill_fields, **(
             {} if weight is None else {"weight_bytes": int(weight)}))
+        # and the bytes of one held expert, which the step's
+        # ``experts_hit`` turns into the expert bytes it read
+        self._expert_bytes = getattr(block, "held_expert_bytes", None) \
+            if "experts_hit" in self._counters else None
         self.metrics = DecodeMetrics(self.name)
         self.metrics.set_capacity(max_slots, self._kv.nbytes)
         self._site = f"decode.{self.name}"
@@ -1281,12 +1285,15 @@ class DecodeSession:
         read_rows, read_bytes = self._kv.read(lens)
         state = {"state_bytes": self._kv.state_bytes(active)} \
             if "state" in self._kv.kinds else {}
+        counters = dict(zip(self._counters, toks[self.max_slots:]))
+        if self._expert_bytes:
+            counters["expert_bytes"] = \
+                int(counters["experts_hit"]) * self._expert_bytes
         flight.turn.close(t0, t1 - t0, active=active, **state,
                           **self._step_fields,
                           kv_live_rows=self._kv.live_rows(lens + 1),
                           kv_read_rows=read_rows, kv_read_bytes=read_bytes,
-                          kv_rows=self._kv.rows,
-                          **dict(zip(self._counters, toks[self.max_slots:])))
+                          kv_rows=self._kv.rows, **counters)
 
     def _finish_slot(self, slot: int) -> None:
         """Retire a finished sequence: resolve its handle, free the slot

@@ -632,3 +632,66 @@ def test_looped_decoder_step_attends_inside_its_pass_loop_published_widths(
         if m.group(2) not in ("parameter", "bitcast", "get-tuple-element"):
             beside[m.group(2)] = beside.get(m.group(2), 0) + 1
     assert beside == {"dynamic-update-slice": 2 * slots}, beside
+
+
+def test_mamba2_decoder_step_updates_its_state_in_one_fusion_published_widths(
+        one_chip):
+    """The data-built decoder's served decode step at Nemotron-3 Super's
+    widths (hidden 4096; Mamba-2 mixers of 128 heads of 64 over 8 groups of
+    state 128, the convolution over 10240 lanes; a latent expert layer
+    whose router is 512 wide, top-22, 4 experts held so that the weights
+    are a test's; attention of 32 query heads over 2 K/V heads of 128; the
+    vocabulary cut), 64 slots of 4096 positions, donated as
+    ``DecodeSession`` lowers it. Every cache array aliases its output; the
+    stacked float32 state ``[2, 64, 1024, 1024]`` (``(N, G, Q)`` a slot)
+    is touched by nothing but one fusion a mixer, which updates it where
+    it lies, and no state-sized tensor is built beside it (temp memory
+    under a quarter of one layer's state): ``B`` and ``C`` broadcast along
+    a group's lanes, a channel's decay and input along ``N``. The layer
+    parts run under their named scopes."""
+    from incubator_mxnet_tpu import serving
+    from incubator_mxnet_tpu.gluon.model_zoo import get_decoder
+
+    slots, t = 64, 4096
+    net = get_decoder("nemotron3_super", hybrid_override_pattern="ME*M",
+                      experts_held=4, expert_share=0, vocab_size=4096,
+                      max_length=t)
+    net.cast("bfloat16")
+    net.initialize(init="zeros")
+    with serving.DecodeSession(net, max_slots=slots, max_len=t,
+                               prefill_buckets=(128,), name="nemo4",
+                               donate=True, artifact_dir="") as sess:
+        assert [(s.shape, s.dtype.name) for s in sess._kv.specs()] == [
+            ((1, slots, 2, t, 128), "bfloat16")] * 2 + [
+            ((2, slots, 128 * 8, 1024), "float32"),
+            ((2, slots, 3, 10240), "bfloat16")]
+        caches = [_spec(one_chip, s.shape, s.dtype)
+                  for s in sess._kv.specs()]
+        vec = _spec(one_chip, (slots,), jnp.int32)
+        params, _ = _served_params(one_chip, sess, net)
+        compiled = jax.jit(
+            sess._decode_apply, donate_argnums=(1, 2, 3, 4)).lower(
+            params, *caches, vec, vec).compile()
+        layer_state = slots * 128 * 8192 * 4
+    n = len(params)
+    text = compiled.as_text()
+    alias = re.search(r"input_output_alias=\{[^\n]*?\}, entry", text)
+    assert alias and all(f"{{{j + 1}}}: ({n + j}, {{}}" in alias.group(0)
+                         for j in range(4)), "a cache array is not aliased"
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_state // 4
+    whole = "f32[2,%d,1024,1024]{" % slots
+    beside, fused = {}, False
+    for line in text.splitlines():
+        if line and not line.startswith((" ", "}")):
+            fused = not line.startswith("ENTRY")   # only the entry's ops
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if fused or not m or not m.group(1).startswith(whole):
+            continue
+        if m.group(2) not in ("parameter", "bitcast", "get-tuple-element"):
+            beside[m.group(2)] = beside.get(m.group(2), 0) + 1
+    assert beside == {"fusion": 2}, beside
+    for scope in ("state_space", "router", "latent_down", "grouped_product",
+                  "latent_up", "shared_expert", "attention"):
+        assert f"jit(_decode_apply)/{scope}/" in text, scope
+    assert _kernel_calls(text) == 1

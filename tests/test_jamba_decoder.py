@@ -104,7 +104,7 @@ def test_the_layers_come_from_the_sources_keys():
     assert net.cache_groups(4096) == [
         dict(layers=2, heads=1, rows=4096, head_dim=128, kind="full"),
         dict(layers=26, kind="state", width=5120, state=16, taps=3,
-             dtype="float32")]
+             taps_width=5120, dtype="float32")]
 
 
 @pytest.mark.parametrize("prompts,n_new", [

@@ -269,19 +269,20 @@ def test_join_of_a_prompt_under_at_and_over_the_rows(kind, n):
 
 # -- the ``state`` kind: a recurrent layer's state, no rows --------------------
 
+#: the taps wider than the state, as Mamba-2's convolution over x, B and C
 _STATE = dict(layers=3, kind="state", width=256, state=4, taps=3,
-              dtype="float32")
+              taps_width=384, dtype="float32")
 
 
 def test_a_state_group_keeps_two_tensors_with_a_dtype_each():
     """The scan's state in the group's own type, the convolution's taps
-    in the cache's, ``E`` minor; a row group's arrays take the cache's
-    type and a prefill hands the join a slot's state with no position
-    axis."""
+    in the cache's, each at its own width, minor; a row group's arrays
+    take the cache's type and a prefill hands the join a slot's state
+    with no position axis."""
     assert kv_cache.tensors("state") == 2
     got = kv_cache.layout(_STATE, 5, "bfloat16")
     assert got == [((3, 5, 4, 256), jnp.dtype("float32")),
-                   ((3, 5, 3, 256), jnp.dtype("bfloat16"))]
+                   ((3, 5, 3, 384), jnp.dtype("bfloat16"))]
     rows = dict(layers=2, heads=2, rows=8, head_dim=128, kind="full")
     assert kv_cache.layout(rows, 5, "bfloat16") == [
         ((2, 5, 2, 8, 128), jnp.dtype("bfloat16"))] * 2
@@ -337,12 +338,12 @@ def test_kv_cache_holds_a_state_group_beside_rows():
     assert [(a.shape, a.dtype) for a in kv.arrays] \
         == [(s.shape, s.dtype) for s in kv.specs()] \
         == [((2, 5, 1, 16, 128), jnp.bfloat16)] * 2 \
-        + [((3, 5, 4, 256), jnp.float32), ((3, 5, 3, 256), jnp.bfloat16)]
+        + [((3, 5, 4, 256), jnp.float32), ((3, 5, 3, 384), jnp.bfloat16)]
     assert kv.slots == 5 and kv.max_len == only.max_len == 16
     assert kv.rows == only.rows == 2 * 5 * 16
     assert kv.live_rows([3, 9]) == only.live_rows([3, 9]) == 2 * 12
     assert kv.read([3, 9]) == only.read([3, 9])
-    slot = 3 * 256 * (4 * 4 + 3 * 2)
+    slot = 3 * (256 * 4 * 4 + 384 * 3 * 2)
     assert kv.nbytes == only.nbytes + 5 * slot
     assert kv.state_bytes(2) == 2 * 2 * slot and kv.state_bytes(0) == 0
     assert only.state_bytes(5) == 0
