@@ -730,7 +730,7 @@ class HybridDecoder(HybridBlock):
             y, counts = moe_held_ffn(
                 u, idx, w, lp.get("experts_gate"), lp["experts_up"],
                 lp["experts_down"], first_expert=self._first_expert,
-                live=live, act=self._act)
+                live=live, act=self._act, width=logits.shape[-1])
         if self._moe_latent:
             with jax.named_scope("latent_up"):
                 y = _mm(y.astype(x.dtype), lp["latent_up"]).astype(

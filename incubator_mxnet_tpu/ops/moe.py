@@ -21,6 +21,9 @@ layer, the layer is told which contiguous share of them it holds, and it
 computes that share's part of the result for every token that chose one
 of them: the token-choices are sorted by held expert and each projection
 is ONE grouped product (``jax.lax.ragged_dot``) over the sorted rows.
+Where few rows are expected to hit nearly every held expert (a decode
+step of many slots over a wide top-k), the same result comes from ONE
+batched product over all the held experts instead (``held_form``).
 No capacity, nothing dropped; what the experts held elsewhere would add
 is not computed and not stood in for.
 """
@@ -152,41 +155,95 @@ def moe_route(logits, k, bias=None, scale=1.0):
     return idx.astype(jnp.int32), w * float(scale)
 
 
+#: the dense form reads every held expert, so it is taken only where the
+#: routing is expected to hit at least this share of them: it then reads
+#: at most ~11% more weight bytes than the grouped form ...
+DENSE_HIT_SHARE = 0.9
+#: ... and only over at most this many rows: 64 FLOP a byte of a 16-bit
+#: weight, a quarter of a TPU v5e's ridge (197 TF/s over 819 GB/s), so the
+#: batched products stay bound by the weights' bytes
+DENSE_MAX_ROWS = 64
+
+
+def held_form(n, k, width):
+    """``"dense"`` or ``"grouped"``: how :func:`moe_held_ffn` multiplies
+    ``n`` rows that each chose ``k`` of the layer's ``width`` experts. The
+    grouped form sorts the token-choices by held expert and reads only
+    the experts hit, paying by the group; the dense form reads every held
+    expert once, in one batched product. Dense where the share of held
+    experts expected to be hit, ``1 - (1 - k / width) ** n``, is at least
+    :data:`DENSE_HIT_SHARE` and ``n`` is at most :data:`DENSE_MAX_ROWS`;
+    grouped where ``width`` is not known."""
+    if width is None or n > DENSE_MAX_ROWS:
+        return "grouped"
+    hit = 1.0 - (1.0 - min(k / width, 1.0)) ** n
+    return "dense" if hit >= DENSE_HIT_SHARE else "grouped"
+
+
 def moe_held_ffn(x, idx, weights, w_gate, w_up, w_down, first_expert=0,
-                 live=None, act=jax.nn.silu):
+                 live=None, act=jax.nn.silu, width=None):
     """The held experts' part of a sparse FFN, dropless: each expert
     ``W_down (act(W_gate x) * W_up x)``, or where ``w_gate`` is None
     ``W_down act(W_up x)``.
 
     ``x`` (N, C); ``idx``/``weights`` (N, k) from :func:`moe_route` (ids
-    over all the layer's experts); ``w_gate`` (or None) / ``w_up`` (E, C,
-    F) and ``w_down`` (E, F, C) the ``E`` experts held here, which are
-    experts ``first_expert .. first_expert + E - 1`` of the layer. The N x k
-    token-choices are sorted by held expert (those that fell on experts
-    held elsewhere go last and belong to no group) and each projection is
-    one grouped product over the sorted rows; every token is served by
-    every held expert it chose. Returns ``(y (N, C) float32, counts)``;
-    ``counts`` are int32 scalars of the routing: ``routed_here``
-    (token-choices on held experts), ``experts_hit`` (held experts with
-    at least one token), ``load_max`` (most tokens on one held expert),
-    over the tokens where ``live`` (N,) is true (all, by default).
+    over all the layer's ``width`` experts); ``w_gate`` (or None) /
+    ``w_up`` (E, C, F) and ``w_down`` (E, F, C) the ``E`` experts held
+    here, which are experts ``first_expert .. first_expert + E - 1`` of the
+    layer. :func:`held_form` picks the form from ``N``, ``k`` and
+    ``width``. Grouped: the N x k token-choices are sorted by held expert
+    (those that fell on experts held elsewhere go last and belong to no
+    group) and each projection is one grouped product over the sorted
+    rows. Dense: every row goes through every held expert in one batched
+    product and the outputs are summed under the choice's weight, zero
+    where a row did not choose the expert. Either way every token is
+    served by every held expert it chose. Returns ``(y (N, C) float32,
+    counts)``; ``counts`` are int32 scalars of the routing:
+    ``routed_here`` (token-choices on held experts), ``experts_hit`` (held
+    experts with at least one token), ``load_max`` (most tokens on one
+    held expert), over the tokens where ``live`` (N,) is true (all, by
+    default).
     """
+    from .. import telemetry
+
     n, k = idx.shape
     e = w_up.shape[0]
     local = idx - int(first_expert)
     held = (local >= 0) & (local < e)
     key = jnp.where(held, local, e).reshape(-1)            # (N*k,)
-    order = jnp.argsort(key, stable=True)
     hits = jax.nn.one_hot(key, e + 1, dtype=jnp.int32)      # (N*k, E+1)
     sizes = hits.sum(0)[:e]
-    rows = jnp.take(x, order // k, axis=0)                  # (N*k, C)
     # 16-bit operands hold nothing a higher contract precision could
     # recover, and the TPU's ragged-dot kernel refuses the pair ("Bad lhs
     # type" under an ambient ``highest``): ask for what they can give
     low = jnp.dtype(x.dtype).itemsize < 4
-    grouped = functools.partial(
-        jax.lax.ragged_dot, group_sizes=sizes,
-        precision=jax.lax.Precision.DEFAULT if low else None)
+    precision = jax.lax.Precision.DEFAULT if low else None
+    form = held_form(n, k, width)
+    telemetry.counter("mxtpu_moe_held_form_total",
+                      "held-expert products traced, by form",
+                      form=form).inc()
+    if form == "dense":
+        y = _held_dense(x, key.reshape(n, k), weights, w_gate, w_up,
+                        w_down, act, precision)
+    else:
+        y = _held_grouped(x, key, held, weights, sizes, w_gate, w_up,
+                          w_down, act, precision)
+    load = sizes if live is None else \
+        (hits * live.astype(jnp.int32).repeat(k)[:, None]).sum(0)[:e]
+    counts = {"routed_here": load.sum(), "experts_hit": (load > 0).sum(),
+              "load_max": load.max()}
+    return y, {name: v.astype(jnp.int32) for name, v in counts.items()}
+
+
+def _held_grouped(x, key, held, weights, sizes, w_gate, w_up, w_down, act,
+                  precision):
+    """The grouped form: one ``ragged_dot`` a projection over the
+    token-choices sorted by held expert."""
+    n, k = held.shape
+    order = jnp.argsort(key, stable=True)
+    rows = jnp.take(x, order // k, axis=0)                  # (N*k, C)
+    grouped = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                                precision=precision)
     h = act(grouped(rows, w_up)) if w_gate is None \
         else act(grouped(rows, w_gate)) * grouped(rows, w_up)
     out = grouped(h, w_down, preferred_element_type=jnp.float32)
@@ -195,9 +252,27 @@ def moe_held_ffn(x, idx, weights, w_gate, w_up, w_down, first_expert=0,
     # are selected out, never multiplied by a zero weight
     back = jnp.take(out, jnp.argsort(order), axis=0).reshape(n, k, -1)
     back = jnp.where(held[..., None], back, 0.0)
-    y = jnp.einsum("nkc,nk->nc", back, weights)
-    load = sizes if live is None else \
-        (hits * live.astype(jnp.int32).repeat(k)[:, None]).sum(0)[:e]
-    counts = {"routed_here": load.sum(), "experts_hit": (load > 0).sum(),
-              "load_max": load.max()}
-    return y, {name: v.astype(jnp.int32) for name, v in counts.items()}
+    return jnp.einsum("nkc,nk->nc", back, weights)
+
+
+def _held_dense(x, key, weights, w_gate, w_up, w_down, act, precision):
+    """The dense form: every row through every held expert as one batched
+    product, no sort and no gather. ``key`` (N, k) is the held expert of
+    each choice, ``E`` where it is held elsewhere."""
+    e = w_up.shape[0]
+    # the combine matrix (N, E): a choice's weight on its held expert,
+    # zero on every expert the row did not choose (the choices held
+    # elsewhere, index E, match no column)
+    w_held = (weights[..., None]
+              * jax.nn.one_hot(key, e, dtype=weights.dtype)).sum(1)
+    rows = jnp.broadcast_to(x, (e,) + x.shape)               # (E, N, C)
+    up = functools.partial(jnp.einsum, "enc,ecf->enf", rows,
+                           precision=precision)
+    h = act(up(w_up)) if w_gate is None else act(up(w_gate)) * up(w_up)
+    # the TPU compiler fuses both products and this sum into one fusion,
+    # so the float32 partials (E, N, C) are never written out; they are
+    # combined in float32, as the grouped form's are
+    out = jnp.einsum("enf,efc->enc", h, w_down, precision=precision,
+                     preferred_element_type=jnp.float32)
+    return jnp.einsum("enc,ne->nc", out, w_held,
+                      precision=jax.lax.Precision.HIGHEST)

@@ -695,3 +695,36 @@ def test_mamba2_decoder_step_updates_its_state_in_one_fusion_published_widths(
                   "latent_up", "shared_expert", "attention"):
         assert f"jit(_decode_apply)/{scope}/" in text, scope
     assert _kernel_calls(text) == 1
+
+
+def test_held_experts_stream_their_weights_where_they_lie_nemotron_widths(
+        one_chip):
+    """The held experts' product at Nemotron-3 Super's decode widths (64
+    rows, 128 of 512 experts held, top-22, a latent of 1024 -> 2688 relu2
+    -> 1024, bfloat16) takes the dense form: no ``ragged-dot`` kernel in
+    the compiled program, the expert weights are read where they lie,
+    with no copy or transpose of either stacked operand anywhere in it,
+    and the float32 partials of the down product (E, N, C) are never
+    written out (temp memory under their size)."""
+    from incubator_mxnet_tpu.ops.moe import held_form, moe_held_ffn
+
+    n, c, f, e, k, width = 64, 1024, 2688, 128, 22, 512
+    assert held_form(n, k, width) == "dense"
+
+    def held(x, idx, weights, w_up, w_down, live):
+        return moe_held_ffn(
+            x, idx, weights, None, w_up, w_down, live=live,
+            act=lambda a: jnp.square(jax.nn.relu(a)), width=width)
+
+    compiled = jax.jit(held).lower(
+        _spec(one_chip, (n, c)), _spec(one_chip, (n, k), jnp.int32),
+        _spec(one_chip, (n, k), jnp.float32), _spec(one_chip, (e, c, f)),
+        _spec(one_chip, (e, f, c)), _spec(one_chip, (n,), jnp.bool_)
+    ).compile()
+    text = compiled.as_text()
+    assert "ragged" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < e * n * c * 4
+    assert not _entry_copies_of_parameters(text)
+    relaid = re.findall(r"= bf16\[%d,(?:%d,%d|%d,%d)\]\S* (?:copy|copy-start|"
+                        r"transpose)\(" % (e, c, f, f, c), text)
+    assert not relaid, relaid
